@@ -227,10 +227,9 @@ def bench_one_launch(K: int, d: int, rounds: int = 4) -> List[Dict]:
     return rows
 
 
-def _bench_sharded_worker(shards: int, K: int, d: int) -> Dict:
-    """One sharded-round timing row, run INSIDE a subprocess whose
-    XLA_FLAGS already forced ``shards`` virtual host devices (the flag
-    must be set before jax imports, hence the subprocess)."""
+def _bench_sharded_row(shards: int, K: int, d: int) -> Dict:
+    """One sharded-round timing row over the first ``shards`` devices
+    of this process (1 = the same round single-device)."""
     from repro.distributed import spmd
 
     N = 8
@@ -257,30 +256,17 @@ def _bench_sharded_worker(shards: int, K: int, d: int) -> Dict:
 
 def bench_sharded(K: int, d: int, shards: int = 8) -> List[Dict]:
     """The d-sharded gossip round (distributed/spmd.py) vs the same
-    two-launch round single-process, each in its own subprocess so
-    ``--xla_force_host_platform_device_count`` lands before jax loads.
-    Interpret-mode caveat applies: on virtual CPU devices the sharded
-    row measures the shard_map + psum orchestration overhead, not a
-    speedup — the wire-traffic win is what ``python -m repro.analysis``
-    verifies statically."""
-    import subprocess
-    import sys
-
-    rows = []
-    for s in (1, shards):
-        env = dict(os.environ)
-        env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
-                            + f" --xla_force_host_platform_device_count={s}"
-                            ).strip()
-        proc = subprocess.run(
-            [sys.executable, os.path.abspath(__file__),
-             "--sharded-worker", str(s), "--sizes", f"{K}x{d}"],
-            capture_output=True, text=True, env=env)
-        if proc.returncode != 0:
-            print(f"sharded worker ({s} dev) failed:\n{proc.stderr}")
-            continue
-        rows.append(json.loads(proc.stdout.splitlines()[-1]))
-    return rows
+    two-launch round on one device, both in this process — one process
+    owns the chips.  It needs ``shards`` devices: on a CPU, force them
+    with ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` before
+    launching.  Interpret-mode caveat applies: on virtual CPU devices
+    the sharded row measures the shard_map + psum orchestration
+    overhead, not a speedup — the wire-traffic win is what
+    ``python -m repro.analysis`` verifies statically."""
+    if jax.device_count() < shards:
+        raise SystemExit(
+            f"--sharded needs {shards} devices, found {jax.device_count()}")
+    return [_bench_sharded_row(s, K, d) for s in (1, shards)]
 
 
 def bench_kernels(K: int, d: int) -> List[Dict]:
@@ -353,9 +339,7 @@ def main(argv=None) -> List[Dict]:
     ap.add_argument("--kernels", action="store_true", help="include Pallas paths")
     ap.add_argument("--sharded", action="store_true",
                     help="include the d-sharded gossip round (1 vs 8 "
-                         "virtual devices, subprocesses)")
-    ap.add_argument("--sharded-worker", type=int, default=0,
-                    help=argparse.SUPPRESS)  # bench_sharded internal
+                         "devices of this process)")
     ap.add_argument("--out", default="")
     ap.add_argument("--bench-json", default="",
                     help="trajectory file to append to (opt-in — "
@@ -363,10 +347,6 @@ def main(argv=None) -> List[Dict]:
                          "ad-hoc/smoke runs default to not touching the "
                          "committed baseline)")
     args = ap.parse_args(argv)
-    if args.sharded_worker:
-        K, d = (int(x) for x in args.sizes.split(",")[0].split("x"))
-        print(json.dumps(_bench_sharded_worker(args.sharded_worker, K, d)))
-        return []
     rows: List[Dict] = []
     for tok in args.sizes.split(","):
         K, d = (int(x) for x in tok.split("x"))

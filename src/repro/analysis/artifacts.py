@@ -23,7 +23,7 @@ from typing import Any, Callable, Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from jax.core import ClosedJaxpr, Jaxpr
+from jax.extend.core import ClosedJaxpr, Jaxpr
 
 
 def iter_subjaxprs(jaxpr: Jaxpr) -> Iterator[Tuple[Any, Jaxpr]]:
@@ -105,9 +105,11 @@ class PallasCallInfo:
 
 
 def _block_dims(block_shape) -> Tuple[int, ...]:
-    # squeezed dims may appear as None / pallas Mapped sentinels
+    # dims arrive as ints or as Pallas block-dim objects (``Blocked(n)``,
+    # ``Squeezed``, ...); a squeezed dim holds one element
+    dims = (getattr(d, "block_size", d) for d in block_shape)
     return tuple(int(d) if isinstance(d, (int, np.integer)) else 1
-                 for d in block_shape)
+                 for d in dims)
 
 
 def collect_pallas_calls(jaxpr: Jaxpr) -> List[PallasCallInfo]:
@@ -121,7 +123,7 @@ def collect_pallas_calls(jaxpr: Jaxpr) -> List[PallasCallInfo]:
             "pallas_call"
         blocks = []
         for bm in gm.block_mappings:
-            sds = bm.array_shape_dtype
+            sds = bm.array_aval
             dt = np.dtype(sds.dtype)
             blocks.append(BlockInfo(
                 origin=str(getattr(bm, "origin", "")),

@@ -57,17 +57,18 @@ def wfagg_round_contract(n: int, k: int, n_shards: int, rounds: int = 1,
     """Contract for ``rounds`` sharded WFAgg gossip rounds over N nodes
     of degree K: all-reduce only, each payload O(N·K) (O(N·K²) with the
     Alt-WFAgg Gram riding along), total wire = rounds x the psum of the
-    statistic partials.  ``headroom`` absorbs float/layout slack and how
-    XLA splits or fuses the per-field psums — NOT a full-d gather, which
-    overshoots these ceilings ~100x at MLP size."""
-    per_collective = 4 * n * (k * k if need_gram else k)
+    statistic partials.  XLA's all-reduce combiner may merge the
+    per-field psums of a round into one collective, so a single payload
+    may carry a whole round's statistics.  ``headroom`` absorbs
+    float/layout slack — NOT a full-d gather, which overshoots these
+    ceilings by more than an order of magnitude at MLP size."""
     per_round = 4 * (_STATS_FIELDS * n * k + n
                      + (n * k * k if need_gram else 0))
     ring = 2 * (n_shards - 1) / max(1, n_shards)   # all-reduce ring factor
     return CommContract(
         axis_size=n_shards,
         allowed_kinds=("all-reduce",),
-        max_collective_bytes=int(headroom * per_collective),
+        max_collective_bytes=int(headroom * per_round),
         wire_budget_bytes=headroom * rounds * ring * per_round,
         description=(f"{rounds} sharded WFAgg round(s): all-reduce-only, "
                      f"O(N*K) statistic psums across {n_shards} shards"),

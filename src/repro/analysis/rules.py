@@ -264,11 +264,11 @@ def _eval_index_map(ij, coords, smem_shapes) -> Optional[Tuple[int, ...]]:
     SMEM scalar-prefetch refs are fed zero tables (block index 0 is
     always in range), so pure-grid arithmetic — the pinning expressions
     like ``i * p`` — is what gets validated."""
-    import jax
+    from jax.extend.core import ClosedJaxpr, jaxpr_as_fun
     args = [np.int32(c) for c in coords]
     args += [np.zeros(s, np.int32) for s in smem_shapes]
     try:
-        out = jax.core.eval_jaxpr(ij.jaxpr, ij.consts, *args)
+        out = jaxpr_as_fun(ClosedJaxpr(ij.jaxpr, ij.consts))(*args)
     except Exception:
         return None
     return tuple(int(o) for o in out)

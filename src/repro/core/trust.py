@@ -1,17 +1,17 @@
 """WFAgg trust-weight derivation from O(K) sufficient statistics.
 
 This is the scoring stage of WFAgg (Alg. 1 lines 9-22 + the valid-aware
-filter masks) factored out of ``core.wfagg`` so that it can run in TWO
-places off the exact same code:
+filter masks) factored out of ``core.wfagg``.  It runs in two places:
 
   * on the host, between the stats and combine kernel launches of the
-    two-launch fused path (``core.wfagg._wfagg_batch_indexed``), vmapped
-    over the N receiving nodes;
+    two-launch fused path and in the valid-aware reference
+    (``core.wfagg._indexed_scoring``), vmapped over the N receiving
+    nodes, through the sort-based ``fused_*_mask_valid`` selections;
   * INSIDE the single-launch round kernel
     (``kernels.robust_stats.kernel._wfagg_round_indexed_kernel``), at the
     phase boundary, on the VMEM-resident ``(1, K)`` accumulators of one
-    node — which is what lets the kernel derive the WFAgg-E weights and
-    combine without a host round-trip.
+    node — ``derive_trust_weights``, written on 2-D tiles Mosaic lowers,
+    with the same selections.
 
 Everything here is O(K)/O(K^2) plain-jnp logic on tiny arrays; the only
 import from the kernels package is the ``RobustStats`` container (pure
@@ -114,17 +114,20 @@ def temporal_bands(hist_s: Array, hist_b: Array, count: Array, t: Array,
 # ---------------------------------------------------------------------------
 
 def sq_dists_from_gram(gram: Array, norm2: Array) -> Array:
-    """(K, K) squared distances from a Gram matrix + squared norms."""
-    d2 = norm2[..., :, None] + norm2[..., None, :] - 2.0 * gram
+    """(K, K) squared distances from a Gram matrix + squared norms
+    (``norm2`` as a (K,) vector or a (1, K) row)."""
+    row = jnp.reshape(norm2, (1, -1))
     K = gram.shape[-1]
-    d2 = d2 * (1.0 - jnp.eye(K, dtype=d2.dtype))
+    d2 = row_to_col(row) + row - 2.0 * gram
+    d2 = d2 * jnp.where(_eye(K), 0.0, 1.0)
     return jnp.maximum(d2, 0.0)
 
 
 def cosine_dist_from_gram(gram: Array, norm2: Array) -> Array:
-    """(K, K) cosine distance matrix from a Gram matrix + squared norms."""
-    n = jnp.sqrt(jnp.maximum(norm2, _EPS))
-    return 1.0 - gram / jnp.maximum(n[..., :, None] * n[..., None, :], _EPS)
+    """(K, K) cosine distance matrix from a Gram matrix + squared norms
+    (``norm2`` as a (K,) vector or a (1, K) row)."""
+    n = jnp.sqrt(jnp.maximum(jnp.reshape(norm2, (1, -1)), _EPS))
+    return 1.0 - gram / jnp.maximum(row_to_col(n) * n, _EPS)
 
 
 def needs_gram(cfg) -> bool:
@@ -199,35 +202,185 @@ def fused_similarity_mask_valid(stats: RobustStats, gram: Optional[Array],
 
 
 # ---------------------------------------------------------------------------
-# the full scoring stage: stats -> (masks, trust weights, combine coeffs)
+# the in-kernel scoring stage, on (1, K) rows
 # ---------------------------------------------------------------------------
+#
+# Mosaic lowers neither sort, argsort, top_k nor scatter, and lays out
+# 1-D vectors poorly, so the round kernel scores on 2-D tiles only:
+# (1, K) rows, (K, 1) columns and (K, K) matrices.  Ranks come from
+# pairwise compares, transposes from a masked sum over the identity (one
+# nonzero term per sum, so they are exact), and updates from one-hot
+# selects.  Each selection equals its sort-based counterpart in
+# ``core.aggregators`` (the valid-aware reference oracle): a stable
+# ascending order, ties broken by index, NaN last.  Boolean tiles are
+# combined with logic ops, never selected between: Mosaic has no select
+# on i1 vectors.
+
+def _iota(shape, axis: int) -> Array:
+    return jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+
+
+def _eye(K: int) -> Array:
+    return _iota((K, K), 0) == _iota((K, K), 1)
+
+
+def row_to_col(row: Array) -> Array:
+    """(1, K) -> (K, 1)."""
+    return jnp.sum(jnp.where(_eye(row.shape[-1]), row, jnp.zeros_like(row)),
+                   axis=1, keepdims=True)
+
+
+def col_to_row(col: Array) -> Array:
+    """(K, 1) -> (1, K)."""
+    return jnp.sum(jnp.where(_eye(col.shape[0]), col, jnp.zeros_like(col)),
+                   axis=0, keepdims=True)
+
+
+def _sorts_before(a: Array, b: Array, a_first_on_tie: Array) -> Array:
+    """``a`` precedes ``b`` in a stable ascending sort with NaN last."""
+    a_nan, b_nan = jnp.isnan(a), jnp.isnan(b)
+    tie = (a == b) | (a_nan & b_nan)
+    return (a < b) | (~a_nan & b_nan) | (tie & a_first_on_tie)
+
+
+def row_ranks(scores: Array) -> Array:
+    """(1, K) -> (1, K) int32 position of each entry in the stable
+    ascending order ``jnp.argsort`` gives."""
+    K = scores.shape[-1]
+    first = _iota((K, K), 0) < _iota((K, K), 1)
+    before = _sorts_before(row_to_col(scores), scores, first)
+    return jnp.sum(before.astype(jnp.int32), axis=0, keepdims=True)
+
+
+def smallest_k_row(scores: Array, k: Array) -> Array:
+    """(1, K) bool row selecting the ``k`` smallest scores (``k`` traced,
+    clamped to [0, K]) — ``agg.smallest_k_mask_dyn`` on a row."""
+    return row_ranks(scores) < jnp.clip(k, 0, scores.shape[-1])
+
+
+def krum_scores_row(d2: Array, f: int, n_valid: Array) -> Array:
+    """(K, K) squared distances (+inf on invalid pairs) -> (1, K) Krum
+    scores: each candidate's sum over its ``max(1, n_valid - f - 2)``
+    closest peers — ``agg.krum_scores_from_sq_dists_dyn`` without the
+    sort (entries are summed in index order, not sorted order)."""
+    K = d2.shape[0]
+    jj = _iota((K, K), 1)
+    d2 = d2 + jnp.where(_eye(K), jnp.inf, 0.0)
+    rank = jnp.zeros((K, K), jnp.int32)
+    for l in range(K):
+        col = jnp.sum(jnp.where(jj == l, d2, 0.0), axis=1, keepdims=True)
+        rank += _sorts_before(col, d2, l < jj).astype(jnp.int32)
+    take = rank < jnp.maximum(n_valid - int(f) - 2, 1)
+    return col_to_row(jnp.sum(jnp.where(take, d2, 0.0), axis=1,
+                              keepdims=True))
+
+
+def _first_flat_argmin(x: Array) -> Array:
+    """``jnp.argmin`` of a (K, K) tile (row-major, first hit, NaN wins)."""
+    K = x.shape[0]
+    flat = _iota((K, K), 0) * K + _iota((K, K), 1)
+    nan = jnp.isnan(x)
+    any_nan = jnp.max(nan.astype(jnp.int32)) > 0
+    hit = (any_nan & nan) | (~any_nan & (x == jnp.min(x)))
+    return jnp.min(jnp.where(hit, flat, K * K))
+
+
+def clustering_row(D0: Array, valid: Array) -> Array:
+    """(K, K) distances, (1, K) bool valid -> (1, K) bool mask of the
+    larger average-linkage cluster of the valid candidates: the
+    recurrence of ``agg.clustering_select_from_dist_dyn``, unrolled over
+    its K - 2 merges with one-hot updates in place of scatters."""
+    K = D0.shape[-1]
+    if K <= 2:
+        return valid
+    eye = _eye(K)
+    ii, jj = _iota((K, K), 0), _iota((K, K), 1)
+    kio = _iota((1, K), 1)
+    sizes = valid.astype(jnp.float32)
+    valid_col = row_to_col(sizes) > 0.0
+    D = jnp.where(valid_col & valid, D0, jnp.inf)
+    n_merge = jnp.sum(valid.astype(jnp.int32)) - 2
+    active, assign = valid, kio
+    for s in range(K - 2):
+        gate = s < n_merge
+        active_col = row_to_col(active.astype(jnp.float32)) > 0.0
+        flat = _first_flat_argmin(
+            jnp.where(active_col & active & ~eye, D, jnp.inf))
+        i0, j0 = jax.lax.div(flat, K), jax.lax.rem(flat, K)
+        i, j = jnp.minimum(i0, j0), jnp.maximum(i0, j0)
+        ni = jnp.sum(jnp.where(kio == i, sizes, 0.0))
+        nj = jnp.sum(jnp.where(kio == j, sizes, 0.0))
+        row_i = jnp.sum(jnp.where(ii == i, D, 0.0), axis=0, keepdims=True)
+        row_j = jnp.sum(jnp.where(ii == j, D, 0.0), axis=0, keepdims=True)
+        newrow = (ni * row_i + nj * row_j) / jnp.maximum(ni + nj, 1.0)
+        nD = jnp.where(jj == i, row_to_col(newrow),
+                       jnp.where(ii == i, newrow, D))
+        nsizes = jnp.where(kio == j, 0.0, jnp.where(kio == i, ni + nj, sizes))
+        D = jnp.where(gate, nD, D)
+        active = active & ~(gate & (kio == j))
+        sizes = jnp.where(gate, nsizes, sizes)
+        assign = jnp.where(gate & (assign == j), i, assign)
+    big = jnp.min(jnp.where(sizes == jnp.max(sizes), kio, K))
+    # <= 2 valid candidates: nothing to cluster, accept them all
+    return valid & ((n_merge <= 0) | (assign == big))
+
+
+def _lane_block(row: Array, q: int, K: int) -> Array:
+    """``row[:, q*K:(q+1)*K]`` of a (1, 4K) row, as a (1, K) row."""
+    pick = _iota((K, row.shape[-1]), 1) == _iota((K, row.shape[-1]), 0) + q * K
+    return col_to_row(jnp.sum(jnp.where(pick, row, 0.0), axis=1,
+                              keepdims=True))
+
 
 def derive_trust_weights(
     stats: RobustStats,
     gram: Optional[Array],
-    valid: Array,          # (K,) float32, 1.0 on real edges
-    tbands: Optional[Array],   # (4, K) from temporal_bands, or None
+    valid: Array,          # (1, K) float32, 1.0 on real edges
+    tbands: Optional[Array],   # (1, 4K) from temporal_bands, or None
     cfg,
 ) -> Tuple[Array, Array, Array, Array]:
-    """One node's WFAgg scoring stage: (mask_d, mask_c, mask_t, weights).
+    """One node's WFAgg scoring stage at the round kernel's phase
+    boundary: (mask_d, mask_c, mask_t, weights), each a (1, K) row.
 
-    Pure O(K)/O(K^2) logic on the sufficient statistics — THE shared code
-    between the host path and the in-kernel phase boundary.  ``weights``
-    already carries the valid mask (padded slots weigh 0), so a degree-0
-    node scores an all-zero vector and the combine falls back to its
-    local model.
+    ``stats`` holds (1, K) rows and a (1, 1) ``mednorm2``.  The masks
+    equal ``fused_distance_mask_valid`` / ``fused_similarity_mask_valid``
+    and ``wfagg_t_decide``'s in-band test.  ``weights`` already carries
+    the valid mask (padded slots weigh 0), so a degree-0 node scores an
+    all-zero vector and the combine falls back to its local model.
     """
-    valid_b = valid.astype(bool)
-    mask_d = fused_distance_mask_valid(stats, gram, valid_b, cfg)
-    mask_c = fused_similarity_mask_valid(stats, gram, valid_b, cfg)
-    if tbands is None:
-        mask_t = jnp.zeros(valid_b.shape, dtype=bool)
+    K = valid.shape[-1]
+    valid_b = valid > 0.0
+    v = jnp.sum(valid_b.astype(jnp.int32))
+    keep = v - int(cfg.f) - 1
+    if cfg.distance_filter == "wfagg_d":
+        mask_d = smallest_k_row(jnp.where(valid_b, stats.dist2, jnp.inf), keep)
+    elif cfg.distance_filter == "multi_krum":
+        d2 = sq_dists_from_gram(gram, stats.norm2)
+        pair = (row_to_col(valid) > 0.0) & valid_b
+        scores = krum_scores_row(jnp.where(pair, d2, jnp.inf), cfg.f, v)
+        m = cfg.multi_krum_m or max(1, K // 4)
+        mask_d = smallest_k_row(jnp.where(valid_b, scores, jnp.inf),
+                                jnp.minimum(m, v))
     else:
+        raise ValueError(f"unknown distance filter {cfg.distance_filter!r}")
+    if cfg.similarity_filter == "wfagg_c":
+        mask_c = smallest_k_row(
+            jnp.where(valid_b, stats.cosine_to_median(), jnp.inf), keep)
+    elif cfg.similarity_filter == "clustering":
+        mask_c = clustering_row(cosine_dist_from_gram(gram, stats.norm2),
+                                valid_b)
+    else:
+        raise ValueError(
+            f"unknown similarity filter {cfg.similarity_filter!r}")
+    if tbands is None:
+        mask_t = jnp.zeros_like(valid_b)
+    else:
+        lo_d, hi_d, lo_c, hi_c = (_lane_block(tbands, q, K) for q in range(4))
         s_t = stats.prev_dist2
         b_t = stats.cosine_to_prev()
-        mask_t = ((s_t >= tbands[0]) & (s_t <= tbands[1])
-                  & (b_t >= tbands[2]) & (b_t <= tbands[3]) & valid_b)
-    weights = wfagg_scores(mask_d, mask_c, mask_t, cfg) * valid.astype(jnp.float32)
+        mask_t = ((s_t >= lo_d) & (s_t <= hi_d)
+                  & (b_t >= lo_c) & (b_t <= hi_c) & valid_b)
+    weights = wfagg_scores(mask_d, mask_c, mask_t, cfg) * valid
     return mask_d, mask_c, mask_t, weights
 
 

@@ -36,6 +36,7 @@ Execution backends (``WFAggConfig.backend``):
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import NamedTuple, Optional, Tuple
 
 import jax
@@ -60,6 +61,20 @@ _EPS = 1e-12
 # — the parity fallback (and the shape every non-indexed fused entry
 # still uses, where no single-launch op exists).
 _FUSED_BACKENDS = ("fused", "fused_two_launch")
+
+
+def f32_dots(fn):
+    """Trace ``fn`` with f32 matmul precision.  WFAgg's statistics,
+    trust scores and combines are f32 algorithms, and the Pallas kernels
+    compute them in f32 on the VPU; XLA's TPU default rounds dot
+    operands to bf16, which reorders near-tied candidates in the jnp
+    reference and rounds the WFAgg-T history when it is re-keyed.  The
+    dots here are O(K)-wide, so f32 costs little."""
+    @functools.wraps(fn)
+    def traced_in_f32(*args, **kwargs):
+        with jax.default_matmul_precision("float32"):
+            return fn(*args, **kwargs)
+    return traced_in_f32
 
 
 @dataclasses.dataclass(frozen=True)
@@ -342,6 +357,7 @@ def wfagg(
     return out, new_state, info
 
 
+@f32_dots
 def wfagg_batch(
     local: Array,
     updates: Array,
@@ -488,6 +504,23 @@ def _push_temporal_history(state: TemporalState, prev_new: Array,
                          count=count, t=t)
 
 
+def sanitize_round(models: Array, valid_b: Array,
+                   state: Optional[TemporalState], cfg: WFAggConfig,
+                   neighbor_idx: Array):
+    """Zero the non-finite rows of the (M, d) model matrix (and of the
+    temporal ``prev``) and demote the edges that read them to invalid,
+    before any statistic: a corrupted payload degrades to a rejected
+    slot instead of poisoning the median.  Returns ``(models, valid_b,
+    state)``; a no-op on finite inputs."""
+    finite = jnp.isfinite(models).all(axis=-1)
+    models = jnp.where(finite[:, None], models, 0.0)
+    valid_b = valid_b & finite[neighbor_idx]
+    if cfg.use_temporal and state is not None:
+        pf = jnp.isfinite(state.prev).all(axis=-1)
+        state = state._replace(prev=jnp.where(pf[..., None], state.prev, 0.0))
+    return models, valid_b, state
+
+
 def _wfagg_batch_indexed(
     local: Array,
     models: Array,
@@ -523,13 +556,8 @@ def _wfagg_batch_indexed(
     if prev_idx is not None and not matrix_prev:
         prev_idx = None        # nothing matrix-formed to re-key
     if cfg.sanitize:
-        finite = jnp.isfinite(models).all(axis=-1)
-        models = jnp.where(finite[:, None], models, 0.0)
-        valid_b = valid_b & finite[neighbor_idx]
-        if temporal:
-            pf = jnp.isfinite(state.prev).all(axis=-1)
-            state = state._replace(
-                prev=jnp.where(pf[..., None], state.prev, 0.0))
+        models, valid_b, state = sanitize_round(models, valid_b, state, cfg,
+                                                neighbor_idx)
     prev = state.prev if temporal else None
 
     if cfg.backend == "reference":
@@ -635,6 +663,7 @@ def _wfagg_batch_indexed_reference_valid(
     return out, new_state, info
 
 
+@f32_dots
 def realign_temporal_history(state: TemporalState,
                              prev_idx: Array, prev_valid: Array,
                              idx: Array, valid: Array) -> TemporalState:

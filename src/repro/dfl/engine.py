@@ -450,10 +450,15 @@ def _make_round_core(cfg: DFLConfig, data: SyntheticImages,
                 wcfg = _wfagg_full_config(cfg, neighbor_idx.shape[1])
                 if cfg.mesh_model_shards > 1:
                     from repro.distributed import spmd
+                    mesh = spmd.aggregation_mesh(cfg.mesh_model_shards)
+                    # the round boundary is replicated: local training
+                    # and evaluation stay whole on every device
+                    flat, t_in = spmd.replicate((flat, state.temporal), mesh)
                     new_flat, new_temporal, info = spmd.wfagg_batch_sharded(
-                        flat, flat, state.temporal, wcfg,
-                        neighbor_idx, neighbor_valid,
-                        mesh=spmd.aggregation_mesh(cfg.mesh_model_shards))
+                        flat, flat, t_in, wcfg,
+                        neighbor_idx, neighbor_valid, mesh=mesh)
+                    new_flat, new_temporal = spmd.replicate(
+                        (new_flat, new_temporal), mesh)
                 else:
                     new_flat, new_temporal, info = wf.wfagg_batch(
                         flat, flat, state.temporal, wcfg,
@@ -929,7 +934,8 @@ def run_dynamic_experiment(cfg: DFLConfig, topo: Topology,
     rounds, reproducing the uninterrupted trajectory bit-exactly (use a
     ``make_fault_schedule("none", ...)`` schedule to checkpoint a
     fault-free run).  ``out["rounds_run"]`` records the [start, end)
-    window a partial run covered.
+    window a partial run covered.  ``out["state"]`` is the final
+    ``DFLState`` (the trained node models).
     """
     if (stop_after is not None or resume_from is not None
             or checkpoint_dir is not None) and faults is None:
@@ -999,7 +1005,7 @@ def run_dynamic_experiment(cfg: DFLConfig, topo: Topology,
         schedule.degree_stats()[r0:r_end].tolist())
     out = {"trace": trace, "final": final, "series": series,
            "aggregator": cfg.aggregator, "attack": cfg.attack,
-           "centralized": cfg.centralized}
+           "centralized": cfg.centralized, "state": state}
     if faults is not None:
         out["faults"] = faults.summary()
         out["rounds_run"] = [r0, r_end]

@@ -20,15 +20,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 MeshAxis = Union[None, str, Tuple[str, ...]]
 
 
-def _get_abstract_mesh():
-    """Version compat: jax.sharding.get_abstract_mesh is only public in
-    newer jax; the pinned 0.4.x keeps it in jax._src.mesh."""
-    fn = getattr(jax.sharding, "get_abstract_mesh", None)
-    if fn is None:
-        from jax._src.mesh import get_abstract_mesh as fn
-    return fn()
-
-
 _state = threading.local()
 
 
@@ -70,7 +61,7 @@ def shard(x: jax.Array, *axes: Optional[str]) -> jax.Array:
     # regions vs Auto outside) match the trace context — a concrete-mesh
     # NamedSharding would poison downstream avals with Auto-typed axes and
     # break AD zero-instantiation inside partial-manual shard_map.
-    cur = _get_abstract_mesh()
+    cur = jax.sharding.get_abstract_mesh()
     use = cur if (cur is not None and not cur.empty) else mesh
     return jax.lax.with_sharding_constraint(x, NamedSharding(use, P(*spec)))
 

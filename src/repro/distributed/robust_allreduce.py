@@ -41,7 +41,9 @@ from repro.core import aggregators as agg_lib
 from repro.core import attacks as atk
 from repro.core import trust
 from repro.core.wfagg import (
-    TemporalState, WFAggConfig, wfagg_scores, wfagg_t_decide, wfagg_t_select)
+    TemporalState, WFAggConfig, f32_dots, wfagg_scores, wfagg_t_decide,
+    wfagg_t_select)
+from repro.distributed.logical import current_mesh
 from repro.kernels.pairwise_dist.ops import pairwise_gram
 from repro.kernels.robust_stats.ops import robust_stats, wfagg_round_indexed
 from repro.obs import decision as obs_decision
@@ -124,17 +126,23 @@ def _axes_tuple(axis: AxisNames) -> Tuple[str, ...]:
 
 
 def axis_size(axis: AxisNames) -> int:
-    return int(jax.lax.psum(1, _axes_tuple(axis)))
+    return jax.lax.axis_size(_axes_tuple(axis))
 
 
 def my_index(axis: AxisNames) -> Array:
-    axes = _axes_tuple(axis)
-    idx = jnp.zeros((), jnp.int32)
-    for a in axes:
-        # psum-of-1 rather than jax.lax.axis_size: the latter only exists
-        # in newer jax than the pinned 0.4.x
-        idx = idx * axis_size(a) + jax.lax.axis_index(a)
-    return idx
+    return jax.lax.axis_index(_axes_tuple(axis))
+
+
+def _whole_on_each_device(fn):
+    """``fn`` — a Pallas launch, which GSPMD cannot partition — run
+    whole on every device of the active mesh (inputs gathered, outputs
+    replicated), or called as is outside a multi-device mesh."""
+    mesh = current_mesh()
+    if mesh is None or mesh.devices.size == 1:
+        return fn
+    return jax.shard_map(fn, mesh=mesh, in_specs=jax.sharding.PartitionSpec(),
+                         out_specs=jax.sharding.PartitionSpec(),
+                         check_vma=False)
 
 
 def _pad_chunks(flat: Array, chunk: int) -> Tuple[Array, int]:
@@ -421,7 +429,8 @@ def _stacked_stats_fused(
     gd = jnp.dtype(cfg.gather_dtype) if cfg.gather_dtype else None
     flat = _concat_candidates(stacked, gd)
     pflat = _concat_candidates(prev) if prev is not None else None
-    stats = robust_stats(flat, prev=pflat, need_center=False)
+    stats = _whole_on_each_device(
+        lambda f, p: robust_stats(f, prev=p, need_center=False))(flat, pflat)
     w = cfg.wfagg
     needs_gram = (
         cfg.method in ("krum", "multi_krum", "clustering", "alt_wfagg")
@@ -429,7 +438,7 @@ def _stacked_stats_fused(
         or w.similarity_filter == "clustering"
     )
     if needs_gram:
-        gram, _ = pairwise_gram(flat)
+        gram, _ = _whole_on_each_device(pairwise_gram)(flat)
     else:
         # _weights_from_stats only reads the diagonal (norm2) in this case
         gram = jnp.diag(stats.norm2)
@@ -499,6 +508,7 @@ def apply_stacked_attack(
     return jax.tree_util.tree_unflatten(treedef, out)
 
 
+@f32_dots
 def robust_allreduce_stacked(
     stacked: Any,
     cfg: RobustAggConfig,
@@ -614,9 +624,10 @@ def _stacked_one_launch(
         tbands = trust.temporal_bands(state.hist_s, state.hist_b,
                                       state.count, state.t, w)[None]
     local = jnp.zeros_like(flat[:1])                 # unused: lcoef = 0
-    out_flat, weights, mask_d, mask_c, mask_t, kstats = wfagg_round_indexed(
-        local, flat, nidx, None, w, prev=prev, tbands=tbands,
-        alpha=1.0, mean_fallback=True)
+    out_flat, weights, mask_d, mask_c, mask_t, kstats = _whole_on_each_device(
+        lambda loc, f, p, tb: wfagg_round_indexed(
+            loc, f, nidx, None, w, prev=p, tbands=tb,
+            alpha=1.0, mean_fallback=True))(local, flat, prev, tbands)
     new_state = state
     if temporal:
         hist_s, hist_b, count, t = trust.push_history(
@@ -640,6 +651,7 @@ def _stacked_one_launch(
 # public entry point
 # ---------------------------------------------------------------------------
 
+@f32_dots
 def robust_allreduce(
     flat: Array,
     axis: AxisNames,

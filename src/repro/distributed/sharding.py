@@ -20,26 +20,6 @@ from jax.sharding import PartitionSpec as P
 from repro.configs.base import ArchConfig
 
 
-def shard_map_compat(f, *, mesh, in_specs, out_specs, axis_names=None,
-                     check_vma: bool = False):
-    """``jax.shard_map`` across jax versions.
-
-    Newer jax exposes top-level ``jax.shard_map(..., axis_names=...,
-    check_vma=...)``; the pinned 0.4.x only has
-    ``jax.experimental.shard_map.shard_map(..., auto=..., check_rep=...)``.
-    ``axis_names`` follows the new API (the MANUAL axes; None = all).
-    """
-    if hasattr(jax, "shard_map"):
-        kw: Dict[str, Any] = dict(mesh=mesh, in_specs=in_specs,
-                                  out_specs=out_specs, check_vma=check_vma)
-        if axis_names is not None:
-            kw["axis_names"] = set(axis_names)
-        return jax.shard_map(f, **kw)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    auto = frozenset(mesh.axis_names) - frozenset(axis_names or mesh.axis_names)
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=bool(check_vma), auto=auto)
-
 # trailing-dim specs keyed by leaf name (without the 'model' axis resolved)
 _TRAILING: Dict[str, Tuple[Optional[str], ...]] = {
     # attention

@@ -101,6 +101,20 @@ def psum_stats(stats: RobustStats, axis: str = SHARD_AXIS) -> RobustStats:
         prev_norm2=ps(stats.prev_norm2), gram=ps(stats.gram))
 
 
+def replicate(tree: Any, mesh: Mesh) -> Any:
+    """Constrain every array of ``tree`` to be replicated over ``mesh``.
+
+    A caller whose data is replicated around a sharded round (the DFL
+    engine) pins both sides of it with this.  Otherwise sharding
+    propagation carries the round's ``P(None, 'model')`` into the
+    surrounding computation and partitions it too: the engine's local
+    training then runs its dots and convolutions split over the model
+    axis, with all-reduces of partial products."""
+    repl = NamedSharding(mesh, P())
+    return jax.tree.map(
+        lambda x: jax.lax.with_sharding_constraint(x, repl), tree)
+
+
 def _state_specs(state: Optional[wf.TemporalState]):
     """PartitionSpecs for a matrix-prev TemporalState: ``prev`` (N, d)
     shards over the model axis, the O(K) ring buffers replicate."""
@@ -147,6 +161,7 @@ def _round_specs(state):
     return in_specs, out_specs
 
 
+@wf.f32_dots
 def wfagg_batch_sharded(
     local: Array,
     models: Array,
@@ -166,14 +181,15 @@ def wfagg_batch_sharded(
     and the pad sliced back off, so callers with replicated inputs (the
     DFL engine) can use any d; the lint entry points pre-pad and keep
     everything sharded instead."""
-    from repro.distributed.sharding import shard_map_compat
-
     _check_state(state)
     S = int(mesh.shape[SHARD_AXIS])
     N, K = neighbor_idx.shape
     d = models.shape[-1]
     valid_b = (jnp.ones((N, K), dtype=bool) if valid is None
                else valid.astype(bool))
+    if cfg.sanitize:
+        models, valid_b, state = wf.sanitize_round(models, valid_b, state,
+                                                   cfg, neighbor_idx)
 
     loc = pad_to_shards(local, S)
     mod = pad_to_shards(models, S)
@@ -181,8 +197,9 @@ def wfagg_batch_sharded(
           if state is not None else None)
 
     in_specs, out_specs = _round_specs(st)
-    fn = shard_map_compat(_shard_round_body(cfg, SHARD_AXIS), mesh=mesh,
-                          in_specs=in_specs, out_specs=out_specs)
+    fn = jax.shard_map(_shard_round_body(cfg, SHARD_AXIS), mesh=mesh,
+                       in_specs=in_specs, out_specs=out_specs,
+                       check_vma=False)
     out, new_state, (mask_d, mask_c, mask_t, weights) = fn(
         loc, mod, st, neighbor_idx, valid_b)
     if out.shape[-1] != d:
@@ -197,6 +214,7 @@ def wfagg_batch_sharded(
     return out, new_state, info
 
 
+@wf.f32_dots
 def wfagg_scan_sharded(
     models: Array,
     state: Optional[wf.TemporalState],
@@ -215,8 +233,6 @@ def wfagg_scan_sharded(
     ``realign_temporal_history`` when temporal state is carried), and
     the shard-local combine.  d must already be a shard multiple
     (``pad_to_shards``)."""
-    from repro.distributed.sharding import shard_map_compat
-
     _check_state(state)
     S = int(mesh.shape[SHARD_AXIS])
     if models.shape[-1] % S:
@@ -245,8 +261,8 @@ def wfagg_scan_sharded(
     in_specs = (P(None, SHARD_AXIS), _state_specs(state),
                 P(None, None, None), P(None, None, None))
     out_specs = (P(None, SHARD_AXIS), _state_specs(state))
-    fn = shard_map_compat(scan_body, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs)
+    fn = jax.shard_map(scan_body, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     return fn(models, state, sched_idx,
               sched_valid.astype(bool))
 

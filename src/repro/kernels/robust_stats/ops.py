@@ -272,7 +272,7 @@ def wfagg_round_indexed(
         loc, m, neighbor_idx, v, cfg, p, tb, prev_idx,
         alpha=float(alpha), mean_fallback=mean_fallback,
         need_gram=trust.needs_gram(cfg), block_d=block_d, interpret=itp)
-    out = outs[0][:, :d]
+    out = outs[0][:, 0, :d]
     weights = outs[1][:, 0, :]
     mask_d, mask_c, mask_t = (o[:, 0, :] > 0.0 for o in outs[2:5])
     dist2, dotmed, norm2, mednorm2 = outs[5:9]

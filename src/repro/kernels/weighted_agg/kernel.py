@@ -31,26 +31,24 @@ def _weighted_agg_indexed_kernel(idx_ref, w_ref, lcoef_ref, local_ref, u_ref,
                                  out_ref, *, K: int):
     """Gather-free batched combine: grid (node, D block, neighbor slot).
     Each step DMAs one neighbor row block (scalar-prefetch index map) and
-    accumulates w[n, k] * models[idx[n, k]] into the revisited output
-    block, seeding it with lcoef * local at the first slot — the (N, K, d)
-    gossip tensor never exists."""
+    accumulates w[n, k] * models[idx[n, k]] into the revisited (1, T)
+    output block, seeding it with lcoef * local at the first slot — the
+    (N, K, d) gossip tensor never exists."""
     del idx_ref
     k = pl.program_id(2)
     is_first = k == 0
-    u = u_ref[...].astype(jnp.float32).reshape(1, -1)     # (1, T)
+    u = u_ref[...].astype(jnp.float32)                    # (1, T)
     w = w_ref[...].astype(jnp.float32)                    # (1, K)
     kio = jax.lax.broadcasted_iota(jnp.int32, w.shape, 1)
     wk = jnp.sum(jnp.where(kio == k, w, 0.0))             # scalar w[n, k]
 
     @pl.when(is_first)
     def _seed():
-        lc = lcoef_ref[0, 0]
-        out_ref[...] = (lc * local_ref[...].astype(jnp.float32)
-                        + wk * u).reshape(out_ref.shape)
+        out_ref[...] = lcoef_ref[...] * local_ref[...].astype(jnp.float32) + wk * u
 
     @pl.when(jnp.logical_not(is_first))
     def _accum():
-        out_ref[...] += (wk * u).reshape(out_ref.shape)
+        out_ref[...] += wk * u
 
 
 def weighted_agg_indexed_pallas(
@@ -63,26 +61,33 @@ def weighted_agg_indexed_pallas(
     block_d: int = 1024,
     interpret: bool | None = None,
 ) -> jax.Array:
+    """Returns the (N, 1, D) combine.  Every per-node operand rides as a
+    (rows, 1, width) view so that each block's trailing dims are whole on
+    the sublane axis, as Mosaic requires of one-row blocks."""
     N, K = wvec.shape
     M, D = models.shape
     assert D % block_d == 0
+    row = pl.BlockSpec((None, 1, block_d), lambda n, i, k, ir: (n, 0, i))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(N, D // block_d, K),
         in_specs=[
-            pl.BlockSpec((1, K), lambda n, i, k, ir: (n, 0)),
-            pl.BlockSpec((1, 1), lambda n, i, k, ir: (n, 0)),
-            pl.BlockSpec((1, block_d), lambda n, i, k, ir: (n, i)),
-            pl.BlockSpec((1, block_d), lambda n, i, k, ir: (ir[n, k], i)),
+            pl.BlockSpec((None, 1, K), lambda n, i, k, ir: (n, 0, 0)),
+            pl.BlockSpec((None, 1, 1), lambda n, i, k, ir: (n, 0, 0)),
+            row,
+            pl.BlockSpec((None, 1, block_d),
+                         lambda n, i, k, ir: (ir[n, k], 0, i)),
         ],
-        out_specs=pl.BlockSpec((1, block_d), lambda n, i, k, ir: (n, i)),
+        out_specs=row,
     )
     return pl.pallas_call(
         functools.partial(_weighted_agg_indexed_kernel, K=K),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((N, D), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((N, 1, D), jnp.float32),
         interpret=resolve_interpret(interpret),
-    )(neighbor_idx.astype(jnp.int32), wvec, lcoef, local, models)
+    )(neighbor_idx.astype(jnp.int32), wvec.reshape(N, 1, K),
+      lcoef.reshape(N, 1, 1), local.reshape(N, 1, D),
+      models.reshape(M, 1, D))
 
 
 def weighted_agg_pallas(

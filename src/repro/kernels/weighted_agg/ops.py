@@ -81,4 +81,4 @@ def weighted_agg_indexed(
         block_d=block_d,
         interpret=interpret,
     )
-    return out[:, :d]
+    return out[:, 0, :d]

@@ -1,9 +1,12 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
-# ^ MUST precede any jax import: jax locks the device count on first init.
-# This is the ONLY entry point that forces 512 host devices; smoke tests
-# and benchmarks see the single real CPU device.
+# ^ MUST precede any jax import: jax locks the platform and device count
+# on first init.  This is the ONLY entry point that forces 512 host
+# devices; smoke tests and benchmarks see the single real CPU device.
+# The dry-run is a CPU compile by design: pinning the platform keeps the
+# parent and every per-pair child off a TPU, which one process owns.
 """Multi-pod dry-run launcher.
 
 For every (architecture x input shape) pair, lower + compile the real
@@ -35,8 +38,8 @@ from typing import Any, Dict, List, Optional
 ARTIFACT_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
                             "benchmarks", "artifacts")
 
-# TPU v5e
-HBM_PER_CHIP = 16e9
+# the chip the production mesh is sized for (a key of launch.mesh.PEAKS)
+TARGET_DEVICE_KIND = "TPU v5 lite"
 
 _DTYPE_BYTES = {
     "pred": 1, "s4": 1, "u4": 1, "s8": 1, "u8": 1, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -136,9 +139,9 @@ def run_one(arch: str, shape_name: str, multi_pod: bool,
     from repro.configs.registry import get_config
     from repro.configs.shapes import SHAPES
     from repro.launch import specs as sp
-    from repro.launch.mesh import (HBM_BW, ICI_BW, PEAK_FLOPS_BF16,
-                                   make_production_mesh)
+    from repro.launch.mesh import make_production_mesh, peak_figures
 
+    peaks = peak_figures(TARGET_DEVICE_KIND)
     cfg = get_config(arch)
     shape = SHAPES[shape_name]
     rec: Dict[str, Any] = {
@@ -152,11 +155,8 @@ def run_one(arch: str, shape_name: str, multi_pod: bool,
     # d-independent once the model dim is blocked — recording it per
     # config turns that scaling claim into data the roofline artifacts
     # carry (see docs/STATIC_ANALYSIS.md, vmem-budget rule).
-    try:
-        from repro.analysis.vmem import config_vmem_report
-        rec["gossip_vmem"] = config_vmem_report(arch=arch)[0]
-    except Exception as e:  # advisory record; never fails the dry-run
-        rec["gossip_vmem"] = {"error": repr(e)}
+    from repro.analysis.vmem import config_vmem_report
+    rec["gossip_vmem"] = config_vmem_report(arch=arch)[0]
 
     variant = sp.arch_variant(cfg, shape)
     if param_dtype and variant is not None:
@@ -196,9 +196,9 @@ def run_one(arch: str, shape_name: str, multi_pod: bool,
     flops_dev = float(tca.flops)
     bytes_dev = float(tca.bytes)
     wire_dev = float(tca.wire_bytes)
-    compute_s = flops_dev / PEAK_FLOPS_BF16
-    memory_s = bytes_dev / HBM_BW
-    collective_s = wire_dev / ICI_BW
+    compute_s = flops_dev / peaks.flops_bf16
+    memory_s = bytes_dev / peaks.hbm_bw
+    collective_s = wire_dev / peaks.ici_bw
     dominant = max(("compute", compute_s), ("memory", memory_s),
                    ("collective", collective_s), key=lambda kv: kv[1])[0]
 
@@ -218,8 +218,8 @@ def run_one(arch: str, shape_name: str, multi_pod: bool,
         lower_s=round(t_lower, 1), compile_s=round(t_compile, 1),
         memory={"argument_bytes": arg_b, "temp_bytes": temp_b,
                 "output_bytes": out_b, "alias_bytes": alias_b,
-                "peak_bytes": peak_b, "hbm_bytes": HBM_PER_CHIP,
-                "fits": bool(peak_b <= HBM_PER_CHIP)},
+                "peak_bytes": peak_b, "hbm_bytes": peaks.hbm_bytes,
+                "fits": bool(peak_b <= peaks.hbm_bytes)},
         cost={"flops_per_device": flops_dev, "bytes_per_device": bytes_dev,
               "raw_flops": float(cost.get("flops", 0.0)),
               "raw_bytes": float(cost.get("bytes accessed", 0.0)),

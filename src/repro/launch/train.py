@@ -65,7 +65,17 @@ def build_everything(args):
     return cfg, mesh, tc
 
 
-def main(argv=None) -> None:
+def init_sharded_state(cfg, tc, mesh, seed: int = 0):
+    """The train state, initialized in place with the mode's shardings
+    (``trainer.state_shardings``) instead of whole on one device."""
+    key = jax.random.PRNGKey(seed)
+    shape = tr.init_train_state(cfg, tc, key, mesh, abstract=True)
+    shardings = tr.state_shardings(cfg, tc, mesh, shape)
+    return jax.jit(lambda k: tr.init_train_state(cfg, tc, k, mesh),
+                   out_shardings=shardings)(key)
+
+
+def make_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen1.5-0.5b")
     ap.add_argument("--reduced", action="store_true",
@@ -101,7 +111,11 @@ def main(argv=None) -> None:
     ap.add_argument("--model-parallel", type=int, default=1)
     ap.add_argument("--production-mesh", action="store_true")
     ap.add_argument("--multi-pod", action="store_true")
-    args = ap.parse_args(argv)
+    return ap
+
+
+def main(argv=None) -> None:
+    args = make_parser().parse_args(argv)
 
     cfg, mesh, tc = build_everything(args)
     print(f"arch={cfg.name} params={cfg.param_count() / 1e6:.1f}M "
@@ -109,7 +123,7 @@ def main(argv=None) -> None:
           f"mode={tc.mode} agg={tc.agg.method} attack={tc.attack} "
           f"malicious={tc.n_malicious}/{mesh.shape['data']}")
 
-    state = tr.init_train_state(cfg, tc, jax.random.PRNGKey(0), mesh)
+    state = init_sharded_state(cfg, tc, mesh)
     step_fn = tr.build_train_step(cfg, tc, mesh)
     stream = TokenStream(vocab_size=cfg.vocab_size, seq_len=args.seq_len,
                          batch_size=args.global_batch)

@@ -345,8 +345,7 @@ def attention_fwd(
             # plain-causal train/prefill case; ring-buffer cache masks stay
             # on the chunked-scan path.
             from repro.kernels.flash_attn.ops import flash_attention
-            out = flash_attention(q, k, v, float(1.0 / hd ** 0.5), causal=True,
-                                  interpret=jax.default_backend() != "tpu")
+            out = flash_attention(q, k, v, float(1.0 / hd ** 0.5), causal=True)
         else:
             out = _sdpa_chunked(q, k, v, scale, mask_chunk_fn)
     else:

@@ -279,11 +279,16 @@ def _chunked_ce(cfg: ArchConfig, params: Params, h: Array, labels: Array, mask: 
     """Cross-entropy without materializing (B, S, V) logits: lax.map over
     sequence chunks (vocab up to 256k makes full logits the peak tensor)."""
     B, Ss, d = h.shape
-    C = cfg.loss_chunk
-    nC = Ss // C
-    hc = h[:, : nC * C].reshape(B, nC, C, d).transpose(1, 0, 2, 3)
-    lc = labels[:, : nC * C].reshape(B, nC, C).transpose(1, 0, 2)
-    mc = mask[:, : nC * C].reshape(B, nC, C).transpose(1, 0, 2)
+    C = min(cfg.loss_chunk, Ss)
+    nC = -(-Ss // C)
+    pad = nC * C - Ss          # the ragged last chunk: zero mask, no loss
+    if pad:
+        h = jnp.pad(h, ((0, 0), (0, pad), (0, 0)))
+        labels = jnp.pad(labels, ((0, 0), (0, pad)))
+        mask = jnp.pad(mask, ((0, 0), (0, pad)))
+    hc = h.reshape(B, nC, C, d).transpose(1, 0, 2, 3)
+    lc = labels.reshape(B, nC, C).transpose(1, 0, 2)
+    mc = mask.reshape(B, nC, C).transpose(1, 0, 2)
 
     def chunk_loss(args):
         hx, lx, mx = args

@@ -154,6 +154,15 @@ def batch_shardings(tc: TrainConfig, mesh: Mesh, batch_shape: Any) -> Any:
     return jax.tree.map(lambda s: NamedSharding(mesh, s), specs)
 
 
+def _pin_state(cfg: ArchConfig, tc: TrainConfig, mesh: Mesh,
+               state: TrainState) -> TrainState:
+    """Constrain a step's output state to ``state_shardings``: left to
+    GSPMD, small leaves (the WFAgg-T history) come out sharded unlike
+    the input, and the next step recompiles for the new shardings."""
+    return jax.tree.map(jax.lax.with_sharding_constraint, state,
+                        state_shardings(cfg, tc, mesh, state))
+
+
 # ---------------------------------------------------------------------------
 # step builders
 # ---------------------------------------------------------------------------
@@ -229,7 +238,7 @@ def build_train_step(cfg: ArchConfig, tc: TrainConfig, mesh: Mesh) -> Callable:
                 a = a if a is not None else jnp.zeros((), jnp.float32)
                 return p, o, a, s, m
 
-            out = shd.shard_map_compat(
+            out = jax.shard_map(
                 wrapped,
                 mesh=mesh,
                 in_specs=(P(), P(), P(), P(), bspecs),
@@ -289,7 +298,7 @@ def build_train_step(cfg: ArchConfig, tc: TrainConfig, mesh: Mesh) -> Callable:
         def stacked_step_fn(state: TrainState, batch):
             has_agg = state.agg_state is not None
             bspecs = shd.batch_specs(batch, data_axes=axes, mesh=mesh)
-            grads_stacked, losses = shd.shard_map_compat(
+            grads_stacked, losses = jax.shard_map(
                 grad_worker,
                 mesh=mesh,
                 in_specs=(P(), P(), bspecs),
@@ -335,7 +344,8 @@ def build_train_step(cfg: ArchConfig, tc: TrainConfig, mesh: Mesh) -> Callable:
 
         def jit_step(state, batch):
             with use_sharding(mesh, rules):
-                return step_fn(state, batch)
+                new_state, m = step_fn(state, batch)
+            return _pin_state(cfg, tc, mesh, new_state), m
 
         return jax.jit(jit_step, donate_argnums=(0,) if tc.donate else ())
 
@@ -353,6 +363,7 @@ def build_train_step(cfg: ArchConfig, tc: TrainConfig, mesh: Mesh) -> Callable:
             gn = jnp.sqrt(sum(jnp.sum(g.astype(jnp.float32) ** 2) for g in jax.tree.leaves(grads)))
             m = {"loss": loss, "lr": lr, "grad_norm": gn,
                  "n_accepted": jnp.asarray(K), "weights": jnp.ones((K,), jnp.float32)}
-            return TrainState(new_params, new_opt, None, state.step + 1), m
+            new_state = TrainState(new_params, new_opt, None, state.step + 1)
+        return _pin_state(cfg, tc, mesh, new_state), m
 
     return jax.jit(gspmd_step, donate_argnums=(0,) if tc.donate else ())

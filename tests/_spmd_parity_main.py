@@ -11,8 +11,11 @@ Modes:
           slot-history realignment) vs the same loop single-process
   stacked mode-B robust_allreduce_stacked(backend="reference") jitted
           over the (1, 8) mesh vs the unsharded call
+  nonfinite  one round with a NaN payload: the sharded path sanitizes it
+          (edges that read it demoted) exactly like the one-device round
   engine  two full DFL rounds (train + attack + gossip) with
-          DFLConfig.mesh_model_shards=8 vs the single-process engine
+          DFLConfig.mesh_model_shards=8 vs the single-process engine,
+          and local training not partitioned over the model axis
   lint    python -m repro.analysis over the three sharded entries,
           in-process — must exit 0 (zero gate failures)
   gather_fire  the doctored replicated-output twin of the sharded
@@ -22,6 +25,7 @@ Modes:
 Prints PARITY_OK:<mode> on success so the pytest wrapper can assert on
 stdout rather than exit codes alone.
 """
+import re
 import sys
 
 import numpy as np
@@ -90,6 +94,30 @@ def check_round():
     _close("round prev", new_state.prev, ref_state.prev)
     _close("round hist_s", new_state.hist_s, ref_state.hist_s, atol=1e-4)
     print("PARITY_OK:round")
+
+
+def check_nonfinite():
+    cfg = _cfg()
+    mesh = spmd.aggregation_mesh(8)
+    rng = np.random.default_rng(SEED + 3)
+    models, sched_idx, _ = _fixture(rng)
+    models = models.at[5, 17].set(jnp.nan)
+    idx = sched_idx[0]
+    state = _state(prev=models * 0.97)
+
+    ref_out, _, ref_info = wf.wfagg_batch(
+        models, models, state, cfg, neighbor_idx=idx)
+    out, _, info = spmd.wfagg_batch_sharded(
+        models, models, state, cfg, idx, mesh=mesh)
+    if np.asarray(info["valid"]).all():
+        raise SystemExit("parity FAIL [nonfinite]: no edge was demoted")
+    for m in ("valid", "mask_d", "mask_c", "mask_t"):
+        if not np.array_equal(np.asarray(info[m]), np.asarray(ref_info[m])):
+            raise SystemExit(f"parity FAIL [nonfinite {m}]: masks differ")
+    # node 5's own (non-finite) model anchors its combine on both paths
+    keep = np.arange(N) != 5
+    _close("nonfinite out", np.asarray(out)[keep], np.asarray(ref_out)[keep])
+    print("PARITY_OK:nonfinite")
 
 
 def check_scan():
@@ -163,7 +191,7 @@ def check_engine():
                          seed=0)
     data = SyntheticImages()
     sched = dyn.churn_schedule(topo, 2, seed=1)
-    finals = []
+    finals, compute = [], []
     for shards in (0, 8):
         cfg = DFLConfig(aggregator="wfagg", attack="ipm_100", model="mlp",
                         wfagg_backend="fused_two_launch",
@@ -171,10 +199,20 @@ def check_engine():
         fn = build_round_fn(cfg, topo, data, dynamic=True)
         state = init_dfl_state(cfg, topo, degree=sched.width)
         for r in range(2):
-            state = fn(state, jnp.asarray(sched.neighbor_idx[r]),
-                       jnp.asarray(sched.valid[r]),
-                       jnp.asarray(sched.malicious[r]))
+            args = (jnp.asarray(sched.neighbor_idx[r]),
+                    jnp.asarray(sched.valid[r]),
+                    jnp.asarray(sched.malicious[r]))
+            if r == 0:
+                hlo = fn.lower(state, *args).compile().as_text()
+                compute.append(sorted(set(re.findall(
+                    r"= (\S+) (?:dot|convolution)\(", hlo))))
+            state = fn(state, *args)
         finals.append(state)
+    # local training stays whole on every device: the sharded round's
+    # dots have the one-device round's shapes, none split over 'model'
+    if compute[0] != compute[1]:
+        raise SystemExit(f"parity FAIL [engine compute]: one-device dots "
+                         f"{compute[0]} vs sharded {compute[1]}")
     ref, sh = finals
     for (path, a), (_, b) in zip(
             jax.tree_util.tree_leaves_with_path(ref.node_params),
@@ -223,6 +261,7 @@ if __name__ == "__main__":
     if len(jax.devices()) < 8:
         raise SystemExit("need 8 devices — set XLA_FLAGS="
                          "--xla_force_host_platform_device_count=8")
-    {"round": check_round, "scan": check_scan, "stacked": check_stacked,
+    {"round": check_round, "nonfinite": check_nonfinite,
+     "scan": check_scan, "stacked": check_stacked,
      "engine": check_engine, "lint": check_lint,
      "gather_fire": check_gather_fire}[mode]()

@@ -1,20 +1,9 @@
-"""Test-suite bootstrap.
-
-Installs the deterministic ``hypothesis`` fallback shim when the real
-package is absent (see _hypothesis_fallback.py), so the property tests
-collect and run everywhere, and registers the ``slow`` marker.
-"""
+"""Test-suite bootstrap: puts the tests directory on the import path (for
+the helper modules the tests share) and registers the ``slow`` marker."""
 import os
 import sys
 
 sys.path.insert(0, os.path.dirname(__file__))
-
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    import _hypothesis_fallback
-
-    _hypothesis_fallback.install()
 
 
 def pytest_configure(config):

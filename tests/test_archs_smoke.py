@@ -10,6 +10,7 @@ from repro.configs.registry import ARCHS
 from repro.core.wfagg import WFAggConfig
 from repro.data.specs import ENC_LEN_DECODE, dummy_batch
 from repro.distributed.robust_allreduce import RobustAggConfig
+from repro.launch.mesh import make_test_mesh
 from repro.models import model as M
 from repro.train.trainer import TrainConfig, build_train_step, init_train_state
 
@@ -17,7 +18,7 @@ ARCH_NAMES = sorted(ARCHS)
 
 
 def _mesh():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_test_mesh(1, 1)
 
 
 @pytest.mark.parametrize("name", ARCH_NAMES)
@@ -77,3 +78,19 @@ def test_full_config_metadata(name):
         "llava-next-34b": (30e9, 38e9),
     }[name]
     assert expected[0] <= n <= expected[1], (name, n)
+
+
+@pytest.mark.parametrize("chunk", [8, 64])
+def test_chunked_loss_counts_every_position(chunk):
+    """Chunked cross-entropy equals the unchunked loss when the sequence
+    (31 predicted positions) is not a multiple of the chunk, and when it
+    is shorter than one chunk: the ragged tail is not dropped."""
+    import dataclasses
+
+    cfg = ARCHS["qwen1.5-0.5b"].reduced()
+    params = M.init_params(cfg, jax.random.PRNGKey(0))
+    batch = dummy_batch(cfg, 2, 32)
+    full, _ = M.loss_fn(dataclasses.replace(cfg, loss_chunk=0), params, batch)
+    chunked, _ = M.loss_fn(dataclasses.replace(cfg, loss_chunk=chunk),
+                           params, batch)
+    np.testing.assert_allclose(float(chunked), float(full), rtol=1e-5)

@@ -85,8 +85,8 @@ def test_grad_of_scan_counts_backward_pass():
 def test_collectives_inside_scan_multiplied(monkeypatch):
     if jax.device_count() < 4:
         pytest.skip("needs forced host devices")
-    from jax.sharding import PartitionSpec as P
-    mesh = jax.make_mesh((4,), ("data",))
+    from jax.sharding import AxisType, PartitionSpec as P
+    mesh = jax.make_mesh((4,), ("data",), axis_types=(AxisType.Auto,))
     N, C, D = 4, 6, 1024
 
     def f(x):
@@ -96,9 +96,8 @@ def test_collectives_inside_scan_multiplied(monkeypatch):
         acc, _ = jax.lax.scan(body, 0.0, x)
         return acc
 
-    from repro.distributed.sharding import shard_map_compat
-    sf = shard_map_compat(f, mesh=mesh, in_specs=(P(None, None),),
-                          out_specs=P(), check_vma=False)
+    sf = jax.shard_map(f, mesh=mesh, in_specs=(P(None, None),),
+                       out_specs=P(), check_vma=False)
     xs = jax.ShapeDtypeStruct((C, D), jnp.float32)
     compiled = jax.jit(sf).lower(xs).compile()
     c = ha.analyze(compiled.as_text(), n_devices=4)

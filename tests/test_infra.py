@@ -111,3 +111,51 @@ def test_microbatched_gradients_match_full_batch():
     assert float(outs[1][0]) == pytest.approx(float(outs[4][0]), rel=1e-5)
     for a, b in zip(jax.tree.leaves(outs[1][1]), jax.tree.leaves(outs[4][1])):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-6)
+
+
+def _chip_smoke():
+    import importlib.util
+    path = os.path.join(os.path.dirname(__file__), "..", "chip_smoke.py")
+    spec = importlib.util.spec_from_file_location("chip_smoke", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@pytest.mark.parametrize("argv", [[], ["--chips", "4"]], ids=["fleet", "multi"])
+def test_chip_smoke_refuses_non_tpu_backend(argv, capsys):
+    """Without a TPU the bring-up check stops before any JAX work: a
+    non-zero exit and no ``"ok"`` line, never a CPU fallback."""
+    if jax.devices()[0].platform == "tpu":
+        pytest.skip("a TPU is attached")
+    with pytest.raises(SystemExit) as e:
+        _chip_smoke().main(argv)
+    assert e.value.code not in (0, None)
+    assert '"ok"' not in capsys.readouterr().out
+
+
+def test_compile_cache_placement(monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR wins when set; otherwise the cache sits
+    at the fixed <repo>/.jax_cache, never a per-run directory."""
+    from repro.launch import device as dev
+
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert dev.use_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == str(tmp_path)
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = dev.use_compile_cache()
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert path == os.path.join(repo, ".jax_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+
+
+def test_peak_figures_keyed_by_device_kind():
+    from repro.launch.mesh import peak_figures
+
+    v5e = peak_figures("TPU v5 lite")
+    assert (v5e.flops_bf16, v5e.hbm_bytes, v5e.hbm_bw) == (197e12, 16e9, 819e9)
+    with pytest.raises(KeyError):
+        peak_figures("cpu")

@@ -198,8 +198,8 @@ def _run_8dev(argv, timeout=1200):
 
 
 @pytest.mark.slow
-@pytest.mark.parametrize("mode", ["round", "scan", "stacked", "engine",
-                                  "gather_fire"])
+@pytest.mark.parametrize("mode", ["round", "nonfinite", "scan", "stacked",
+                                  "engine", "gather_fire"])
 def test_spmd_parity_8dev(mode):
     out = _run_8dev([PARITY, mode])
     assert f"PARITY_OK:{mode}" in out

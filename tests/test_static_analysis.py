@@ -100,3 +100,23 @@ def test_parse_suppressions():
     assert sup["no-nkd-buffer"] == {"reference_round", "other"}
     with pytest.raises(ValueError):
         parse_suppressions(["not-a-rule"])
+
+
+def test_block_shapes_read_from_pallas_block_objects():
+    """Block dims arrive as Pallas ``Blocked``/``Squeezed`` objects; the
+    VMEM model must read their sizes (a squeezed dim counts 1)."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.analysis.artifacts import collect_pallas_calls
+    from repro.kernels.robust_stats.ops import robust_stats_indexed
+
+    N, K, d, T = 4, 3, 512, 256
+    jaxpr = jax.make_jaxpr(lambda m, i: robust_stats_indexed(
+        m, i, block_d=T, interpret=True))(
+        jax.ShapeDtypeStruct((N, d), jnp.float32),
+        jax.ShapeDtypeStruct((N, K), jnp.int32))
+    (info,) = collect_pallas_calls(jaxpr.jaxpr)
+    shapes = [b.block_shape for b in info.blocks]
+    assert (1, 1, K) in shapes and (1, 1, T) in shapes, shapes
+    assert info.scratch_bytes == K * T * 4

@@ -249,9 +249,8 @@ def test_robust_allreduce_consensus_identical_output():
         out, _, info = robust_allreduce(x, "data", cfg, None)
         return out, info["weights"]
 
-    from repro.distributed.sharding import shard_map_compat
-    sf = shard_map_compat(fn, mesh=mesh, in_specs=(P("data"),),
-                          out_specs=(P(), P()), check_vma=False)
+    sf = jax.shard_map(fn, mesh=mesh, in_specs=(P("data"),),
+                       out_specs=(P(), P()), check_vma=False)
     x = jax.random.normal(jax.random.PRNGKey(0), (4 * d,))
     out, w = jax.jit(sf)(x)
     assert out.shape == (d,)
@@ -280,10 +279,9 @@ def test_stacked_layout_matches_flat_layout():
         out, _, info = robust_allreduce(flat, "data", cfg, None)
         return unravel(out), info["weights"]
 
-    from repro.distributed.sharding import shard_map_compat
-    sf = shard_map_compat(flat_fn, mesh=mesh, in_specs=(P("data"), P("data")),
-                          out_specs=(({"a": P(), "b": P()}), P()),
-                          check_vma=False)
+    sf = jax.shard_map(flat_fn, mesh=mesh, in_specs=(P("data"), P("data")),
+                       out_specs=(({"a": P(), "b": P()}), P()),
+                       check_vma=False)
     (oa_f, w_f) = jax.jit(sf)(grads["a"], grads["b"])
 
     # stacked path is pure GSPMD — call it directly on the (K, ...) arrays
@@ -328,3 +326,21 @@ def test_stacked_attack_matches_distributed_semantics():
                                jax.random.PRNGKey(1))["w"]
     np.testing.assert_allclose(np.asarray(out[1]), -np.asarray(g[1]))
     np.testing.assert_allclose(np.asarray(out[2]), np.asarray(g[2]))
+
+
+def test_axis_helpers_over_candidate_axis_tuple():
+    """``axis_size`` / ``my_index`` over the multi-pod candidate axes
+    ('pod', 'data') — JAX's own axis_size / axis_index on a tuple."""
+    from jax.sharding import PartitionSpec as P
+    from repro.distributed.robust_allreduce import axis_size, my_index
+
+    n = jax.device_count()
+    mesh = make_test_mesh(data=n, model=1, pod=1)
+    axes = ("pod", "data")
+    f = jax.shard_map(
+        lambda x: (x + my_index(axes), x + axis_size(axes)),
+        mesh=mesh, in_specs=P(axes), out_specs=(P(axes), P(axes)),
+        check_vma=False)
+    idx, size = f(jnp.zeros((n,), jnp.int32))
+    assert idx.tolist() == list(range(n))
+    assert size.tolist() == [n] * n
