@@ -10,7 +10,7 @@ measured under BOTH backends so the fused-vs-reference pass-count win is
 visible in every run, and every invocation appends its rows to the
 ``BENCH_agg.json`` trajectory so later PRs can regress against it.
 
-Timing methodology (shared with ``repro.obs.profile``): the FIRST call
+Timing methodology: the FIRST call
 — trace + compile + one execution — is reported as its own
 ``compile_us`` column; ``us_per_call`` (and the GBps derived from it) is
 the MEDIAN of ``reps`` further calls, each synchronized with its own

@@ -50,6 +50,7 @@ from repro.kernels.robust_stats.ops import (
     wfagg_round_indexed)
 from repro.kernels.robust_stats.ref import RobustStats, robust_stats_indexed_ref
 from repro.kernels.weighted_agg.ops import weighted_agg, weighted_agg_indexed
+from repro.obs import profile as obs_profile
 
 Array = jax.Array
 _EPS = 1e-12
@@ -512,12 +513,13 @@ def sanitize_round(models: Array, valid_b: Array,
     before any statistic: a corrupted payload degrades to a rejected
     slot instead of poisoning the median.  Returns ``(models, valid_b,
     state)``; a no-op on finite inputs."""
-    finite = jnp.isfinite(models).all(axis=-1)
-    models = jnp.where(finite[:, None], models, 0.0)
-    valid_b = valid_b & finite[neighbor_idx]
-    if cfg.use_temporal and state is not None:
-        pf = jnp.isfinite(state.prev).all(axis=-1)
-        state = state._replace(prev=jnp.where(pf[..., None], state.prev, 0.0))
+    with obs_profile.phase("sanitize"):
+        finite = jnp.isfinite(models).all(axis=-1)
+        models = jnp.where(finite[:, None], models, 0.0)
+        valid_b = valid_b & finite[neighbor_idx]
+        if cfg.use_temporal and state is not None:
+            pf = jnp.isfinite(state.prev).all(axis=-1)
+            state = state._replace(prev=jnp.where(pf[..., None], state.prev, 0.0))
     return models, valid_b, state
 
 

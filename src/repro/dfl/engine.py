@@ -41,6 +41,7 @@ from repro.core.topology import Topology, TopologySchedule
 from repro.data.synthetic import SyntheticImages
 from repro.dfl import faults as flt
 from repro.obs import decision as obs_decision
+from repro.obs import profile as obs_profile
 from repro.models.lenet import init_lenet, init_mlp_classifier, lenet_fwd, mlp_classifier_fwd
 
 Array = jax.Array
@@ -154,13 +155,15 @@ def _local_train(cfg: DFLConfig, data: SyntheticImages, malicious: Array,
     def node_train(node_id, params_i, mom_i):
         def one_batch(carry, b):
             params_i, mom_i = carry
-            key = jax.random.fold_in(
-                jax.random.fold_in(jax.random.PRNGKey(data.seed), node_id), rnd * 1000 + b
-            )
-            imgs, labels = data.batch(key, p.batch_size)
-            if label_flip:
-                bad = malicious[node_id]
-                labels = jnp.where(bad, atk.flip_labels(labels, data.n_classes), labels)
+            with obs_profile.phase("data"):
+                key = jax.random.fold_in(
+                    jax.random.fold_in(jax.random.PRNGKey(data.seed), node_id),
+                    rnd * 1000 + b)
+                imgs, labels = data.batch(key, p.batch_size)
+                if label_flip:
+                    bad = malicious[node_id]
+                    labels = jnp.where(bad, atk.flip_labels(labels, data.n_classes),
+                                       labels)
 
             def loss(pp):
                 return met.cross_entropy(fwd(pp, imgs), labels)
@@ -418,83 +421,87 @@ def _make_round_core(cfg: DFLConfig, data: SyntheticImages,
         # global model (captured before local training — the mean of
         # freshly-received models would itself be poisoned under IPM).
         prev_flat, _ = _ravel_nodes(state.node_params)
-        params, momentum = _local_train(
-            cfg, data, mal_mask, state.node_params, state.node_momentum,
-            state.rnd
-        )
-        flat, unravel_one = _ravel_nodes(params)
-        view = _defense_view(cfg, state, neighbor_idx, neighbor_valid)
-        flat = _apply_attacks(cfg, mal_mask, flat, state.rnd, view)
-
-        record = None
-        if cfg.centralized:
-            if telemetry:
-                raise NotImplementedError(
-                    "telemetry records per-EDGE gossip verdicts; the CFL "
-                    "baseline has one server and no edges")
-            # one server-side aggregation over all N received models
-            t0 = jax.tree.map(lambda x: x[0], state.temporal) if state.temporal is not None else None
-            global_prev = prev_flat[0]  # all nodes share the global model in CFL
-            new_global, new_t0 = _aggregate_one(cfg, global_prev, flat, t0)
-            new_flat = jnp.broadcast_to(new_global, flat.shape)
-            new_temporal = (
-                jax.tree.map(lambda x: x[None], new_t0) if new_t0 is not None else None
+        with obs_profile.phase("local_train"):
+            params, momentum = _local_train(
+                cfg, data, mal_mask, state.node_params, state.node_momentum,
+                state.rnd
             )
-        else:
-            if cfg.aggregator in ("wfagg", "alt_wfagg"):
-                # gather-free gossip: all N per-node aggregations in one
-                # neighbor-indexed kernel launch — the (N, K, d) gossip
-                # tensor never exists, the kernels DMA each neighbor's
-                # d-blocks straight from the (N, d) model matrix (the
-                # reference backend gathers, for parity runs)
-                wcfg = _wfagg_full_config(cfg, neighbor_idx.shape[1])
-                if cfg.mesh_model_shards > 1:
-                    from repro.distributed import spmd
-                    mesh = spmd.aggregation_mesh(cfg.mesh_model_shards)
-                    # the round boundary is replicated: local training
-                    # and evaluation stay whole on every device
-                    flat, t_in = spmd.replicate((flat, state.temporal), mesh)
-                    new_flat, new_temporal, info = spmd.wfagg_batch_sharded(
-                        flat, flat, t_in, wcfg,
-                        neighbor_idx, neighbor_valid, mesh=mesh)
-                    new_flat, new_temporal = spmd.replicate(
-                        (new_flat, new_temporal), mesh)
-                else:
-                    new_flat, new_temporal, info = wf.wfagg_batch(
-                        flat, flat, state.temporal, wcfg,
-                        neighbor_idx=neighbor_idx, valid=neighbor_valid)
-                if telemetry:
-                    # the indexed info dict carries the full 2-of-3 vote
-                    # (mask_d/mask_c/mask_t/valid/weights) — pack it
-                    record = obs_decision.record_from_info(info)
-            elif state.temporal is not None:
-                gathered = flat[neighbor_idx]  # (N, K, d) gossip exchange
-                new_flat, new_temporal = jax.vmap(
-                    lambda loc, upd, ts: _aggregate_one(
-                        cfg, loc, upd, ts, wfagg_backend="reference")
-                )(flat, gathered, state.temporal)
-            elif neighbor_valid is not None:
-                # baseline aggregators on a padded/dynamic slate: gossip
-                # gather + the valid-mask-aware DYN_AGGREGATORS variants
-                gathered = flat[neighbor_idx]  # (N, K, d) gossip exchange
-                new_flat = jax.vmap(
-                    lambda loc, upd, v: _aggregate_one_dyn(cfg, loc, upd, v)
-                )(flat, gathered, neighbor_valid)
-                new_temporal = None
-            else:
-                gathered = flat[neighbor_idx]  # (N, K, d) gossip exchange
-                new_flat, _ = jax.vmap(
-                    lambda loc, upd: _aggregate_one(cfg, loc, upd, None)
-                )(flat, gathered)
-                new_temporal = None
-            if telemetry and record is None:
-                # baselines have no per-edge filter verdicts: uniform
-                # accept over the valid slate (degree-0 still tracked)
-                valid_all = (neighbor_valid if neighbor_valid is not None
-                             else jnp.ones(neighbor_idx.shape, bool))
-                record = obs_decision.record_uniform(valid_all)
+            flat, unravel_one = _ravel_nodes(params)
+        with obs_profile.phase("attack"):
+            view = _defense_view(cfg, state, neighbor_idx, neighbor_valid)
+            flat = _apply_attacks(cfg, mal_mask, flat, state.rnd, view)
 
-        new_params = jax.vmap(unravel_one)(new_flat)
+        with obs_profile.phase("aggregate"):
+            record = None
+            if cfg.centralized:
+                if telemetry:
+                    raise NotImplementedError(
+                        "telemetry records per-EDGE gossip verdicts; the CFL "
+                        "baseline has one server and no edges")
+                # one server-side aggregation over all N received models
+                t0 = (jax.tree.map(lambda x: x[0], state.temporal)
+                      if state.temporal is not None else None)
+                global_prev = prev_flat[0]  # all nodes share the global model in CFL
+                new_global, new_t0 = _aggregate_one(cfg, global_prev, flat, t0)
+                new_flat = jnp.broadcast_to(new_global, flat.shape)
+                new_temporal = (
+                    jax.tree.map(lambda x: x[None], new_t0) if new_t0 is not None else None
+                )
+            else:
+                if cfg.aggregator in ("wfagg", "alt_wfagg"):
+                    # gather-free gossip: all N per-node aggregations in one
+                    # neighbor-indexed kernel launch — the (N, K, d) gossip
+                    # tensor never exists, the kernels DMA each neighbor's
+                    # d-blocks straight from the (N, d) model matrix (the
+                    # reference backend gathers, for parity runs)
+                    wcfg = _wfagg_full_config(cfg, neighbor_idx.shape[1])
+                    if cfg.mesh_model_shards > 1:
+                        from repro.distributed import spmd
+                        mesh = spmd.aggregation_mesh(cfg.mesh_model_shards)
+                        # the round boundary is replicated: local training
+                        # and evaluation stay whole on every device
+                        flat, t_in = spmd.replicate((flat, state.temporal), mesh)
+                        new_flat, new_temporal, info = spmd.wfagg_batch_sharded(
+                            flat, flat, t_in, wcfg,
+                            neighbor_idx, neighbor_valid, mesh=mesh)
+                        new_flat, new_temporal = spmd.replicate(
+                            (new_flat, new_temporal), mesh)
+                    else:
+                        new_flat, new_temporal, info = wf.wfagg_batch(
+                            flat, flat, state.temporal, wcfg,
+                            neighbor_idx=neighbor_idx, valid=neighbor_valid)
+                    if telemetry:
+                        # the indexed info dict carries the full 2-of-3 vote
+                        # (mask_d/mask_c/mask_t/valid/weights) — pack it
+                        record = obs_decision.record_from_info(info)
+                elif state.temporal is not None:
+                    gathered = flat[neighbor_idx]  # (N, K, d) gossip exchange
+                    new_flat, new_temporal = jax.vmap(
+                        lambda loc, upd, ts: _aggregate_one(
+                            cfg, loc, upd, ts, wfagg_backend="reference")
+                    )(flat, gathered, state.temporal)
+                elif neighbor_valid is not None:
+                    # baseline aggregators on a padded/dynamic slate: gossip
+                    # gather + the valid-mask-aware DYN_AGGREGATORS variants
+                    gathered = flat[neighbor_idx]  # (N, K, d) gossip exchange
+                    new_flat = jax.vmap(
+                        lambda loc, upd, v: _aggregate_one_dyn(cfg, loc, upd, v)
+                    )(flat, gathered, neighbor_valid)
+                    new_temporal = None
+                else:
+                    gathered = flat[neighbor_idx]  # (N, K, d) gossip exchange
+                    new_flat, _ = jax.vmap(
+                        lambda loc, upd: _aggregate_one(cfg, loc, upd, None)
+                    )(flat, gathered)
+                    new_temporal = None
+                if telemetry and record is None:
+                    # baselines have no per-edge filter verdicts: uniform
+                    # accept over the valid slate (degree-0 still tracked)
+                    valid_all = (neighbor_valid if neighbor_valid is not None
+                                 else jnp.ones(neighbor_idx.shape, bool))
+                    record = obs_decision.record_uniform(valid_all)
+
+            new_params = jax.vmap(unravel_one)(new_flat)
         new_state = DFLState(new_params, momentum, new_temporal, state.rnd + 1)
         if telemetry:
             return new_state, record
@@ -535,82 +542,89 @@ def _make_chaos_round_core(cfg: DFLConfig, data: SyntheticImages,
     def round_core(state: DFLState, neighbor_idx: Array,
                    neighbor_valid: Optional[Array], mal_mask: Array,
                    ts: flt.TransportState, fr: flt.FaultRound):
-        prev_flat, _ = _ravel_nodes(state.node_params)
-        params, momentum = _local_train(
-            cfg, data, mal_mask, state.node_params, state.node_momentum,
-            state.rnd
-        )
-        flat, unravel_one = _ravel_nodes(params)
-        view = _defense_view(cfg, state, neighbor_idx, neighbor_valid)
-        flat = _apply_attacks(cfg, mal_mask, flat, state.rnd, view)
-        # crash freeze: a down node broadcasts (and keeps) its stored
-        # model; its training step and momentum advance are discarded
-        down = fr.down.astype(bool)
-        flat = jnp.where(down[:, None], prev_flat, flat)
-        momentum = jax.tree.map(
-            lambda old, new: jnp.where(
-                down.reshape((-1,) + (1,) * (new.ndim - 1)), old, new),
-            state.node_momentum, momentum)
+        with obs_profile.phase("transport"):
+            # the stored models a crashed node keeps broadcasting
+            prev_flat, _ = _ravel_nodes(state.node_params)
+        with obs_profile.phase("local_train"):
+            params, momentum = _local_train(
+                cfg, data, mal_mask, state.node_params, state.node_momentum,
+                state.rnd
+            )
+            flat, unravel_one = _ravel_nodes(params)
+        with obs_profile.phase("attack"):
+            view = _defense_view(cfg, state, neighbor_idx, neighbor_valid)
+            flat = _apply_attacks(cfg, mal_mask, flat, state.rnd, view)
+        with obs_profile.phase("transport"):
+            # crash freeze: a down node broadcasts (and keeps) its stored
+            # model; its training step and momentum advance are discarded
+            down = fr.down.astype(bool)
+            flat = jnp.where(down[:, None], prev_flat, flat)
+            momentum = jax.tree.map(
+                lambda old, new: jnp.where(
+                    down.reshape((-1,) + (1,) * (new.ndim - 1)), old, new),
+                state.node_momentum, momentum)
 
-        valid = (neighbor_valid if neighbor_valid is not None
-                 else jnp.ones(neighbor_idx.shape, bool))
-        tout = flt.apply_transport(flat, ts, neighbor_idx, valid, fr, fcfg,
-                                   state.rnd)
+            valid = (neighbor_valid if neighbor_valid is not None
+                     else jnp.ones(neighbor_idx.shape, bool))
+            tout = flt.apply_transport(flat, ts, neighbor_idx, valid, fr, fcfg,
+                                       state.rnd)
 
-        record = None
-        if cfg.aggregator in ("wfagg", "alt_wfagg"):
-            wcfg = _wfagg_full_config(cfg, neighbor_idx.shape[1])
-            t_in = state.temporal
-            mu_s = mu_b = None
-            matrix_prev = t_in is not None and t_in.prev.ndim == 2
-            if matrix_prev:
-                if wcfg.use_temporal:
-                    # pre-round EWMA centers: the hygiene value a no-
-                    # delivery edge pushes instead of a garbage metric
-                    mu_s, _ = jax.vmap(
-                        lambda h, c: trust.ewma_mean_std(h, c, wcfg.ewma_decay)
-                    )(t_in.hist_s, t_in.count)
-                    mu_b, _ = jax.vmap(
-                        lambda h, c: trust.ewma_mean_std(h, c, wcfg.ewma_decay)
-                    )(t_in.hist_b, t_in.count)
-                # the carried (N, d) prev is superseded by the stacked
-                # matrix + prev_idx (the payload each edge ACTUALLY
-                # served last round, aged one round)
-                t_in = t_in._replace(prev=tout.full)
-            new_flat, new_temporal, info = wf.wfagg_batch(
-                flat, tout.full, t_in, wcfg,
-                neighbor_idx=tout.eff_idx, valid=tout.eff_valid,
-                prev_idx=tout.prev_idx)
-            if matrix_prev and new_temporal is not None:
-                hist_s, hist_b = new_temporal.hist_s, new_temporal.hist_b
-                if mu_s is not None:
-                    hist_s = hist_s.at[:, 0, :].set(
-                        jnp.where(tout.eff_valid, hist_s[:, 0, :], mu_s))
-                    hist_b = hist_b.at[:, 0, :].set(
-                        jnp.where(tout.eff_valid, hist_b[:, 0, :], mu_b))
-                new_temporal = new_temporal._replace(
-                    prev=flat, hist_s=hist_s, hist_b=hist_b)
+        with obs_profile.phase("aggregate"):
+            record = None
+            if cfg.aggregator in ("wfagg", "alt_wfagg"):
+                wcfg = _wfagg_full_config(cfg, neighbor_idx.shape[1])
+                t_in = state.temporal
+                mu_s = mu_b = None
+                matrix_prev = t_in is not None and t_in.prev.ndim == 2
+                if matrix_prev:
+                    if wcfg.use_temporal:
+                        # pre-round EWMA centers: the hygiene value a no-
+                        # delivery edge pushes instead of a garbage metric
+                        mu_s, _ = jax.vmap(
+                            lambda h, c: trust.ewma_mean_std(h, c, wcfg.ewma_decay)
+                        )(t_in.hist_s, t_in.count)
+                        mu_b, _ = jax.vmap(
+                            lambda h, c: trust.ewma_mean_std(h, c, wcfg.ewma_decay)
+                        )(t_in.hist_b, t_in.count)
+                    # the carried (N, d) prev is superseded by the stacked
+                    # matrix + prev_idx (the payload each edge ACTUALLY
+                    # served last round, aged one round)
+                    t_in = t_in._replace(prev=tout.full)
+                new_flat, new_temporal, info = wf.wfagg_batch(
+                    flat, tout.full, t_in, wcfg,
+                    neighbor_idx=tout.eff_idx, valid=tout.eff_valid,
+                    prev_idx=tout.prev_idx)
+                if matrix_prev and new_temporal is not None:
+                    hist_s, hist_b = new_temporal.hist_s, new_temporal.hist_b
+                    if mu_s is not None:
+                        hist_s = hist_s.at[:, 0, :].set(
+                            jnp.where(tout.eff_valid, hist_s[:, 0, :], mu_s))
+                        hist_b = hist_b.at[:, 0, :].set(
+                            jnp.where(tout.eff_valid, hist_b[:, 0, :], mu_b))
+                    new_temporal = new_temporal._replace(
+                        prev=flat, hist_s=hist_s, hist_b=hist_b)
+                if telemetry:
+                    record = obs_decision.record_from_info(info)
+            else:
+                # baselines gather (they already do on the dynamic path);
+                # the valid-aware variants see the post-fault slate
+                gathered = tout.full[tout.eff_idx]
+                new_flat = jax.vmap(
+                    lambda loc, upd, v: _aggregate_one_dyn(cfg, loc, upd, v)
+                )(flat, gathered, tout.eff_valid)
+                new_temporal = None
+                if telemetry:
+                    record = obs_decision.record_uniform(tout.eff_valid)
             if telemetry:
-                record = obs_decision.record_from_info(info)
-        else:
-            # baselines gather (they already do on the dynamic path);
-            # the valid-aware variants see the post-fault slate
-            gathered = tout.full[tout.eff_idx]
-            new_flat = jax.vmap(
-                lambda loc, upd, v: _aggregate_one_dyn(cfg, loc, upd, v)
-            )(flat, gathered, tout.eff_valid)
-            new_temporal = None
-            if telemetry:
-                record = obs_decision.record_uniform(tout.eff_valid)
-        if telemetry:
-            record = obs_decision.with_fault_bits(
-                record, tout.dropped, tout.stale, tout.corrupt)
+                record = obs_decision.with_fault_bits(
+                    record, tout.dropped, tout.stale, tout.corrupt)
 
-        # a down receiver aggregates nothing (its slate is all-invalid so
-        # this is already true on the wfagg path; make it explicit)
-        new_flat = jnp.where(down[:, None], prev_flat, new_flat)
-        new_params = jax.vmap(unravel_one)(new_flat)
-        new_ts = flt.advance_ring(ts, flat, tout.served_lag)
+            # a down receiver aggregates nothing (its slate is all-invalid so
+            # this is already true on the wfagg path; make it explicit)
+            new_flat = jnp.where(down[:, None], prev_flat, new_flat)
+            new_params = jax.vmap(unravel_one)(new_flat)
+        with obs_profile.phase("transport"):
+            new_ts = flt.advance_ring(ts, flat, tout.served_lag)
         new_state = DFLState(new_params, momentum, new_temporal,
                              state.rnd + 1)
         if telemetry:
@@ -813,14 +827,15 @@ def build_dynamic_scan_fn(cfg: DFLConfig, topo: Topology,
     ever_mal = jnp.asarray(schedule.malicious.any(axis=0))
 
     def eval_out(st):
-        accs = jax.vmap(
-            lambda p: met.micro_accuracy(fwd(p, imgs), labels)
-        )(st.node_params)
-        benign = ~ever_mal
-        bw = benign.astype(jnp.float32)
-        acc_benign = jnp.sum(accs * bw) / jnp.maximum(bw.sum(), 1.0)
-        flat, _ = _ravel_nodes(st.node_params)
-        return (accs, acc_benign, met.r_squared(flat, weights=bw))
+        with obs_profile.phase("evaluate"):
+            accs = jax.vmap(
+                lambda p: met.micro_accuracy(fwd(p, imgs), labels)
+            )(st.node_params)
+            benign = ~ever_mal
+            bw = benign.astype(jnp.float32)
+            acc_benign = jnp.sum(accs * bw) / jnp.maximum(bw.sum(), 1.0)
+            flat, _ = _ravel_nodes(st.node_params)
+            return (accs, acc_benign, met.r_squared(flat, weights=bw))
 
     if faults is not None:
         if faults.rounds != schedule.rounds:
@@ -840,12 +855,14 @@ def build_dynamic_scan_fn(cfg: DFLConfig, topo: Topology,
                 idx, val, mal = xs[:3]
                 fr = flt.FaultRound(*xs[3:])
                 if st.temporal is not None:
-                    st = st._replace(temporal=wf.realign_temporal_history(
-                        st.temporal, prev_idx, prev_val, idx, val))
+                    with obs_profile.phase("aggregate"):
+                        st = st._replace(temporal=wf.realign_temporal_history(
+                            st.temporal, prev_idx, prev_val, idx, val))
                 # the served-lag table is slot-keyed like the temporal
                 # history: re-key it to this round's slate too
-                ts = ts._replace(served_lag=flt.realign_served_lag(
-                    ts.served_lag, prev_idx, prev_val, idx, val))
+                with obs_profile.phase("transport"):
+                    ts = ts._replace(served_lag=flt.realign_served_lag(
+                        ts.served_lag, prev_idx, prev_val, idx, val))
                 if telemetry:
                     st, ts, record = round_core(st, idx, val, mal, ts, fr)
                 else:
@@ -873,8 +890,9 @@ def build_dynamic_scan_fn(cfg: DFLConfig, topo: Topology,
                 # this round's slate by neighbor IDENTITY, so a neighbor
                 # that shifted slots (or rejoined) is scored against ITS
                 # history, not whoever held the slot before
-                st = st._replace(temporal=wf.realign_temporal_history(
-                    st.temporal, prev_idx, prev_val, idx, val))
+                with obs_profile.phase("aggregate"):
+                    st = st._replace(temporal=wf.realign_temporal_history(
+                        st.temporal, prev_idx, prev_val, idx, val))
             if telemetry:
                 st, record = round_core(st, idx, val, mal)
             else:

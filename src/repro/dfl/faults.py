@@ -59,6 +59,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.topology import TopologySchedule
+from repro.obs import profile as obs_profile
 
 Array = jax.Array
 
@@ -193,9 +194,10 @@ def apply_transport(flat: Array, ts: TransportState, neighbor_idx: Array,
     eff_idx = jnp.where(corrupt, (L + 1) * M + slot, eff_idx)
 
     # --- sanitizer: the kernels must never see a non-finite row ----------
-    finite = jnp.isfinite(full).all(axis=1)
-    full = jnp.where(finite[:, None], full, 0.0)
-    eff_valid = eff_valid & finite[eff_idx]
+    with obs_profile.phase("sanitize"):
+        finite = jnp.isfinite(full).all(axis=1)
+        full = jnp.where(finite[:, None], full, 0.0)
+        eff_valid = eff_valid & finite[eff_idx]
 
     # --- staleness pricing: where was last round's delivery? -------------
     # the payload edge (n, k) served last round is one round older now;

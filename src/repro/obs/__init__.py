@@ -7,8 +7,9 @@ Three planes, three modules:
   per-node summaries, emitted as pure traced outputs of the round/scan
   (import-light: engine and mode-B depend on it, so this package
   ``__init__`` must NOT pull in the heavier planes below).
-- :mod:`repro.obs.profile` — compile-vs-steady wall clock, named scopes
-  / TraceAnnotations, achieved-bytes/s via the ``memory_passes`` table.
+- :mod:`repro.obs.profile` — the round's ``phase.*`` scopes on the
+  device trace, host TraceAnnotations, achieved-bytes/s via the
+  ``memory_passes`` table.
 - :mod:`repro.obs.recorder` / :mod:`repro.obs.trace` /
   ``python -m repro.obs.report`` — JSONL event log, Chrome/Perfetto
   ``trace_event`` export, and the per-filter audit tables.

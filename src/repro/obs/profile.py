@@ -1,59 +1,53 @@
-"""Timing plane: compile-vs-steady wall clock, profiler scopes and the
-achieved-bandwidth join against the ``memory_passes`` traffic table.
+"""Timing plane: the round's phases on the device trace, host spans for
+the profiler, and the achieved-bandwidth join against the
+``memory_passes`` traffic table.
 
-Everything here is host-side instrumentation AROUND jitted computations
-— nothing in this module enters a traced region, so the decision plane's
-no-host-transfer-in-scan guarantee is untouched.  The one JAX-profiler
-integration is opt-in: :func:`annotate` wraps a round in
-``jax.named_scope`` + ``jax.profiler.TraceAnnotation`` (so device traces
-attribute time to rounds), and :func:`capture` brackets a run with
-``jax.profiler.trace`` for a full TensorBoard/Perfetto device capture on
-TPU runs.
-
-Timing methodology (shared with ``benchmarks/agg_microbench.py``): the
-FIRST call is trace + compile + one execution and is reported as its own
-number; steady state is the median over ``reps`` further calls, each
-individually synchronized with ``block_until_ready`` — an async dispatch
-queue otherwise attributes every round's device time to whichever call
-finally blocks.
+:func:`phase` is the one piece that enters traced code: a
+``jax.named_scope`` opened while a round is traced, so every HLO op the
+phase emits carries ``phase.<name>`` in its ``metadata={op_name=...}``
+and a device trace can charge the op's time to the phase
+(``bench/trace.py`` ``phase_map``).  A scope changes metadata only —
+fusion and scheduling ignore it — so the phases are always on and cost
+nothing when no profile is taken.  The rest is host-side
+instrumentation AROUND jitted computations: :func:`annotate` marks a
+host slice with ``jax.profiler.TraceAnnotation``, and :func:`capture`
+brackets a run with ``jax.profiler.trace`` for a full
+TensorBoard/Perfetto device capture on TPU runs.
 """
 from __future__ import annotations
 
 import contextlib
-import statistics
-import time
-from typing import Callable, List, NamedTuple, Optional
+from typing import Optional
 
 import jax
 
+# The fleet round's phases, in execution order.  ``data`` nests in
+# ``local_train``; ``sanitize`` nests in whichever phase holds a
+# sanitizer (``transport`` for the chaos ring, ``aggregate`` for
+# ``wfagg.sanitize_round``).  Glue code (a ravel, a ``where``) belongs
+# to the phase whose output it produces.
+PHASES = ("local_train", "data", "attack", "transport", "sanitize",
+          "aggregate", "evaluate")
 
-class TimingResult(NamedTuple):
-    compile_s: float        # first call: trace + compile + one run
-    steady_s: float         # median of the per-call steady-state times
-    steady_all_s: List[float]   # every steady-state sample (reps of them)
 
-
-def time_compile_steady(fn: Callable, *args, reps: int = 5) -> TimingResult:
-    """Time ``fn(*args)``: separate first-call (compile) and median
-    steady-state seconds, each call synchronized with
-    ``block_until_ready``."""
-    t0 = time.perf_counter()
-    jax.block_until_ready(fn(*args))
-    compile_s = time.perf_counter() - t0
-    samples = []
-    for _ in range(max(1, reps)):
-        t0 = time.perf_counter()
-        jax.block_until_ready(fn(*args))
-        samples.append(time.perf_counter() - t0)
-    return TimingResult(compile_s, statistics.median(samples), samples)
+def phase(name: str):
+    """``jax.named_scope("phase.<name>")``: open it while tracing the
+    phase's work, so the compiled ops carry the phase in their HLO
+    metadata.  ``name`` must be one of :data:`PHASES`."""
+    if name not in PHASES:
+        raise ValueError(f"unknown phase {name!r}; one of {PHASES}")
+    return jax.named_scope(f"phase.{name}")
 
 
 @contextlib.contextmanager
 def annotate(name: str):
-    """Name a round for the device profiler: ``jax.named_scope`` tags
-    ops traced inside, ``TraceAnnotation`` marks the host slice so a
-    ``jax.profiler`` capture shows rounds as labelled spans."""
-    with jax.named_scope(name), jax.profiler.TraceAnnotation(name):
+    """Mark a host slice (a round driven from Python) with
+    ``jax.profiler.TraceAnnotation``, so a ``jax.profiler`` capture
+    shows rounds as labelled host spans.  A ``jax.named_scope`` here
+    would name nothing: a scope around a call to an already-jitted
+    function reaches neither its lowered nor its compiled HLO.  The
+    device ops are named by :func:`phase`, inside the traced round."""
+    with jax.profiler.TraceAnnotation(name):
         yield
 
 

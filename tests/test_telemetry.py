@@ -395,17 +395,8 @@ def test_render_audit_smoke():
 
 
 # ---------------------------------------------------------------------------
-# timing plane + microbench methodology (satellite 1)
+# timing plane + microbench methodology
 # ---------------------------------------------------------------------------
-
-def test_time_compile_steady():
-    fn = jax.jit(lambda x: (x * 2.0).sum())
-    x = jnp.ones((256,))
-    res = obs_profile.time_compile_steady(fn, x, reps=3)
-    assert res.compile_s > 0 and res.steady_s > 0
-    assert len(res.steady_all_s) == 3
-    assert res.steady_s == sorted(res.steady_all_s)[1]  # the median
-
 
 def test_round_traffic_bytes_joins_memory_passes():
     wcfg = wf.WFAggConfig(backend="fused")
