@@ -39,7 +39,7 @@ def main() -> None:
     print("(Each WFAgg gossip round above ran as ONE kernel launch: the "
           "default backend fuses the filter statistics, the trust-weight "
           "derivation and the WFAgg-E combine into a single-launch "
-          "Pallas kernel — ~1 candidate pass per round; see "
+          "Pallas kernel — 2 candidate passes per round; see "
           "src/repro/kernels/README.md.  That single-launch claim, and "
           "every other structural invariant of the round, is pinned by "
           "the computation linter: PYTHONPATH=src python -m "

@@ -116,7 +116,8 @@ python benchmarks/agg_microbench.py --kernels --sizes 8x4096 \
   --bench-json "${BENCH_JSON:-}"
 
 # memory_passes() for the shipped configs must not exceed the traffic
-# table documented in src/repro/kernels/README.md (single-launch = ~1).
+# table documented in src/repro/kernels/README.md (single-launch = 2:
+# phase 1 re-reads the candidates, as the two-launch path does).
 python scripts/passes_gate.py
 
 # Computation linter: one static-analysis pass over the jaxprs, optimized

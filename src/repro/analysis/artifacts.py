@@ -62,6 +62,15 @@ def count_pallas_calls(jaxpr: Jaxpr) -> int:
     return sum(1 for e in walk_eqns(jaxpr) if e.primitive.name == "pallas_call")
 
 
+# memory spaces the pipeline never stages in VMEM: operands left in HBM
+# for the kernel's own DMAs (``pl.ANY``), SMEM, and semaphores
+NOT_VMEM = frozenset({"any", "hbm", "smem", "semaphore_mem"})
+
+
+def _memory_space(aval) -> str:
+    return str(getattr(aval, "memory_space", None) or "vmem")
+
+
 @dataclasses.dataclass(frozen=True)
 class BlockInfo:
     """One operand's BlockSpec as seen by the compiled launch."""
@@ -71,9 +80,13 @@ class BlockInfo:
     dtype: str
     itemsize: int
     index_map_jaxpr: Any             # ClosedJaxpr (grid idx [+ smem refs]) -> block idx
+    memory_space: str = "vmem"
 
     @property
     def block_bytes(self) -> int:
+        """VMEM bytes of one block: 0 for an operand left in HBM."""
+        if self.memory_space in NOT_VMEM:
+            return 0
         return math.prod(self.block_shape) * self.itemsize
 
 
@@ -86,7 +99,8 @@ class PallasCallInfo:
     n_inputs: int
     n_outputs: int
     n_scalar_prefetch: int
-    scratch_shapes: Tuple[Tuple[Tuple[int, ...], str, int], ...]  # (shape, dtype, itemsize)
+    # (shape, dtype, itemsize); a semaphore or SMEM scratch has itemsize 0
+    scratch_shapes: Tuple[Tuple[Tuple[int, ...], str, int], ...]
 
     @property
     def scratch_bytes(self) -> int:
@@ -132,6 +146,7 @@ def collect_pallas_calls(jaxpr: Jaxpr) -> List[PallasCallInfo]:
                 dtype=dt.name,
                 itemsize=dt.itemsize,
                 index_map_jaxpr=bm.index_map_jaxpr,
+                memory_space=_memory_space(bm.transformed_block_aval),
             ))
         # scratch avals are the tail invars of the kernel jaxpr
         scratch = []
@@ -140,9 +155,12 @@ def collect_pallas_calls(jaxpr: Jaxpr) -> List[PallasCallInfo]:
             inner = eqn.params["jaxpr"]
             for var in inner.invars[-n_scratch:]:
                 aval = getattr(var.aval, "inner_aval", var.aval)
+                shape = tuple(int(d) for d in aval.shape)
+                if _memory_space(var.aval) in NOT_VMEM:
+                    scratch.append((shape, str(aval.dtype), 0))
+                    continue
                 dt = np.dtype(aval.dtype)
-                scratch.append((tuple(int(d) for d in aval.shape),
-                                dt.name, dt.itemsize))
+                scratch.append((shape, dt.name, dt.itemsize))
         infos.append(PallasCallInfo(
             name=name,
             grid=tuple(int(g) for g in gm.grid),

@@ -236,7 +236,7 @@ def entry_points() -> Dict[str, EntryPoint]:
             compile_once=_compile_once_probe,
             passes=(("single-launch indexed gossip round (the default)",
                      WFAggConfig(),
-                     dict(include_gather=True, indexed=True), 1),),
+                     dict(include_gather=True, indexed=True), 2),),
         ),
         EntryPoint(
             name="one_launch_round_alt",
@@ -246,7 +246,7 @@ def entry_points() -> Dict[str, EntryPoint]:
             expected_launches=1, nkd=nkd,
             passes=(("single-launch indexed Alt-WFAgg (Gram folded into "
                      "the stats phase)", alt_wfagg_config(),
-                     dict(include_gather=True, indexed=True), 1),),
+                     dict(include_gather=True, indexed=True), 2),),
         ),
         EntryPoint(
             name="two_launch_round",

@@ -4,7 +4,7 @@ Every rule is a small dataclass: an id, a severity, the artifact layer
 it inspects (``jaxpr`` / ``hlo`` / ``pallas`` / ``runtime`` / ``config``)
 and a check function returning :class:`Finding`\\ s.  Rules encode the
 repo's compiled-computation claims — gather-free gossip, no (N, K, d)
-materialization, ~1 candidate pass per round, compile-once dynamic
+materialization, 2 candidate passes per round, compile-once dynamic
 schedules, f32 trust arithmetic, bounded VMEM — as machine-checked
 properties instead of ad-hoc HLO greps copy-pasted across test files.
 

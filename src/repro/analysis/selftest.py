@@ -249,7 +249,7 @@ def test_memory_passes() -> None:
            RULES_BY_ID["memory-passes"].run(
                art, _entry("passes-ok", passes=(
                    ("single-launch pin", WFAggConfig(),
-                    dict(include_gather=True, indexed=True), 1),))),
+                    dict(include_gather=True, indexed=True), 2),))),
            why="table row within ceiling")
 
 

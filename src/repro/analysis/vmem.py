@@ -1,39 +1,45 @@
 """Per-config VMEM-budget headroom over the model-shape registry.
 
-The round kernel blocks the model dimension, so its per-grid-step VMEM
-residency is set by (K, block_d), NOT by d — that independence is
-exactly the scaling claim (LeNet to yi-6b through one kernel), and this
-report makes it checkable instead of folklore: for every registered
+The round kernel tiles the model dimension, so its per-grid-step VMEM
+residency is set by (K, T), NOT by d — that independence is exactly the
+scaling claim (LeNet to yi-6b through one kernel), and this report
+makes it checkable instead of folklore: for every registered
 architecture, trace ``wfagg_round_indexed`` abstractly at the compiled-
-TPU block policy (1024 lanes) and price the launch with the same
+TPU tile rule (``round_tile_width``) and price the launch with the same
 :class:`~repro.analysis.artifacts.PallasCallInfo` model the vmem-budget
-rule uses.  Tracing uses ShapeDtypeStructs only — a 480B-parameter
-config costs the same milliseconds as LeNet.
+rule uses (the candidate and ``prev`` matrices stay in HBM and cost no
+VMEM; the landing tiles are scratch).  Tracing uses ShapeDtypeStructs
+only — a 480B-parameter config costs the same milliseconds as LeNet.
 
 ``launch/dryrun.py`` embeds one of these records per dry-run artifact;
 ``python -m repro.analysis --configs`` emits the whole sweep.
 """
 from __future__ import annotations
 
+import math
 from typing import Any, Dict, List, Optional
 
-# compiled-TPU policy: 1024-lane D tiles, ~16 MiB/core VMEM
-TPU_BLOCK_D = 1024
+# ~16 MiB/core of scoped VMEM
 DEFAULT_VMEM_CEILING = 16 * 1024 * 1024
 
 
 def round_kernel_residency(d: int, n: int = 10, k: int = 8,
-                           block_d: int = TPU_BLOCK_D,
+                           block_d: Optional[int] = None,
                            temporal: bool = True) -> Dict[str, Any]:
     """Trace the one-launch round kernel at ``(n, k, d)`` and return its
-    grid + modelled per-grid-step VMEM bytes (no arrays allocated)."""
+    grid, tile width, grid steps a round and modelled per-grid-step VMEM
+    bytes (no arrays allocated).  ``block_d=None`` is the compiled-TPU
+    tile rule."""
     import jax
     import jax.numpy as jnp
 
     from repro.analysis.artifacts import collect_pallas_calls
     from repro.core import wfagg as wf
+    from repro.kernels.robust_stats.kernel import round_tile_width
     from repro.kernels.robust_stats.ops import wfagg_round_indexed
 
+    if block_d is None:
+        block_d = round_tile_width(k, d, temporal)
     cfg = wf.WFAggConfig(f=1)
     f32 = jnp.float32
     local = jax.ShapeDtypeStruct((n, d), f32)
@@ -56,6 +62,7 @@ def round_kernel_residency(d: int, n: int = 10, k: int = 8,
     return {
         "kernel": info.name,
         "grid": list(info.grid),
+        "grid_steps": math.prod(info.grid),
         "block_d": block_d,
         "block_bytes": info.block_bytes,
         "scratch_bytes": info.scratch_bytes,

@@ -25,7 +25,8 @@ Execution backends (``WFAggConfig.backend``):
              streams the neighbor blocks, accumulates every filter
              statistic, derives the WFAgg-E trust weights at an
              in-kernel phase boundary (``core.trust``), and writes the
-             trust-weighted combine — ~1 candidate pass per round.  On
+             trust-weighted combine — 2 candidate passes per round (the
+             combine phase gathers the neighbor rows again).  On
              single-node / gathered entries it is the stats-kernel +
              host-scoring + combine pipeline (2 passes).
   fused_two_launch
@@ -383,7 +384,7 @@ def wfagg_batch(
     DMA each neighbor's d-blocks straight from it, so the (N, K, d)
     gossip tensor never exists in HBM.  Under the default
     backend="fused" this is ONE single-launch round kernel (stats,
-    in-kernel trust weights, WFAgg-E combine — ~1 candidate pass);
+    in-kernel trust weights, WFAgg-E combine — 2 candidate passes);
     backend="fused_two_launch" keeps the stats + combine launch pair.
     ``valid (N, K)`` marks the real edges of padded irregular topologies
     (None = regular); the temporal ``prev`` state may be per-edge
@@ -718,18 +719,15 @@ def memory_passes(cfg: WFAggConfig, include_gather: bool = False,
 
     On the indexed path, backend="fused" is the SINGLE-LAUNCH round
     kernel: stats, in-kernel weight derivation and combine in one
-    pallas_call — ~1 candidate pass (the phase-1 combine re-walks the
-    neighbor blocks through the same index maps, but those are the tiles
-    the stats phase just made resident, so the streamed HBM traffic is
-    one candidate read whenever a node's (K, d) slab fits VMEM).
-    backend="fused_two_launch" keeps the separate stats + combine
-    launches (2 passes) for parity runs.
+    pallas_call — 2 candidate passes, like the separate stats + combine
+    launches of backend="fused_two_launch": the phase-1 combine gathers
+    each (K, T) tile from HBM again (the tiles of a node's (K, d) slab
+    do not stay resident from phase 0).  The single launch saves the
+    host round-trip and the second launch, not a pass.
     """
     t = 1 if cfg.use_temporal else 0
     gather = 1 if (include_gather and not indexed) else 0
     if cfg.backend in _FUSED_BACKENDS:
-        if indexed and cfg.backend == "fused":
-            return 1 + gather      # single launch: one streamed read
         gram = 1 if (_needs_gram(cfg) and not indexed) else 0
         return 2 + gram + gather
     d_passes = 1 if cfg.distance_filter == "multi_krum" else 2

@@ -282,16 +282,15 @@ def _robust_stats_indexed_kernel(*refs, K: int, has_prev: bool,
             scratch_p[...] if has_prev else None)
 
 
-def _prev_spec(prev: Array, models: Array, neighbor_idx: Array,
-               prev_idx: Array | None, block_d: int, phase_axis: bool):
-    """(BlockSpec, row-view array, index table) for the ``prev`` input of
+def _prev_rows(prev: Array, models: Array, neighbor_idx: Array,
+               prev_idx: Array | None):
+    """(row-view array, index table, row map) for the ``prev`` input of
     the indexed launches.  ``prev`` is per-edge (N, K, D) — viewed as
     (N*K, 1, D) rows — or a previous-round matrix (M', D) read through
     ``neighbor_idx`` or, with ``prev_idx``, through its own (N, K) table
     (the two tables then ride the scalar prefetch as one (N, 2K) block).
-    With ``phase_axis`` (the round kernel's (node, phase, D block, slot)
-    grid) the map is pinned to one block in phase 1, which reads no prev,
-    so the combine re-walk fetches nothing new."""
+    ``row(table, n, k)`` is the row of the view that slot k of node n
+    compares against."""
     N, K = neighbor_idx.shape
     D = models.shape[-1]
     table = neighbor_idx
@@ -304,23 +303,11 @@ def _prev_spec(prev: Array, models: Array, neighbor_idx: Array,
         else:
             assert prev.shape == models.shape, (prev.shape, models.shape)
             off = 0
-        view = _row_view(prev)
-        if phase_axis:
-            imap = lambda n, p, i, k, ir: (ir[n, off + k * (1 - p)], 0,
-                                           i * (1 - p))
-        else:
-            imap = lambda n, i, k, ir: (ir[n, off + k], 0, i)
-    else:
-        if prev_idx is not None:
-            raise ValueError("prev_idx requires a matrix-form prev")
-        assert prev.shape == (N, K, D), (prev.shape, (N, K, D))
-        view = prev.reshape(N * K, 1, D)
-        if phase_axis:
-            imap = lambda n, p, i, k, ir: (n * K + k * (1 - p), 0,
-                                           i * (1 - p))
-        else:
-            imap = lambda n, i, k, ir: (n * K + k, 0, i)
-    return pl.BlockSpec((None, 1, block_d), imap), view, table
+        return _row_view(prev), table, lambda ir, n, k: ir[n, off + k]
+    if prev_idx is not None:
+        raise ValueError("prev_idx requires a matrix-form prev")
+    assert prev.shape == (N, K, D), (prev.shape, (N, K, D))
+    return prev.reshape(N * K, 1, D), table, lambda ir, n, k: n * K + k
 
 
 def robust_stats_indexed_pallas(
@@ -367,9 +354,9 @@ def robust_stats_indexed_pallas(
     args = [valid.astype(jnp.float32).reshape(N, 1, K), _row_view(models)]
     table = neighbor_idx
     if has_prev:
-        spec, view, table = _prev_spec(prev, models, neighbor_idx, prev_idx,
-                                       block_d, phase_axis=False)
-        in_specs.append(spec)
+        view, table, row = _prev_rows(prev, models, neighbor_idx, prev_idx)
+        in_specs.append(pl.BlockSpec(
+            (None, 1, block_d), lambda n, i, k, ir: (row(ir, n, k), 0, i)))
         args.append(view)
     out_shapes = [
         jax.ShapeDtypeStruct((N, 1, K), jnp.float32),   # dist2
@@ -404,24 +391,53 @@ def robust_stats_indexed_pallas(
     )(table.astype(jnp.int32), *args)
 
 
-def _wfagg_round_indexed_kernel(*refs, K: int, n_d: int, has_prev: bool,
-                                has_tbands: bool, need_gram: bool,
-                                cfg, alpha: float, mean_fallback: bool):
-    """Single-launch WFAgg round body: grid (node, PHASE, D block, slot).
+# VMEM the round kernel's tile-sized buffers may take: the double-buffered
+# (K, T) landing tiles of the candidates (and of ``prev``) plus the
+# pipelined (1, T) ``local`` and output blocks — well under the 16 MiB of
+# scoped VMEM, which also holds the flush's live rows
+ROUND_TILE_BUDGET = 4 * 1024 * 1024
+# lanes one flush of the statistics works on: the sort network runs on
+# (K, ROUND_CHUNK) slices of the resident tile, so its code and live rows
+# do not grow with the tile width
+ROUND_CHUNK = 1024
 
-    Phase 0 is the indexed stats pass — each step DMAs one neighbor row
-    block via the scalar-prefetch index map into the (K, T) VMEM scratch
-    and flushes the D/C/T accumulators (and the Alt-WFAgg Gram) at the
-    last slot, exactly like ``_robust_stats_indexed_kernel``.  At the
-    phase boundary (last D block, last slot of phase 0) the WFAgg scoring
-    stage runs IN-KERNEL on the VMEM-resident (1, K) accumulators
+
+def round_tile_width(K: int, d: int, has_prev: bool) -> int:
+    """Tile width T of the round kernel: the widest multiple of 1024
+    lanes whose tile-sized buffers fit ``ROUND_TILE_BUDGET`` and that
+    divides d rounded up to 1024 lanes.  So the operands are padded
+    exactly as far as 1024-lane tiles pad them: a d that is a multiple
+    of 1024 (the trainer's flat gradients can be) needs no padded copy
+    of the (K, d) matrices in HBM.  VMEM depends on (K, T) only, never
+    on d (LeNet's d = 44,426: T = 4,096 at K = 30, 11 tiles; 22,528 at
+    K = 8, 2 tiles; 45,056 lanes either way)."""
+    lane_bytes = 4 * (2 * K * (2 if has_prev else 1) + 4)
+    m_cap = max(1, ROUND_TILE_BUDGET // (1024 * lane_bytes))
+    nb = -(-d // 1024)
+    return 1024 * max(m for m in range(1, min(m_cap, nb) + 1) if nb % m == 0)
+
+
+def _wfagg_round_indexed_kernel(*refs, K: int, n_t: int, T: int, chunk: int,
+                                has_prev: bool, prev_row, has_tbands: bool,
+                                need_gram: bool, cfg, alpha: float,
+                                mean_fallback: bool):
+    """Single-launch WFAgg round body: grid (node, PHASE, D tile).
+
+    Each step gathers the node's K neighbor rows of one (K, T) tile with
+    K row DMAs, ``models[table[n, k], tile] -> land_u[slot, k]`` (in
+    phase 0 also the K ``prev`` rows), and starts the NEXT step's
+    gathers into the other slot before it computes — across phase and
+    node boundaries too, so only the launch's first tile is exposed.
+    Phase 0 flushes the D/C/T accumulators (and the Alt-WFAgg Gram) off
+    the resident tile, ``chunk`` lanes at a time
+    (``_flush_indexed_stats``, as the two-launch stats kernel does).  At
+    the phase boundary (phase 0, last tile) the WFAgg scoring stage runs
+    IN-KERNEL on the VMEM-resident (1, K) accumulators
     (``core.trust.derive_trust_weights``), the masks/weights are written
     to their O(K) outputs, and the normalized combine coefficients land
-    in a VMEM scratch.  Phase 1 re-DMAs the neighbor blocks through the
-    same index map and accumulates the trust-weighted WFAgg-E combine
-    into the (1, T) output block — no host round-trip, no second kernel
-    launch, and the candidate re-read hits tiles that are still resident
-    whenever a node's (K, D) slab fits VMEM.
+    in a VMEM scratch.  Phase 1 gathers the tiles again and writes
+    ``lcoef * local + sum_k w_k u_k`` to the (1, T) output block — no
+    host round-trip and no second kernel launch.
 
     The WFAgg-T decision is four compares against the precomputed flat
     (1, 4K) EWMA band input (``core.trust.temporal_bands`` — the history
@@ -432,12 +448,13 @@ def _wfagg_round_indexed_kernel(*refs, K: int, n_d: int, has_prev: bool,
     # package-init time; by kernel-trace time repro.core is fully loaded
     from repro.core import trust
 
-    refs = list(refs[1:])  # refs[0]: the prefetched table, read by index maps
+    table = refs[0]
+    refs = list(refs[1:])
     valid_ref = refs.pop(0)
     tbands_ref = refs.pop(0) if has_tbands else None
     local_ref = refs.pop(0)
-    u_ref = refs.pop(0)
-    prev_ref = refs.pop(0) if has_prev else None
+    models_hbm = refs.pop(0)
+    prev_hbm = refs.pop(0) if has_prev else None
     out_ref, w_ref, md_ref, mc_ref, mt_ref = refs[:5]
     n_acc = 4 + (1 if need_gram else 0) + (3 if has_prev else 0)
     acc_refs = refs[5:5 + n_acc]
@@ -445,34 +462,62 @@ def _wfagg_round_indexed_kernel(*refs, K: int, n_d: int, has_prev: bool,
     dist2_ref, dotmed_ref, norm2_ref, mednorm2_ref = acc_refs[:4]
     gram_ref = acc_refs[4] if need_gram else None
     prev_acc = acc_refs[5 if need_gram else 4:] if has_prev else ()
-    scratch_u = scratch[0]
-    scratch_p = scratch[1] if has_prev else None
+    land_u, sem_u = scratch[0], scratch[1]
+    land_p, sem_p = (scratch[2], scratch[3]) if has_prev else (None, None)
     wcomb_ref, lcoef_ref = scratch[-2], scratch[-1]
 
-    # program ids are read outside the pl.when bodies
-    p = pl.program_id(1)
-    i = pl.program_id(2)
-    k = pl.program_id(3)
-    is_phase0 = p == 0
-    is_last_slot = k == K - 1
-    is_first_d = i == 0
-    is_boundary = is_phase0 & is_last_slot & (i == n_d - 1)
+    n, p, i = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    step = (2 * n + p) * n_t + i
+    slot = jax.lax.rem(step, 2)
 
-    u_now = u_ref[...].astype(jnp.float32)                  # (1, T)
-
-    @pl.when(is_phase0)
-    def _stage():
-        scratch_u[pl.ds(k, 1), :] = u_now
+    def gather(nn, pp, ii, s, op):
+        """Start (``op="start"``) or wait for (``op="wait"``) the row DMAs
+        of step (nn, pp, ii) into landing slot s: K model rows, and in
+        phase 0 the K prev rows."""
+        cols = pl.ds(ii * T, T)
+        for k in range(K):
+            getattr(pltpu.make_async_copy(
+                models_hbm.at[table[nn, k], :, cols], land_u.at[s, k],
+                sem_u.at[s]), op)()
         if has_prev:
-            scratch_p[pl.ds(k, 1), :] = prev_ref[...].astype(jnp.float32)
+            @pl.when(pp == 0)
+            def _prev():
+                for k in range(K):
+                    getattr(pltpu.make_async_copy(
+                        prev_hbm.at[prev_row(table, nn, k), :, cols],
+                        land_p.at[s, k], sem_p.at[s]), op)()
 
-    @pl.when(is_phase0 & is_last_slot)
-    def _flush():
-        _flush_indexed_stats(
-            scratch_u[...], valid_ref[...], acc_refs, is_first_d, need_gram,
-            scratch_p[...] if has_prev else None)
+    @pl.when(step == 0)
+    def _first():
+        gather(n, p, i, slot, "start")
 
-    @pl.when(is_boundary)
+    last_t = i == n_t - 1
+    nxt_n = jnp.where(last_t & (p == 1), n + 1, n)
+    nxt_p = jnp.where(last_t, 1 - p, p)
+    nxt_i = jnp.where(last_t, 0, i + 1)
+
+    @pl.when(nxt_n < pl.num_programs(0))
+    def _prefetch():
+        gather(nxt_n, nxt_p, nxt_i, 1 - slot, "start")
+
+    gather(n, p, i, slot, "wait")
+
+    @pl.when(p == 0)
+    def _stats():
+        valid_row = valid_ref[...]
+
+        def flush(c, carry):
+            cols = pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
+            u = land_u[slot, :, :, cols].reshape(K, chunk)
+            pv = (land_p[slot, :, :, cols].reshape(K, chunk)
+                  if has_prev else None)
+            _flush_indexed_stats(u, valid_row, acc_refs, (i == 0) & (c == 0),
+                                 need_gram, pv)
+            return carry
+
+        jax.lax.fori_loop(0, T // chunk, flush, 0)
+
+    @pl.when((p == 0) & last_t)
     def _derive():
         valid_f = valid_ref[...]                              # (1, K)
         tail = [r[...] for r in prev_acc] if has_prev else [None] * 3
@@ -495,21 +540,17 @@ def _wfagg_round_indexed_kernel(*refs, K: int, n_d: int, has_prev: bool,
         wcomb_ref[...] = wcomb
         lcoef_ref[...] = jnp.broadcast_to(lcoef, (1, 1))
 
-    # ---- phase 1: trust-weighted combine (same DMA pattern, weights in
-    # VMEM from the boundary step; matches _weighted_agg_indexed_kernel) --
-    is_phase1 = jnp.logical_not(is_phase0)
-    kio = jax.lax.broadcasted_iota(jnp.int32, (1, K), 1)
-
-    @pl.when(is_phase1 & (k == 0))
-    def _seed():
-        wk = jnp.sum(jnp.where(kio == k, wcomb_ref[...], 0.0))
-        out_ref[...] = (lcoef_ref[...] * local_ref[...].astype(jnp.float32)
-                        + wk * u_now)
-
-    @pl.when(is_phase1 & (k != 0))
-    def _accum():
-        wk = jnp.sum(jnp.where(kio == k, wcomb_ref[...], 0.0))
-        out_ref[...] += wk * u_now
+    # ---- phase 1: trust-weighted combine over the resident tile, in the
+    # slot order of _weighted_agg_indexed_kernel
+    @pl.when(p == 1)
+    def _combine():
+        kio = jax.lax.broadcasted_iota(jnp.int32, (1, K), 1)
+        wcomb = wcomb_ref[...]
+        acc = lcoef_ref[...] * local_ref[...].astype(jnp.float32)
+        for k in range(K):
+            wk = jnp.sum(jnp.where(kio == k, wcomb, 0.0))
+            acc = acc + wk * land_u[slot, k]
+        out_ref[...] = acc
 
 
 def wfagg_round_indexed_pallas(
@@ -525,14 +566,16 @@ def wfagg_round_indexed_pallas(
     alpha: float,
     mean_fallback: bool = False,
     need_gram: bool = False,
-    block_d: int = 1024,
+    block_d: int,
     interpret: bool | None = None,
 ):
-    """Launch the single-launch WFAgg round kernel over a 4-D
-    (node, phase, D block, slot) grid.  Phase 0 accumulates the indexed
-    robust statistics, the phase boundary derives the trust weights
-    in-kernel, and phase 1 writes the WFAgg-E combine — one launch for
-    the entire gossip round.
+    """Launch the single-launch WFAgg round kernel over a 3-D
+    (node, phase, D tile) grid with (K, block_d) tiles.  ``models`` and
+    ``prev`` stay in HBM (``pl.ANY``) and the body gathers each tile's K
+    rows with row DMAs; the other operands are BlockSpec-pipelined.
+    Phase 0 accumulates the indexed robust statistics, the phase
+    boundary derives the trust weights in-kernel, and phase 1 writes the
+    WFAgg-E combine — one launch for the entire gossip round.
 
     With ``prev_idx`` the matrix-form ``prev`` reads through its own
     (N, K) table (concatenated after ``neighbor_idx`` into one (N, 2K)
@@ -547,17 +590,13 @@ def wfagg_round_indexed_pallas(
     N, K = neighbor_idx.shape
     assert D % block_d == 0, (D, block_d)
     assert local.shape == (N, D), (local.shape, (N, D))
-    n_d = D // block_d
+    n_t = D // block_d
     has_prev = prev is not None
     has_tbands = tbands is not None
     if prev_idx is not None and not (has_prev and prev.ndim == 2):
         raise ValueError("prev_idx requires a matrix-form prev")
-    kernel = functools.partial(
-        _wfagg_round_indexed_kernel, K=K, n_d=n_d, has_prev=has_prev,
-        has_tbands=has_tbands, need_gram=need_gram, cfg=cfg, alpha=alpha,
-        mean_fallback=mean_fallback,
-    )
-    k_spec = pl.BlockSpec((None, 1, K), lambda n, p, i, k, ir: (n, 0, 0))
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    k_spec = pl.BlockSpec((None, 1, K), lambda n, p, i, ir: (n, 0, 0))
     in_specs = [k_spec]                                            # valid
     args = [valid.astype(jnp.float32).reshape(N, 1, K)]
     if has_tbands:
@@ -565,24 +604,28 @@ def wfagg_round_indexed_pallas(
         # gossip tensor to the rank-based (N, K, d)-free scan when K == 4
         assert tbands.shape == (N, 4 * K), (tbands.shape, (N, 4 * K))
         in_specs.append(
-            pl.BlockSpec((None, 1, 4 * K), lambda n, p, i, k, ir: (n, 0, 0)))
+            pl.BlockSpec((None, 1, 4 * K), lambda n, p, i, ir: (n, 0, 0)))
         args.append(tbands.astype(jnp.float32).reshape(N, 1, 4 * K))
-    # local and the combine output: pinned to block 0 during phase 0
+    # local and the combine output: pinned to tile 0 during phase 0
     # (only phase 1 touches them) — `i * p` keeps the block constant
     # until the combine phase
     row_spec = pl.BlockSpec((None, 1, block_d),
-                            lambda n, p, i, k, ir: (n, 0, i * p))
-    in_specs.append(row_spec)
-    args.append(_row_view(local))
-    in_specs.append(pl.BlockSpec(
-        (None, 1, block_d), lambda n, p, i, k, ir: (ir[n, k], 0, i)))
-    args.append(_row_view(models))
-    table = neighbor_idx
+                            lambda n, p, i, ir: (n, 0, i * p))
+    in_specs += [row_spec, hbm]
+    args += [_row_view(local), _row_view(models)]
+    table, prev_row = neighbor_idx, None
     if has_prev:
-        spec, view, table = _prev_spec(prev, models, neighbor_idx, prev_idx,
-                                       block_d, phase_axis=True)
-        in_specs.append(spec)
+        view, table, prev_row = _prev_rows(prev, models, neighbor_idx,
+                                           prev_idx)
+        in_specs.append(hbm)
         args.append(view)
+    chunk = ROUND_CHUNK if block_d % ROUND_CHUNK == 0 else block_d
+    kernel = functools.partial(
+        _wfagg_round_indexed_kernel, K=K, n_t=n_t, T=block_d, chunk=chunk,
+        has_prev=has_prev, prev_row=prev_row, has_tbands=has_tbands,
+        need_gram=need_gram, cfg=cfg, alpha=alpha,
+        mean_fallback=mean_fallback,
+    )
 
     out_shapes = [
         jax.ShapeDtypeStruct((N, 1, D), jnp.float32),   # combined models
@@ -599,23 +642,25 @@ def wfagg_round_indexed_pallas(
         row_spec,
         k_spec, k_spec, k_spec, k_spec,                  # weights + masks
         k_spec, k_spec, k_spec,
-        pl.BlockSpec((None, 1, 1), lambda n, p, i, k, ir: (n, 0, 0)),
+        pl.BlockSpec((None, 1, 1), lambda n, p, i, ir: (n, 0, 0)),
     ]
     if need_gram:
         out_shapes.append(jax.ShapeDtypeStruct((N, K, K), jnp.float32))
         out_specs.append(
-            pl.BlockSpec((None, K, K), lambda n, p, i, k, ir: (n, 0, 0)))
+            pl.BlockSpec((None, K, K), lambda n, p, i, ir: (n, 0, 0)))
     if has_prev:
         out_shapes += [jax.ShapeDtypeStruct((N, 1, K), jnp.float32)] * 3
         out_specs += [k_spec] * 3
-    scratch_shapes = [pltpu.VMEM((K, block_d), jnp.float32)]
-    if has_prev:
-        scratch_shapes.append(pltpu.VMEM((K, block_d), jnp.float32))
+    # landing tiles: two slots of K (1, block_d) rows, each row its own
+    # (1, 128)-tiled block, so a row DMA lands on whole tiles
+    land = [pltpu.VMEM((2, K, 1, block_d), jnp.float32),
+            pltpu.SemaphoreType.DMA((2,))]
+    scratch_shapes = land * (2 if has_prev else 1)
     scratch_shapes += [pltpu.VMEM((1, K), jnp.float32),   # combine weights
                        pltpu.VMEM((1, 1), jnp.float32)]   # local coefficient
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(N, 2, n_d, K),
+        grid=(N, 2, n_t),
         in_specs=in_specs,
         out_specs=tuple(out_specs),
         scratch_shapes=scratch_shapes,

@@ -21,11 +21,13 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.common import pad_d, resolve_block_d
+from repro.kernels.common import (
+    auto_block_d, pad_d, resolve_block_d, resolve_interpret)
 from repro.kernels.robust_stats.kernel import (
     robust_stats_batch_pallas,
     robust_stats_indexed_pallas,
     robust_stats_pallas,
+    round_tile_width,
     wfagg_round_indexed_pallas,
 )
 from repro.kernels.robust_stats.ref import (
@@ -224,18 +226,19 @@ def wfagg_round_indexed(
     interpret: Optional[bool] = None,
 ):
     """One-launch gossip round: the fused WFAgg-E combine folded into the
-    indexed robust_stats kernel (ROADMAP's "2 passes -> ~1").
+    indexed robust_stats kernel.
 
-    A single 4-D (node, phase, D block, slot) Pallas launch streams the
-    neighbor blocks, accumulates every filter statistic, derives the
-    trust weights at the in-kernel phase boundary
-    (``core.trust.derive_trust_weights`` on the VMEM-resident (1, K)
-    accumulators — the Alt-WFAgg Gram included via the resident-tile
-    matmul), and writes the trust-weighted combine in phase 1.  The
-    WFAgg-T EWMA bands are precomputed from history by the caller
-    (``core.trust.temporal_bands``) and ride in as an O(K) input; the
-    in-kernel temporal decision is a compare against the kernel's own
-    prev_dist2 / cosine statistics.
+    A single 3-D (node, phase, D tile) Pallas launch gathers each
+    node's K neighbor rows of a (K, T) tile with row DMAs, accumulates
+    every filter statistic, derives the trust weights at the in-kernel
+    phase boundary (``core.trust.derive_trust_weights`` on the
+    VMEM-resident (1, K) accumulators — the Alt-WFAgg Gram included via
+    the resident-tile matmul), and writes the trust-weighted combine in
+    phase 1, which gathers the tiles again: two reads of the candidates
+    and one of ``prev``.  The WFAgg-T EWMA bands are precomputed from
+    history by the caller (``core.trust.temporal_bands``) and ride in as
+    an O(K) input; the in-kernel temporal decision is a compare against
+    the kernel's own prev_dist2 / cosine statistics.
 
     Returns ``(out (N, d), weights (N, K), mask_d, mask_c, mask_t
     ((N, K) bool), stats)`` where ``stats`` is a ``RobustStats`` with
@@ -244,10 +247,10 @@ def wfagg_round_indexed(
     all-rejected behavior: local model (DFL, Eq. 3) vs uniform valid
     mean (robust all-reduce).
 
-    Interpret-mode block policy: ONE D block (``interpret_blocks=1``) —
-    the interpreter carries the (N, d) combine output through every grid
-    step, so fewer/bigger steps beat smaller tiles; compiled TPU keeps
-    1024-lane tiles.
+    Tile width T: ``block_d`` when given; else on a TPU
+    ``round_tile_width`` (K, d and a fixed VMEM budget), and in
+    interpret mode ONE tile — the interpreter carries the (N, d)
+    combine output through every grid step, so fewer steps win.
     """
     from repro.core import trust  # deferred: see kernel.py
 
@@ -259,7 +262,10 @@ def wfagg_round_indexed(
             "reads the kernel's own prev_dist2/cosine temporal statistics")
     if alpha is None:
         alpha = cfg.alpha
-    block_d, itp = resolve_block_d(d, block_d, interpret, interpret_blocks=1)
+    itp = resolve_interpret(interpret)
+    if block_d is None:
+        block_d = (auto_block_d(d, itp, interpret_blocks=1) if itp
+                   else round_tile_width(K, d, prev is not None))
     m = pad_d(models, block_d)
     loc = pad_d(local, block_d)
     p = pad_d(prev, block_d) if prev is not None else None

@@ -120,3 +120,27 @@ def test_block_shapes_read_from_pallas_block_objects():
     shapes = [b.block_shape for b in info.blocks]
     assert (1, 1, K) in shapes and (1, 1, T) in shapes, shapes
     assert info.scratch_bytes == K * T * 4
+
+
+@pytest.mark.parametrize("n,k,d", [(1024, 30, 44_426), (1, 8, 7_000_000_000)],
+                         ids=["er1024", "d7e9"])
+def test_round_kernel_modelled_under_vmem_ceiling(n, k, d):
+    """The round kernel's VMEM follows its tile rule, not d: at the
+    1,024-node fleet's shape and at a 7B-parameter d the modelled
+    per-step residency stays under the 16 MiB ceiling.  The candidate and
+    ``prev`` matrices stay in HBM (``pl.ANY``, priced at 0) and the DMA
+    semaphores cost no VMEM; the double-buffered landing tiles do."""
+    from repro.analysis.vmem import DEFAULT_VMEM_CEILING, round_kernel_residency
+    from repro.kernels.robust_stats.kernel import round_tile_width
+
+    res = round_kernel_residency(d, n=n, k=k)
+    T = round_tile_width(k, d, True)
+    assert res["block_d"] == T and T % 1024 == 0
+    n_t = -(-d // T)
+    assert res["grid"] == [n, 2, n_t]
+    assert res["grid_steps"] == n * 2 * n_t
+    landing = 2 * (2 * k * T * 4)                   # models + prev, 2 slots
+    assert landing <= res["scratch_bytes"] < landing + 4096
+    assert res["vmem_bytes"] <= DEFAULT_VMEM_CEILING, res
+    if d == 44_426:
+        assert T == 4096 and n_t == 11           # 45,056 lanes, as before
