@@ -225,10 +225,9 @@ def trainer_check(argv=None) -> None:
                          batch_size=args.global_batch)
     record = {}
     for backend in ("fused", "reference"):
-        # donated as TrainConfig does by default; the launcher's CLI
-        # keeps the state, which costs a second copy of it
+        # build_everything donates the state, as TrainConfig does
         tcb = dataclasses.replace(
-            tc, donate=True, agg=dataclasses.replace(tc.agg, backend=backend))
+            tc, agg=dataclasses.replace(tc.agg, backend=backend))
         state = launch.init_sharded_state(cfg, tcb, mesh)
         step = tr.build_train_step(cfg, tcb, mesh)
         with mesh:

@@ -1,6 +1,7 @@
 """qwen1.5-0.5b [dense]: QKV-bias decoder with tied embeddings.
 
-24L d_model=1024 16H (GQA kv=16) d_ff=2816 vocab=151936.
+24L d_model=1024 16H (GQA kv=16) d_ff=2816 vocab=151936, rope_theta
+1,000,000 and rms_norm_eps 1e-6 as its published config.json gives them.
 [hf:Qwen/Qwen1.5-0.5B]
 """
 from repro.configs.base import ArchConfig
@@ -17,6 +18,8 @@ CONFIG = ArchConfig(
     vocab_size=151936,
     qkv_bias=True,
     tie_embeddings=True,
+    rope_theta=1_000_000.0,
+    norm_eps=1e-6,
     loss_chunk=512,
     optimizer="adamw",
 )

@@ -60,7 +60,7 @@ def build_everything(args):
         ),
         lr=args.lr, warmup=args.warmup, total_steps=args.steps,
         attack=args.attack, n_malicious=args.n_malicious,
-        multi_pod=args.multi_pod, donate=False,
+        multi_pod=args.multi_pod,
     )
     return cfg, mesh, tc
 

@@ -32,6 +32,7 @@ from repro.configs.base import ArchConfig
 from repro.distributed.logical import shard
 from repro.models import layers as L
 from repro.models import ssm as S
+from repro.obs.profile import phase
 
 Array = jax.Array
 Params = Dict[str, Any]
@@ -248,7 +249,8 @@ def forward(cfg: ArchConfig, params: Params, batch: Dict[str, Array]) -> Tuple[A
         h, _, _ = _scan_blocks(cfg, params["layers"], h, pos, "dense", enc_out=enc_out)
     else:
         tokens = batch["tokens"]
-        h = L.embed_fwd(cfg, params["embedding"], tokens, dt)
+        with phase("data"):
+            h = L.embed_fwd(cfg, params["embedding"], tokens, dt)
         if "patch_embeds" in batch:
             pe = batch["patch_embeds"].astype(dt)
             proj = params["projector"]
@@ -302,7 +304,10 @@ def _chunked_ce(cfg: ArchConfig, params: Params, h: Array, labels: Array, mask: 
 
 
 def loss_fn(cfg: ArchConfig, params: Params, batch: Dict[str, Array]) -> Tuple[Array, Dict[str, Array]]:
-    """Next-token cross-entropy (+ MoE aux).  VLM: loss on text positions only."""
+    """Next-token cross-entropy (+ MoE aux).  VLM: loss on text positions only.
+
+    The batch's way into the model (the token embedding and the
+    next-token labels) is the ``data`` phase (``repro.obs.profile``)."""
     dt = _dtype(cfg)
     n_modal = batch["patch_embeds"].shape[1] if "patch_embeds" in batch else 0
 
@@ -311,7 +316,8 @@ def loss_fn(cfg: ArchConfig, params: Params, batch: Dict[str, Array]) -> Tuple[A
         logits = None
         # forward trunk without unembedding
         tokens = batch["tokens"]
-        h = L.embed_fwd(cfg, params["embedding"], tokens, dt)
+        with phase("data"):
+            h = L.embed_fwd(cfg, params["embedding"], tokens, dt)
         if n_modal:
             pe = batch["patch_embeds"].astype(dt)
             proj = params["projector"]
@@ -334,15 +340,16 @@ def loss_fn(cfg: ArchConfig, params: Params, batch: Dict[str, Array]) -> Tuple[A
             aux = aux + a
         h = L.norm_fwd(cfg, params["final_norm"], h)
         # shift: predict token t+1 from position t
-        labels_full = jnp.concatenate(
-            [jnp.zeros((Bb, n_modal), tokens.dtype), batch["tokens"]], axis=1
-        ) if n_modal else batch["tokens"]
+        with phase("data"):
+            labels_full = jnp.concatenate(
+                [jnp.zeros((Bb, n_modal), tokens.dtype), batch["tokens"]], axis=1
+            ) if n_modal else batch["tokens"]
+            lab = labels_full[:, 1:]
+            mask = jnp.ones_like(lab, jnp.float32)
+            if n_modal:
+                posn = jnp.arange(lab.shape[1])
+                mask = mask * (posn[None, :] >= n_modal - 1)
         h_in = h[:, :-1]
-        lab = labels_full[:, 1:]
-        mask = jnp.ones_like(lab, jnp.float32)
-        if n_modal:
-            posn = jnp.arange(lab.shape[1])
-            mask = mask * (posn[None, :] >= n_modal - 1)
         ce = _chunked_ce(cfg, params, h_in, lab, mask)
         return ce + aux, {"ce": ce, "aux": aux}
 
@@ -353,7 +360,8 @@ def loss_fn(cfg: ArchConfig, params: Params, batch: Dict[str, Array]) -> Tuple[A
     else:
         logits_text = logits
     lg = logits_text[:, :-1].astype(jnp.float32)
-    lab = tokens[:, 1:]
+    with phase("data"):
+        lab = tokens[:, 1:]
     logz = jax.nn.logsumexp(lg, axis=-1)
     gold = jnp.take_along_axis(lg, lab[..., None], axis=-1)[..., 0]
     ce = jnp.mean(logz - gold)

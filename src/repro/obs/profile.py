@@ -1,6 +1,6 @@
-"""Timing plane: the round's phases on the device trace, host spans for
-the profiler, and the achieved-bandwidth join against the
-``memory_passes`` traffic table.
+"""Timing plane: the phases of the gossip round and of the training
+step on the device trace, host spans for the profiler, and the
+achieved-bandwidth join against the ``memory_passes`` traffic table.
 
 :func:`phase` is the one piece that enters traced code: a
 ``jax.named_scope`` opened while a round is traced, so every HLO op the
@@ -28,14 +28,19 @@ import jax
 # to the phase whose output it produces.
 PHASES = ("local_train", "data", "attack", "transport", "sanitize",
           "aggregate", "evaluate")
+# The robust-DP training step's phases (``train.trainer``), in execution
+# order: ``grad`` is every worker's forward and backward, with ``data``
+# (the batch's way into the model) nested in it.
+TRAIN_PHASES = ("grad", "data", "attack", "aggregate", "optimizer")
 
 
 def phase(name: str):
     """``jax.named_scope("phase.<name>")``: open it while tracing the
     phase's work, so the compiled ops carry the phase in their HLO
-    metadata.  ``name`` must be one of :data:`PHASES`."""
-    if name not in PHASES:
-        raise ValueError(f"unknown phase {name!r}; one of {PHASES}")
+    metadata.  ``name`` must be one of :data:`PHASES` or
+    :data:`TRAIN_PHASES`."""
+    if name not in PHASES + TRAIN_PHASES:
+        raise ValueError(f"unknown phase {name!r}; one of {PHASES + TRAIN_PHASES}")
     return jax.named_scope(f"phase.{name}")
 
 

@@ -14,6 +14,11 @@ Two execution modes (DESIGN.md Section 3):
   FSDP+TP param sharding).  Used for the >=100B arch whose K full
   gradient candidates cannot coexist in pod HBM (arctic-480b), and as the
   non-robust performance baseline.
+
+Both name their phases on the device trace (``repro.obs.profile.phase``):
+``grad`` (with ``data`` nested in it), ``attack``, ``aggregate`` and
+``optimizer``.  The scopes change metadata only; the compiled step is
+the same program.
 """
 from __future__ import annotations
 
@@ -42,6 +47,7 @@ from repro.distributed.robust_allreduce import (
     robust_allreduce_stacked,
 )
 from repro.models import model as M
+from repro.obs.profile import phase
 from repro.optim.optimizers import make_optimizer, warmup_cosine
 
 Array = jax.Array
@@ -199,23 +205,28 @@ def build_train_step(cfg: ArchConfig, tc: TrainConfig, mesh: Mesh) -> Callable:
                     tokens=jnp.where(bad, (cfg.vocab_size - 1) - batch["tokens"],
                                      batch["tokens"]),
                 )
-            (loss, metrics), grads = jax.value_and_grad(
-                lambda p: M.loss_fn(cfg, p, batch), has_aux=True
-            )(params)
+            with phase("grad"):
+                (loss, metrics), grads = jax.value_and_grad(
+                    lambda p: M.loss_fn(cfg, p, batch), has_aux=True
+                )(params)
+                flat, unravel = ravel_pytree(grads)
             attacking = tc.attack not in ("none", "label_flip") and tc.n_malicious > 0
-            akey = jax.random.fold_in(jax.random.PRNGKey(tc.agg.seed + 1), step)
-
-            flat, unravel = ravel_pytree(grads)
             if attacking:
-                flat = apply_distributed_attack(flat, axes, malicious,
-                                                tc.attack, akey)
-            agg_flat, new_agg, info = robust_allreduce(flat, axes, tc.agg,
-                                                       agg_state)
-            grads = unravel(agg_flat)
-            gn = jnp.sqrt(jnp.sum(agg_flat.astype(jnp.float32) ** 2))
-            lr = lr_fn(step)
-            updates, new_opt = opt.update(grads, opt_state, params, lr)
-            new_params = jax.tree.map(lambda p, u: p + u.astype(p.dtype), params, updates)
+                with phase("attack"):
+                    akey = jax.random.fold_in(jax.random.PRNGKey(tc.agg.seed + 1),
+                                              step)
+                    flat = apply_distributed_attack(flat, axes, malicious,
+                                                    tc.attack, akey)
+            with phase("aggregate"):
+                agg_flat, new_agg, info = robust_allreduce(flat, axes, tc.agg,
+                                                           agg_state)
+                grads = unravel(agg_flat)
+            with phase("optimizer"):
+                gn = jnp.sqrt(jnp.sum(agg_flat.astype(jnp.float32) ** 2))
+                lr = lr_fn(step)
+                updates, new_opt = opt.update(grads, opt_state, params, lr)
+                new_params = jax.tree.map(lambda p, u: p + u.astype(p.dtype),
+                                          params, updates)
             mean_loss = jax.lax.pmean(loss, axes)
             out_metrics = {
                 "loss": mean_loss,
@@ -298,39 +309,45 @@ def build_train_step(cfg: ArchConfig, tc: TrainConfig, mesh: Mesh) -> Callable:
         def stacked_step_fn(state: TrainState, batch):
             has_agg = state.agg_state is not None
             bspecs = shd.batch_specs(batch, data_axes=axes, mesh=mesh)
-            grads_stacked, losses = jax.shard_map(
-                grad_worker,
-                mesh=mesh,
-                in_specs=(P(), P(), bspecs),
-                out_specs=(jax.tree.map(lambda _: P(axis_spec), state.params),
-                           P(axis_spec)),
-                axis_names=set(axes),
-                check_vma=False,
-            )(state.params, state.step, batch)
-            # pin the stacked candidate layout: (K over data axes, TP inner)
-            grads_stacked = jax.tree.map(
-                lambda g, sp: jax.lax.with_sharding_constraint(
-                    g, NamedSharding(mesh, sp)),
-                grads_stacked, stacked_specs)
+            with phase("grad"):
+                grads_stacked, losses = jax.shard_map(
+                    grad_worker,
+                    mesh=mesh,
+                    in_specs=(P(), P(), bspecs),
+                    out_specs=(jax.tree.map(lambda _: P(axis_spec), state.params),
+                               P(axis_spec)),
+                    axis_names=set(axes),
+                    check_vma=False,
+                )(state.params, state.step, batch)
+                # pin the stacked candidate layout: (K over data axes, TP inner)
+                grads_stacked = jax.tree.map(
+                    lambda g, sp: jax.lax.with_sharding_constraint(
+                        g, NamedSharding(mesh, sp)),
+                    grads_stacked, stacked_specs)
 
             if tc.attack not in ("none", "label_flip") and tc.n_malicious > 0:
-                akey = jax.random.fold_in(jax.random.PRNGKey(tc.agg.seed + 1),
-                                          state.step)
-                grads_stacked = apply_stacked_attack(
-                    grads_stacked, malicious, tc.attack, akey)
+                with phase("attack"):
+                    akey = jax.random.fold_in(jax.random.PRNGKey(tc.agg.seed + 1),
+                                              state.step)
+                    grads_stacked = apply_stacked_attack(
+                        grads_stacked, malicious, tc.attack, akey)
 
-            agg = state.agg_state if has_agg else None
-            grads, new_agg, info = robust_allreduce_stacked(
-                grads_stacked, tc.agg, agg)
+            with phase("aggregate"):
+                agg = state.agg_state if has_agg else None
+                grads, new_agg, info = robust_allreduce_stacked(
+                    grads_stacked, tc.agg, agg)
 
-            lr = lr_fn(state.step)
-            updates, new_opt = opt.update(grads, state.opt_state, state.params, lr)
-            new_params = jax.tree.map(lambda p, u: p + u.astype(p.dtype),
-                                      state.params, updates)
-            gn = jnp.sqrt(sum(jnp.sum(g.astype(jnp.float32) ** 2)
-                              for g in jax.tree.leaves(grads)))
+            with phase("optimizer"):
+                lr = lr_fn(state.step)
+                updates, new_opt = opt.update(grads, state.opt_state,
+                                              state.params, lr)
+                new_params = jax.tree.map(lambda p, u: p + u.astype(p.dtype),
+                                          state.params, updates)
+                gn = jnp.sqrt(sum(jnp.sum(g.astype(jnp.float32) ** 2)
+                                  for g in jax.tree.leaves(grads)))
             m = {
                 "loss": jnp.mean(losses),
+                "losses": losses,
                 "lr": lr,
                 "grad_norm": gn,
                 "n_accepted": info.get("n_accepted", jnp.asarray(K)),
@@ -354,13 +371,18 @@ def build_train_step(cfg: ArchConfig, tc: TrainConfig, mesh: Mesh) -> Callable:
 
     def gspmd_step(state: TrainState, batch):
         with use_sharding(mesh, rules):
-            (loss, metrics), grads = jax.value_and_grad(
-                lambda p: M.loss_fn(cfg, p, batch), has_aux=True
-            )(state.params)
-            lr = lr_fn(state.step)
-            updates, new_opt = opt.update(grads, state.opt_state, state.params, lr)
-            new_params = jax.tree.map(lambda p, u: p + u.astype(p.dtype), state.params, updates)
-            gn = jnp.sqrt(sum(jnp.sum(g.astype(jnp.float32) ** 2) for g in jax.tree.leaves(grads)))
+            with phase("grad"):
+                (loss, metrics), grads = jax.value_and_grad(
+                    lambda p: M.loss_fn(cfg, p, batch), has_aux=True
+                )(state.params)
+            with phase("optimizer"):
+                lr = lr_fn(state.step)
+                updates, new_opt = opt.update(grads, state.opt_state,
+                                              state.params, lr)
+                new_params = jax.tree.map(lambda p, u: p + u.astype(p.dtype),
+                                          state.params, updates)
+                gn = jnp.sqrt(sum(jnp.sum(g.astype(jnp.float32) ** 2)
+                                  for g in jax.tree.leaves(grads)))
             m = {"loss": loss, "lr": lr, "grad_norm": gn,
                  "n_accepted": jnp.asarray(K), "weights": jnp.ones((K,), jnp.float32)}
             new_state = TrainState(new_params, new_opt, None, state.step + 1)

@@ -44,6 +44,26 @@ def test_launcher_cli_end_to_end(tmp_path, capsys):
     assert os.path.exists(os.path.join(str(tmp_path), "step_3.npz"))
 
 
+def test_launcher_donates_the_train_state():
+    """build_everything keeps TrainConfig's donation: the step takes the
+    state's buffers for its outputs, so the launcher holds one copy."""
+    from repro.launch import train as T
+    from repro.train import trainer as tr
+    args = T.make_parser().parse_args([
+        "--arch", "qwen1.5-0.5b", "--reduced", "--d-model", "64", "--n-layers", "2",
+        "--vocab", "128", "--seq-len", "16", "--global-batch", "2",
+        "--chunk-size", "4096", "--sketch-dim", "128"])
+    cfg, mesh, tc = T.build_everything(args)
+    assert tc.donate
+    state = T.init_sharded_state(cfg, tc, mesh)
+    step = tr.build_train_step(cfg, tc, mesh)
+    batch = TokenStream(vocab_size=cfg.vocab_size, seq_len=16, batch_size=2).batch(0)
+    with mesh:
+        new_state, _ = step(state, batch)
+    assert all(x.is_deleted() for x in jax.tree.leaves(state))
+    assert not any(x.is_deleted() for x in jax.tree.leaves(new_state))
+
+
 def test_token_stream_deterministic():
     s = TokenStream(vocab_size=256, seq_len=16, batch_size=4, seed=3)
     b1, b2 = s.batch(5), s.batch(5)
