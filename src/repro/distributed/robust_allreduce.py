@@ -26,6 +26,30 @@ The temporal filter (WFAgg-T) runs on AMS count-sketches of the gradients
 (inner-product preserving), so its state is (K, sketch_dim) instead of
 (K, P) — this is the beyond-paper change that makes the paper's temporal
 statistics affordable at LLM scale.
+
+The stacked layout (``robust_allreduce_stacked``) keeps a leading K axis
+on every gradient leaf and exact WFAgg-T state (each worker's previous
+gradient).  With ``backend="fused"`` wfagg/alt_wfagg run through the
+WFAgg round kernel.  On a mesh whose candidate axes (every axis but
+'model') hold n > 1 devices the round is sliced over the parameters:
+
+  exchange   each leaf is resharded to K whole, one of its dims split n
+             ways (an all-to-all: every device receives (n-1)/n of a
+             gradient); ``prev`` is kept in the same layout, so it never
+             moves;
+  phase 0    every device runs the round kernel's statistics phase on
+             its (K, P/n) slice and ``prev`` slice
+             (``wfagg_round_indexed_stats``);
+  psum       ONE all-reduce of the O(K) accumulators; every device then
+             derives the same masks and weights
+             (``core.trust.derive_trust_weights``, as the kernel does at
+             its phase boundary);
+  combine    every device weighs its own slice; the aggregate is then
+             gathered whole for the optimizer.
+
+Outside a mesh, or with one candidate device, the round is ONE launch
+over the concatenated (K, P) candidates, which derives the weights
+in-kernel between its two phases.
 """
 from __future__ import annotations
 
@@ -36,6 +60,7 @@ from typing import Any, Dict, NamedTuple, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import aggregators as agg_lib
 from repro.core import attacks as atk
@@ -44,8 +69,11 @@ from repro.core.wfagg import (
     TemporalState, WFAggConfig, f32_dots, wfagg_scores, wfagg_t_decide,
     wfagg_t_select)
 from repro.distributed.logical import current_mesh
+from repro.distributed.spmd import SHARD_AXIS, psum_stats
 from repro.kernels.pairwise_dist.ops import pairwise_gram
-from repro.kernels.robust_stats.ops import robust_stats, wfagg_round_indexed
+from repro.kernels.robust_stats.kernel import round_padded_width
+from repro.kernels.robust_stats.ops import (
+    robust_stats, wfagg_round_indexed, wfagg_round_indexed_stats)
 from repro.obs import decision as obs_decision
 
 Array = jax.Array
@@ -84,10 +112,10 @@ class RobustAggConfig:
     # temporal metrics must stay full-precision while the D/C stats
     # quantize, which one read cannot provide); "fused_two_launch"
     # forces the separate stats launch + host scoring + jnp combine;
-    # "reference" keeps the per-leaf jnp loop.  The fused paths assume
-    # the candidates fit one process (mode-A scale / shard_map-manual
-    # regions); pure-GSPMD multi-pod sharding of the kernel is an open
-    # item.
+    # "reference" keeps the per-leaf jnp loop.  On a mesh of several
+    # candidate devices "fused" runs the round sliced over the
+    # parameters (module docstring); the two-launch shapes run whole on
+    # every device.
     backend: str = "reference"
 
     @property
@@ -146,9 +174,9 @@ def _whole_on_each_device(fn):
 
 
 def _pad_chunks(flat: Array, chunk: int) -> Tuple[Array, int]:
-    P = flat.shape[0]
-    n_chunks = max(1, -(-P // chunk))
-    pad = n_chunks * chunk - P
+    size = flat.shape[0]
+    n_chunks = max(1, -(-size // chunk))
+    pad = n_chunks * chunk - size
     return jnp.pad(flat, (0, pad)), n_chunks
 
 
@@ -516,13 +544,15 @@ def robust_allreduce_stacked(
 ) -> Tuple[Any, Optional[TreeAggState], Dict[str, Array]]:
     """Sharded robust aggregation over stacked candidate gradients.
 
-    Pure-GSPMD fast path (layout='stacked'): no shard_map, no manual
-    collectives.  Input leaves are (K, *param_shape) with the candidate
-    axis sharded over the data mesh axes; the output drops the candidate
-    axis.  Same consensus semantics as ``robust_allreduce``; the WFAgg-T
-    filter uses exact metrics against ``state.prev`` (each worker's
-    previous gradient, still candidate-sharded — one gradient per
-    device).
+    Pure-GSPMD fast path (layout='stacked').  Input leaves are
+    (K, *param_shape) with the candidate axis sharded over the data mesh
+    axes; the output drops the candidate axis.  Same consensus semantics
+    as ``robust_allreduce``; the WFAgg-T filter uses exact metrics
+    against ``state.prev`` (each worker's previous gradient — one
+    gradient per device).  The round kernel's route runs sliced over the
+    active mesh's candidate axes when they hold more than one device
+    (``_stacked_sliced_round``); ``state.prev`` then lies as
+    ``stacked_prev_spec`` says.
     """
     leaves = jax.tree.leaves(stacked)
     K = leaves[0].shape[0]
@@ -549,14 +579,21 @@ def robust_allreduce_stacked(
     fused = cfg.backend in ("fused", "fused_two_launch")
     temporal = (cfg.method in ("wfagg", "alt_wfagg") and cfg.wfagg.use_temporal
                 and state is not None)
-    # Single-launch route (backend="fused"): the whole wfagg/alt_wfagg
-    # aggregation — statistics, in-kernel weight derivation, weighted
-    # combine — in ONE round-kernel launch over the concatenated (K, P)
-    # candidates.  gather_dtype forces the two-launch shape instead: the
-    # temporal metrics must stay full-precision while the D/C statistics
-    # quantize, which a single candidate read cannot provide.
-    if (cfg.backend == "fused" and cfg.method in ("wfagg", "alt_wfagg")
-            and cfg.gather_dtype is None):
+    # Round-kernel route (backend="fused"): the whole wfagg/alt_wfagg
+    # aggregation — statistics, weight derivation, weighted combine —
+    # through the round kernel.  Where the active mesh's candidate axes
+    # hold more than one device, each device runs it on its 1/n slice of the
+    # parameters (``_stacked_sliced_round``); else it is ONE launch over
+    # the concatenated (K, P) candidates.  gather_dtype forces the
+    # two-launch shape instead: the temporal metrics must stay
+    # full-precision while the D/C statistics quantize, which a single
+    # candidate read cannot provide.
+    if _runs_round_kernel(cfg):
+        mesh = current_mesh()
+        axes, n = _candidate_axes(mesh)
+        if n > 1:
+            return _stacked_sliced_round(stacked, cfg, state, temporal,
+                                         mesh, axes, n)
         return _stacked_one_launch(stacked, cfg, state, temporal)
     # The temporal metrics are computed on FULL-precision candidates in
     # the reference path (gather_dtype only quantizes the D/C/Gram
@@ -645,6 +682,168 @@ def _stacked_one_launch(
             jnp.ones(weights[0].shape, bool), weights[0]),
     }
     return out, new_state, info
+
+
+def _runs_round_kernel(cfg: RobustAggConfig) -> bool:
+    """The stacked aggregation goes through the WFAgg round kernel."""
+    return (cfg.backend == "fused" and cfg.method in ("wfagg", "alt_wfagg")
+            and cfg.gather_dtype is None)
+
+
+def _candidate_axes(mesh) -> Tuple[Tuple[str, ...], int]:
+    """The axes of ``mesh`` the candidates are split over — every axis
+    but the model axis — and the devices they hold; ((), 1) without a
+    mesh."""
+    if mesh is None:
+        return (), 1
+    axes = tuple(a for a in mesh.axis_names if a != SHARD_AXIS)
+    return axes, math.prod(mesh.shape[a] for a in axes)
+
+
+def _sliced_spec(shape: Tuple[int, ...], axes: Tuple[str, ...],
+                 n: int) -> Optional[P]:
+    """Spec of a (K, *shape) candidate leaf in the sliced round: K whole,
+    the first dim of ``shape`` that ``n`` divides split over ``axes``;
+    None when no dim divides."""
+    j = next((i for i, size in enumerate(shape) if size % n == 0), None)
+    if j is None:
+        return None
+    ax = axes if len(axes) > 1 else axes[0]
+    return P(None, *[ax if i == j else None for i in range(len(shape))])
+
+
+def stacked_prev_spec(cfg: RobustAggConfig, shape: Tuple[int, ...],
+                      param_spec: P, data_axes: Tuple[str, ...], mesh) -> P:
+    """Sharding of one ``TreeAggState.prev`` leaf (K, *shape) in a train
+    state: where ``robust_allreduce_stacked`` runs the round sliced over
+    ``mesh``, the slice of every worker's gradient that device reads and
+    writes (so no collective moves ``prev``); else each worker's own
+    gradient, K over ``data_axes``, its dims as the parameter's."""
+    axes, n = _candidate_axes(mesh)
+    spec = (_sliced_spec(shape, axes, n)
+            if _runs_round_kernel(cfg) and n > 1 else None)
+    if spec is None:
+        dax = data_axes if len(data_axes) > 1 else data_axes[0]
+        spec = P(dax, *tuple(param_spec))
+    return spec
+
+
+def _stacked_sliced_round(
+    stacked: Any,
+    cfg: RobustAggConfig,
+    state: Optional[TreeAggState],
+    temporal: bool,
+    mesh,
+    axes: Tuple[str, ...],
+    n: int,
+) -> Tuple[Any, Optional[TreeAggState], Dict[str, Array]]:
+    """The round of ``_stacked_one_launch`` with the parameters split
+    over the ``n`` devices of ``axes``: each device reads only its 1/n
+    slice of every candidate and of ``prev``.
+
+    Each leaf is resharded so that K is whole and one of its dims is
+    split over ``axes`` (an all-to-all; a leaf no dim of which ``n``
+    divides rides flattened and zero-padded).  Inside a ``shard_map``
+    every device concatenates its slices into one (K, P/n) matrix,
+    zero-padded to ``round_padded_width`` so the round kernel's tile
+    stays wide, and runs the kernel's statistics phase on it
+    (``wfagg_round_indexed_stats``).  One psum of the O(K) accumulators
+    follows, then every device derives the same masks and weights with
+    the functions the kernel calls at its phase boundary, and combines
+    its own slice: ``sum_k w_k u_k`` in slot order, an XLA fusion.  The
+    aggregate is gathered whole onto every device for the optimizer.
+
+    Exact as the one launch is, up to the accumulators' summation order:
+    every WFAgg statistic is a sum over coordinates or a per-coordinate
+    median, so a permutation of the coordinates and zero columns change
+    none of them (``distributed/spmd.py``).  The new ``prev`` is the
+    resharded candidates, left where the next step reads them.
+    """
+    leaves, treedef = jax.tree_util.tree_flatten(stacked)
+    K = leaves[0].shape[0]
+    w = _effective_wfagg_config(cfg, K)
+    ax = axes if len(axes) > 1 else axes[0]
+    specs = [_sliced_spec(leaf.shape[1:], axes, n) for leaf in leaves]
+    in_specs = [sp if sp is not None else P(None, ax) for sp in specs]
+
+    def slices(tree):
+        out = []
+        for leaf, sp in zip(jax.tree.leaves(tree), specs):
+            leaf = leaf.astype(jnp.float32)
+            if sp is None:
+                flat = leaf.reshape(K, -1)
+                leaf = jnp.pad(flat, ((0, 0), (0, -flat.shape[1] % n)))
+            out.append(leaf)
+        return out
+
+    # the exchange: K whole, the parameters split (the new prev too)
+    cands = [jax.lax.with_sharding_constraint(c, NamedSharding(mesh, sp))
+             for c, sp in zip(slices(stacked), in_specs)]
+    tbands = (trust.temporal_bands(state.hist_s, state.hist_b, state.count,
+                                   state.t, w)[None] if temporal else None)
+
+    def body(us, prevs, tb):
+        width = sum(math.prod(u.shape[1:]) for u in us)
+        pad = round_padded_width(K, width, temporal) - width
+
+        def matrix(parts):
+            # built as the kernel's (K, 1, width) row view, so the
+            # concatenation writes the launch's layout directly
+            parts = [x.reshape(K, 1, -1) for x in parts]
+            if pad:
+                parts.append(jnp.zeros((K, 1, pad), jnp.float32))
+            return jnp.concatenate(parts, axis=2).reshape(K, -1)
+
+        nidx = jnp.arange(K, dtype=jnp.int32)[None, :]   # identity slate
+        st = wfagg_round_indexed_stats(
+            matrix(us), nidx, matrix(prevs) if temporal else None,
+            need_gram=trust.needs_gram(w))
+        st = psum_stats(st, axes)
+        valid = jnp.ones((1, K), jnp.float32)
+        gram = st.gram[0] if st.gram is not None else None
+        mask_d, mask_c, mask_t, weights = trust.derive_trust_weights(
+            st, gram, valid, tb, w)
+        wcomb, _ = trust.combine_coefficients(weights, 1.0, valid, True)
+        out = []
+        for u in us:
+            acc = wcomb[0, 0] * u[0]
+            for k in range(1, K):
+                acc = acc + wcomb[0, k] * u[k]
+            out.append(acc)
+        tail = ((st.prev_dist2[0], st.cosine_to_prev()[0]) if temporal
+                else None)
+        return out, (mask_d[0], mask_c[0], mask_t[0], weights[0]), tail
+
+    out, (mask_d, mask_c, mask_t, weights), tail = jax.shard_map(
+        body, mesh=mesh,
+        in_specs=(in_specs, in_specs if temporal else None, P()),
+        out_specs=([P(*sp[1:]) for sp in in_specs], P(), P()),
+        check_vma=False,
+    )(cands, slices(state.prev) if temporal else None, tbands)
+
+    rep = NamedSharding(mesh, P())
+    outs = []
+    for o, leaf, sp in zip(out, leaves, specs):
+        if sp is None:
+            o = o[:math.prod(leaf.shape[1:])].reshape(leaf.shape[1:])
+        outs.append(jax.lax.with_sharding_constraint(o.astype(leaf.dtype), rep))
+    new_state = state
+    if temporal:
+        hist_s, hist_b, count, t = trust.push_history(
+            state.hist_s, state.hist_b, state.count, state.t, *tail)
+        prev = [c if sp is not None
+                else c[:, :math.prod(leaf.shape[1:])].reshape(leaf.shape)
+                for c, leaf, sp in zip(cands, leaves, specs)]
+        new_state = TreeAggState(
+            prev=jax.tree_util.tree_unflatten(treedef, prev),
+            hist_s=hist_s, hist_b=hist_b, count=count, t=t)
+    info = {
+        "mask_d": mask_d, "mask_c": mask_c, "mask_t": mask_t,
+        "weights": weights, "n_accepted": (weights > 0).sum(),
+        "record": obs_decision.record_from_masks(
+            mask_d, mask_c, mask_t, jnp.ones(weights.shape, bool), weights),
+    }
+    return jax.tree_util.tree_unflatten(treedef, outs), new_state, info
 
 
 # ---------------------------------------------------------------------------

@@ -411,16 +411,33 @@ def round_tile_width(K: int, d: int, has_prev: bool) -> int:
     of the (K, d) matrices in HBM.  VMEM depends on (K, T) only, never
     on d (LeNet's d = 44,426: T = 4,096 at K = 30, 11 tiles; 22,528 at
     K = 8, 2 tiles; 45,056 lanes either way)."""
-    lane_bytes = 4 * (2 * K * (2 if has_prev else 1) + 4)
-    m_cap = max(1, ROUND_TILE_BUDGET // (1024 * lane_bytes))
+    m_cap = _round_tile_cap(K, has_prev)
     nb = -(-d // 1024)
     return 1024 * max(m for m in range(1, min(m_cap, nb) + 1) if nb % m == 0)
+
+
+def _round_tile_cap(K: int, has_prev: bool) -> int:
+    """The widest tile ``ROUND_TILE_BUDGET`` allows, in 1024-lane blocks."""
+    lane_bytes = 4 * (2 * K * (2 if has_prev else 1) + 4)
+    return max(1, ROUND_TILE_BUDGET // (1024 * lane_bytes))
+
+
+def round_padded_width(K: int, d: int, has_prev: bool) -> int:
+    """d rounded up to the fewest tiles of at most the budget's width,
+    each as narrow as that count allows: a caller that builds its (K, d)
+    matrix anyway pads it this far, so ``round_tile_width`` finds a wide
+    tile whatever d's factors (the robust-DP trainer's per-chip slice,
+    P / 4 = 63,083 blocks of 1024 lanes = 199 x 317, would otherwise get
+    T = 1,024).  The pad is under one 1024-lane block a tile."""
+    nb = -(-d // 1024)
+    n_t = -(-nb // _round_tile_cap(K, has_prev))
+    return 1024 * n_t * -(-nb // n_t)
 
 
 def _wfagg_round_indexed_kernel(*refs, K: int, n_t: int, T: int, chunk: int,
                                 has_prev: bool, prev_row, has_tbands: bool,
                                 need_gram: bool, cfg, alpha: float,
-                                mean_fallback: bool):
+                                mean_fallback: bool, stats_only: bool):
     """Single-launch WFAgg round body: grid (node, PHASE, D tile).
 
     Each step gathers the node's K neighbor rows of one (K, T) tile with
@@ -443,6 +460,11 @@ def _wfagg_round_indexed_kernel(*refs, K: int, n_t: int, T: int, chunk: int,
     (1, 4K) EWMA band input (``core.trust.temporal_bands`` — the history
     lives outside the kernel); the ring-buffer push happens on the host
     off the emitted temporal statistics.
+
+    ``stats_only`` runs phase 0 alone (grid (node, 1, D tile)) and emits
+    only the accumulators: the launch of a caller that holds a slice of
+    the columns and sums the accumulators across slices before it scores
+    (``distributed.robust_allreduce``'s sliced round).
     """
     # deferred import: core.wfagg -> robust_stats.ops -> this module at
     # package-init time; by kernel-trace time repro.core is fully loaded
@@ -452,22 +474,24 @@ def _wfagg_round_indexed_kernel(*refs, K: int, n_t: int, T: int, chunk: int,
     refs = list(refs[1:])
     valid_ref = refs.pop(0)
     tbands_ref = refs.pop(0) if has_tbands else None
-    local_ref = refs.pop(0)
+    local_ref = None if stats_only else refs.pop(0)
     models_hbm = refs.pop(0)
     prev_hbm = refs.pop(0) if has_prev else None
-    out_ref, w_ref, md_ref, mc_ref, mt_ref = refs[:5]
+    n_out = 0 if stats_only else 5
+    out_ref, w_ref, md_ref, mc_ref, mt_ref = refs[:n_out] or (None,) * 5
     n_acc = 4 + (1 if need_gram else 0) + (3 if has_prev else 0)
-    acc_refs = refs[5:5 + n_acc]
-    scratch = refs[5 + n_acc:]
+    acc_refs = refs[n_out:n_out + n_acc]
+    scratch = refs[n_out + n_acc:]
     dist2_ref, dotmed_ref, norm2_ref, mednorm2_ref = acc_refs[:4]
     gram_ref = acc_refs[4] if need_gram else None
     prev_acc = acc_refs[5 if need_gram else 4:] if has_prev else ()
     land_u, sem_u = scratch[0], scratch[1]
     land_p, sem_p = (scratch[2], scratch[3]) if has_prev else (None, None)
-    wcomb_ref, lcoef_ref = scratch[-2], scratch[-1]
+    wcomb_ref, lcoef_ref = (None, None) if stats_only else scratch[-2:]
 
+    n_phase = 1 if stats_only else 2
     n, p, i = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    step = (2 * n + p) * n_t + i
+    step = (n_phase * n + p) * n_t + i
     slot = jax.lax.rem(step, 2)
 
     def gather(nn, pp, ii, s, op):
@@ -492,8 +516,8 @@ def _wfagg_round_indexed_kernel(*refs, K: int, n_t: int, T: int, chunk: int,
         gather(n, p, i, slot, "start")
 
     last_t = i == n_t - 1
-    nxt_n = jnp.where(last_t & (p == 1), n + 1, n)
-    nxt_p = jnp.where(last_t, 1 - p, p)
+    nxt_n = jnp.where(last_t & (p == n_phase - 1), n + 1, n)
+    nxt_p = jnp.where(last_t, 1 - p, p) if n_phase == 2 else p
     nxt_i = jnp.where(last_t, 0, i + 1)
 
     @pl.when(nxt_n < pl.num_programs(0))
@@ -516,6 +540,9 @@ def _wfagg_round_indexed_kernel(*refs, K: int, n_t: int, T: int, chunk: int,
             return carry
 
         jax.lax.fori_loop(0, T // chunk, flush, 0)
+
+    if stats_only:
+        return
 
     @pl.when((p == 0) & last_t)
     def _derive():
@@ -568,6 +595,7 @@ def wfagg_round_indexed_pallas(
     need_gram: bool = False,
     block_d: int,
     interpret: bool | None = None,
+    stats_only: bool = False,
 ):
     """Launch the single-launch WFAgg round kernel over a 3-D
     (node, phase, D tile) grid with (K, block_d) tiles.  ``models`` and
@@ -585,11 +613,18 @@ def wfagg_round_indexed_pallas(
     Returns (out (N, 1, D), weights, mask_d, mask_c, mask_t (each
     (N, 1, K)), dist2, dotmed, norm2 ((N, 1, K)), mednorm2 ((N, 1, 1))
     [, gram (N, K, K)][, prev_dist2, prev_dot, prev_norm2 ((N, 1, K))]).
+
+    ``stats_only`` (``local`` and ``tbands`` None) runs phase 0 alone
+    over a (node, 1, D tile) grid and returns the accumulators only,
+    from ``dist2`` on.
     """
     M, D = models.shape
     N, K = neighbor_idx.shape
     assert D % block_d == 0, (D, block_d)
-    assert local.shape == (N, D), (local.shape, (N, D))
+    if stats_only:
+        assert local is None and tbands is None
+    else:
+        assert local.shape == (N, D), (local.shape, (N, D))
     n_t = D // block_d
     has_prev = prev is not None
     has_tbands = tbands is not None
@@ -611,8 +646,11 @@ def wfagg_round_indexed_pallas(
     # until the combine phase
     row_spec = pl.BlockSpec((None, 1, block_d),
                             lambda n, p, i, ir: (n, 0, i * p))
-    in_specs += [row_spec, hbm]
-    args += [_row_view(local), _row_view(models)]
+    if not stats_only:
+        in_specs.append(row_spec)
+        args.append(_row_view(local))
+    in_specs.append(hbm)
+    args.append(_row_view(models))
     table, prev_row = neighbor_idx, None
     if has_prev:
         view, table, prev_row = _prev_rows(prev, models, neighbor_idx,
@@ -624,23 +662,27 @@ def wfagg_round_indexed_pallas(
         _wfagg_round_indexed_kernel, K=K, n_t=n_t, T=block_d, chunk=chunk,
         has_prev=has_prev, prev_row=prev_row, has_tbands=has_tbands,
         need_gram=need_gram, cfg=cfg, alpha=alpha,
-        mean_fallback=mean_fallback,
+        mean_fallback=mean_fallback, stats_only=stats_only,
     )
 
-    out_shapes = [
+    out_shapes = [] if stats_only else [
         jax.ShapeDtypeStruct((N, 1, D), jnp.float32),   # combined models
         jax.ShapeDtypeStruct((N, 1, K), jnp.float32),   # trust weights
         jax.ShapeDtypeStruct((N, 1, K), jnp.float32),   # mask_d
         jax.ShapeDtypeStruct((N, 1, K), jnp.float32),   # mask_c
         jax.ShapeDtypeStruct((N, 1, K), jnp.float32),   # mask_t
+    ]
+    out_shapes += [
         jax.ShapeDtypeStruct((N, 1, K), jnp.float32),   # dist2
         jax.ShapeDtypeStruct((N, 1, K), jnp.float32),   # dotmed
         jax.ShapeDtypeStruct((N, 1, K), jnp.float32),   # norm2
         jax.ShapeDtypeStruct((N, 1, 1), jnp.float32),   # mednorm2
     ]
-    out_specs = [
+    out_specs = [] if stats_only else [
         row_spec,
         k_spec, k_spec, k_spec, k_spec,                  # weights + masks
+    ]
+    out_specs += [
         k_spec, k_spec, k_spec,
         pl.BlockSpec((None, 1, 1), lambda n, p, i, ir: (n, 0, 0)),
     ]
@@ -656,11 +698,12 @@ def wfagg_round_indexed_pallas(
     land = [pltpu.VMEM((2, K, 1, block_d), jnp.float32),
             pltpu.SemaphoreType.DMA((2,))]
     scratch_shapes = land * (2 if has_prev else 1)
-    scratch_shapes += [pltpu.VMEM((1, K), jnp.float32),   # combine weights
-                       pltpu.VMEM((1, 1), jnp.float32)]   # local coefficient
+    if not stats_only:
+        scratch_shapes += [pltpu.VMEM((1, K), jnp.float32),  # combine weights
+                           pltpu.VMEM((1, 1), jnp.float32)]  # local coefficient
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(N, 2, n_t),
+        grid=(N, 1 if stats_only else 2, n_t),
         in_specs=in_specs,
         out_specs=tuple(out_specs),
         scratch_shapes=scratch_shapes,

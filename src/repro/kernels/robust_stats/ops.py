@@ -281,19 +281,58 @@ def wfagg_round_indexed(
     out = outs[0][:, 0, :d]
     weights = outs[1][:, 0, :]
     mask_d, mask_c, mask_t = (o[:, 0, :] > 0.0 for o in outs[2:5])
-    dist2, dotmed, norm2, mednorm2 = outs[5:9]
-    rest = outs[9:]
+    stats = _round_stats(outs[5:], trust.needs_gram(cfg), prev is not None)
+    return out, weights, mask_d, mask_c, mask_t, stats
+
+
+def _round_stats(accs, need_gram: bool, has_prev: bool) -> RobustStats:
+    """The round kernel's accumulator outputs, (dist2, dotmed, norm2,
+    mednorm2[, gram][, prev_dist2, prev_dot, prev_norm2]), as a
+    ``RobustStats`` of (N, K) rows."""
+    dist2, dotmed, norm2, mednorm2 = accs[:4]
+    rest = accs[4:]
     gram = None
-    if trust.needs_gram(cfg):
+    if need_gram:
         gram, rest = rest[0], rest[1:]
     tail = (None, None, None)
-    if prev is not None:
+    if has_prev:
         tail = tuple(o[:, 0, :] for o in rest)
-    stats = RobustStats(
+    return RobustStats(
         med=None, trim=None,
         dist2=dist2[:, 0, :], dotmed=dotmed[:, 0, :], norm2=norm2[:, 0, :],
         mednorm2=mednorm2[:, 0, 0],
         prev_dist2=tail[0], prev_dot=tail[1], prev_norm2=tail[2],
         gram=gram,
     )
-    return out, weights, mask_d, mask_c, mask_t, stats
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "need_gram", "block_d", "interpret"))
+def wfagg_round_indexed_stats(
+    models: jax.Array,         # (M, d) model matrix
+    neighbor_idx: jax.Array,   # (N, K) rows into models
+    prev: Optional[jax.Array] = None,    # (M, d) matrix, rows as models'
+    need_gram: bool = False,
+    block_d: Optional[int] = None,
+    interpret: Optional[bool] = None,
+) -> RobustStats:
+    """Phase 0 of ``wfagg_round_indexed`` alone, every slot valid: the
+    round kernel's statistics (the Gram with ``need_gram``, the WFAgg-T
+    tail with ``prev``) and no scoring or combine.  For a caller that
+    holds a slice of d and sums the accumulators across slices before
+    it scores them.  The fields carry a leading N axis, as
+    ``wfagg_round_indexed``'s statistics do; the tile is its too, so a d
+    that ``round_padded_width`` rounded needs no padded copy."""
+    N, K = neighbor_idx.shape
+    d = models.shape[-1]
+    itp = resolve_interpret(interpret)
+    if block_d is None:
+        block_d = (auto_block_d(d, itp, interpret_blocks=1) if itp
+                   else round_tile_width(K, d, prev is not None))
+    m = pad_d(models, block_d)
+    p = pad_d(prev, block_d) if prev is not None else None
+    outs = wfagg_round_indexed_pallas(
+        None, m, neighbor_idx, jnp.ones((N, K), jnp.float32), None, p,
+        alpha=1.0, need_gram=need_gram, block_d=block_d, interpret=itp,
+        stats_only=True)
+    return _round_stats(outs, need_gram, prev is not None)

@@ -45,6 +45,7 @@ from repro.distributed.robust_allreduce import (
     init_tree_agg_state,
     robust_allreduce,
     robust_allreduce_stacked,
+    stacked_prev_spec,
 )
 from repro.models import model as M
 from repro.obs.profile import phase
@@ -136,12 +137,13 @@ def state_shardings(cfg: ArchConfig, tc: TrainConfig, mesh: Mesh,
     if state_shape.agg_state is None:
         aspecs = None
     elif isinstance(state_shape.agg_state, TreeAggState):
-        # prev: leading candidate axis over the data axes, inner dims keep
-        # the param's TP sharding (shifted one dim right).
+        # prev: where the aggregation reads it (stacked_prev_spec)
         prev_p = shd.param_specs(cfg, state_shape.params, fsdp=False,
                                  data_axes=data_axes, mesh=mesh)
-        dax = data_axes if len(data_axes) > 1 else data_axes[0]
-        prev_specs = jax.tree.map(lambda sp: P(dax, *tuple(sp)), prev_p)
+        prev_specs = jax.tree.map(
+            lambda sp, leaf: stacked_prev_spec(tc.agg, leaf.shape, sp,
+                                               data_axes, mesh),
+            prev_p, state_shape.params)
         aspecs = TreeAggState(prev=prev_specs,
                               hist_s=P(), hist_b=P(), count=P(), t=P())
     else:

@@ -116,14 +116,12 @@ def test_round_kernel_compiles_trainer(one_chip, temporal):
         *args)
 
 
-def test_trainer_step_compiles_on_four_chips(topo, monkeypatch):
+@pytest.fixture(scope="module")
+def trainer_step_four_chips(topo):
     """The robust-DP trainer's whole step, as the four-chip bring-up
     check runs it (qwen1.5-0.5b widths at 8 layers, stacked WFAgg with
-    the fused round, a (data=4, model=1) mesh, donated state), compiles
-    for a described v5e 2x2 and fits its HBM.  P = 258,383,872 is a
-    multiple of 1024, so the round's tile must divide it: a tile that
-    padded the (K, P) gradients would add padded copies of them to the
-    step's temporaries, and the step would no longer fit."""
+    the fused round, a (data=4, model=1) mesh, donated state), compiled
+    for a described v5e 2x2: (compiled text, parameter shapes)."""
     import dataclasses
 
     import numpy as np
@@ -135,11 +133,8 @@ def test_trainer_step_compiles_on_four_chips(topo, monkeypatch):
     from repro.data.synthetic import TokenStream
     from repro.train import trainer as tr
 
-    # the described chips are not the default backend: pin the Mosaic path
-    monkeypatch.setattr(kcommon, "default_interpret", lambda: False)
     mesh = Mesh(np.array(topo.devices).reshape(4, 1), ("data", "model"))
     cfg = dataclasses.replace(get_config("qwen1.5-0.5b"), n_layers=8)
-    assert cfg.param_count() % 1024 == 0
     tc = tr.TrainConfig(
         agg=RobustAggConfig(method="wfagg", layout="stacked",
                             wfagg=WFAggConfig(f=1), backend="fused"),
@@ -155,10 +150,50 @@ def test_trainer_step_compiles_on_four_chips(topo, monkeypatch):
     batch = jax.tree.map(
         lambda a, sh: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh),
         bshape, tr.batch_shardings(tc, mesh, bshape))
-    with mesh:
+    # the described chips are not the default backend: pin the Mosaic path
+    with pytest.MonkeyPatch.context() as mp, mesh:
+        mp.setattr(kcommon, "default_interpret", lambda: False)
         compiled = tr.build_train_step(cfg, tc, mesh).lower(
             state, batch).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    return compiled.as_text(), [tuple(p.shape) for p in
+                                jax.tree.leaves(shape.params)]
+
+
+def test_trainer_step_compiles_on_four_chips(trainer_step_four_chips):
+    """The step compiles with the Mosaic round kernel in it and fits
+    the chips' HBM."""
+    assert "tpu_custom_call" in trainer_step_four_chips[0]
+
+
+def test_trainer_step_reads_only_its_slice(trainer_step_four_chips):
+    """Each chip aggregates its 1/4 slice of every candidate: no
+    all-gather or all-to-all of the step yields K whole candidates or
+    K whole ``prev`` rows, flattened ((K, P)) or leaf by leaf
+    ((K, *leaf)), nor anything larger than the largest leaf; and every
+    Pallas launch is the round kernel's (the benchmark reads the
+    ``wfagg_round_indexed`` prefix)."""
+    import math
+    import re
+
+    hlo, leaves = trainer_step_four_chips
+    k = 4
+    p_total = sum(math.prod(s) for s in leaves)
+    whole = {(k,) + s for s in leaves} | {(k, math.prod(s)) for s in leaves}
+    whole.add((k, p_total))
+    largest = max(math.prod(s) for s in leaves)
+    moved = re.findall(
+        r"= (\(?[^=]*?) (all-gather|all-to-all)(?:-start)?\(", hlo)
+    assert moved, "the step moves no candidate"
+    for result, op in moved:
+        for dims in re.findall(r"f32\[([\d,]*)\]", result):
+            shape = tuple(int(x) for x in dims.split(",") if x)
+            squeezed = tuple(x for x in shape if x != 1)
+            assert squeezed not in whole, (op, shape)
+            assert math.prod(shape) <= largest, (op, shape)
+    launches = re.findall(
+        r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", hlo)
+    assert launches and all(
+        n.startswith("wfagg_round_indexed") for n in launches), launches
 
 
 @pytest.mark.parametrize("need_gram", [False, True], ids=["stats", "gram"])
