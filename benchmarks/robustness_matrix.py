@@ -202,7 +202,7 @@ def main(argv=None):
     ap.add_argument("--topology", default="ring",
                     choices=("ring", "complete", "erdos_renyi"))
     ap.add_argument("--backend", default="fused",
-                    choices=("fused", "fused_two_launch", "reference"),
+                    choices=("fused", "reference"),
                     help="WFAgg execution backend for wfagg/alt_wfagg cells")
     ap.add_argument("--model", default="mlp", choices=("mlp", "lenet"))
     ap.add_argument("--placement", default="close",

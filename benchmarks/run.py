@@ -2,7 +2,6 @@
 
   table1        paper Table I   (accuracy per aggregator x attack, CFL+DFL)
   r2            paper Figs 4/6  (R^2 model consistency, DFL)
-  microbench    aggregation-rule complexity table (Section IV)
   roofline      Section Roofline report from dry-run artifacts
 
 ``python -m benchmarks.run`` runs the fast versions of everything;
@@ -19,12 +18,12 @@ HERE = os.path.dirname(__file__)
 
 def main() -> None:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--only", default="", help="comma list: table1,r2,microbench,roofline")
+    ap.add_argument("--only", default="", help="comma list: table1,r2,roofline")
     ap.add_argument("--full", action="store_true")
     ap.add_argument("--rounds", type=int, default=10)
     args = ap.parse_args()
     selected = set(args.only.split(",")) if args.only else {
-        "table1", "r2", "microbench", "roofline"}
+        "table1", "r2", "roofline"}
 
     t0 = time.time()
     results = {}
@@ -46,18 +45,6 @@ def main() -> None:
         results["r2"] = consistency_r2.main(
             ["--rounds", str(args.rounds),
              "--out", os.path.join(HERE, "out_r2.json")])
-
-    if "microbench" in selected:
-        print("=" * 72)
-        print("== aggregation microbenchmark ==")
-        from benchmarks import agg_microbench
-        # every run appends to the BENCH_agg.json trajectory so future
-        # PRs have a perf baseline (rule, K, d, us_per_call, backend)
-        argv = ["--out", os.path.join(HERE, "out_microbench.json"),
-                "--bench-json", os.path.join(HERE, "BENCH_agg.json")]
-        if args.full:
-            argv.append("--kernels")
-        results["microbench"] = agg_microbench.main(argv)
 
     if "roofline" in selected:
         print("=" * 72)

@@ -7,8 +7,8 @@ accuracy trace of the paper's Figure 7.
 
 Beyond-paper switches: ``--topology erdos_renyi`` runs the gather-free
 irregular-degree path (padded neighbor tables), ``--backend
-fused|fused_two_launch|reference`` selects the WFAgg execution backend
-(fused = the single-launch round kernel, the default), and
+fused|reference`` selects the WFAgg execution backend (fused = the
+single-launch round kernel, the default), and
 ``--scenario churn|link_failure|partition|mobility|sleeper|eclipse|dos|
 collusion`` runs the whole experiment under a round-varying topology
 schedule (one jit, lax.scan over the schedule — the graph and the
@@ -55,10 +55,9 @@ def main() -> None:
                     help="gossip graph; erdos_renyi exercises the "
                          "irregular-degree (padded-table) path")
     ap.add_argument("--backend", default="fused",
-                    choices=("fused", "fused_two_launch", "reference"),
+                    choices=("fused", "reference"),
                     help="WFAgg execution backend (fused = single-launch "
-                         "gather-free round kernel; fused_two_launch = "
-                         "separate stats + combine launches; reference = "
+                         "gather-free round kernel; reference = "
                          "multi-pass jnp, valid-aware)")
     ap.add_argument("--scenario", default="",
                     choices=("",) + SCENARIO_NAMES,

@@ -1,14 +1,14 @@
 #!/usr/bin/env bash
-# Tier-1 gate + benchmark smoke: run before merging.
+# Tier-1 gate: run before merging.
 #
-#   ./scripts/check.sh                 tier-1 tests + smoke-size microbench
+#   ./scripts/check.sh                 tier-1 tests, passes gate, linter
 #   FAST=1 ./scripts/check.sh          skip the slow end-to-end trainer tests
 #   DYNAMICS_SMOKE=1 ./scripts/check.sh
 #                                      dynamics-only smoke: one short
 #                                      --scenario churn experiment through
 #                                      the scenario engine (the CI
 #                                      dynamics job), skipping the full
-#                                      pytest + microbench gate
+#                                      pytest gate
 #   OBS_SMOKE=1 ./scripts/check.sh     flight-recorder smoke: a small
 #                                      telemetry-on experiment through
 #                                      python -m repro.obs.report, with
@@ -34,16 +34,7 @@
 #                                      writes the report it uploads),
 #                                      then run the 8-device parity +
 #                                      fire checks, skipping the full
-#                                      pytest + microbench gate
-#
-# The microbench invocation exercises the Pallas kernel paths (fused
-# robust_stats incl. the batched, +prev and schedule-swap variants) at a
-# smoke size so the bench path itself cannot rot silently.  Smoke rows
-# are NOT appended to the committed benchmarks/BENCH_agg.json baseline —
-# real trajectory entries come from `python -m benchmarks.run`.  Set
-# BENCH_JSON=<path> to append this run's rows somewhere (CI appends to
-# its workspace copy of BENCH_agg.json so the uploaded artifact carries
-# the run's own numbers, not just the committed baseline).
+#                                      pytest gate
 set -euo pipefail
 cd "$(dirname "$0")/.."
 export PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}"
@@ -112,12 +103,9 @@ else
   python -m pytest -x -q
 fi
 
-python benchmarks/agg_microbench.py --kernels --sizes 8x4096 \
-  --bench-json "${BENCH_JSON:-}"
-
 # memory_passes() for the shipped configs must not exceed the traffic
 # table documented in src/repro/kernels/README.md (single-launch = 2:
-# phase 1 re-reads the candidates, as the two-launch path does).
+# phase 1 re-reads the candidates).
 python scripts/passes_gate.py
 
 # Computation linter: one static-analysis pass over the jaxprs, optimized

@@ -57,7 +57,7 @@ def count_pallas_calls(jaxpr: Jaxpr) -> int:
     """Recursively count ``pallas_call`` eqns through all sub-jaxprs.
 
     This is the launch counter the one-launch round test pins to 1 (and
-    the two-launch fallback to 2) — hoisted here from
+    the round's two phases run alone to 2) — hoisted here from
     ``tests/test_one_launch.py`` so every entry point shares it."""
     return sum(1 for e in walk_eqns(jaxpr) if e.primitive.name == "pallas_call")
 

@@ -1,7 +1,7 @@
 """The registered lint targets.
 
 Every computation the repo ships — the fused one-launch round, the
-two-launch fallback, the valid-aware reference oracle, the
+valid-aware reference oracle, the
 dynamic-scenario scan, stacked ``robust_allreduce`` mode-B — is
 registered here as an :class:`~repro.analysis.rules.EntryPoint` and gets
 the FULL rule gate on every ``python -m repro.analysis`` run.  A new
@@ -42,15 +42,14 @@ def _ring_fixture():
     return topo, data, sched
 
 
-def _build_dynamic_round(aggregator: str, backend: str):
-    """(fn, args) for one jitted dynamic round under ``backend``."""
+def _build_dynamic_round(aggregator: str):
+    """(fn, args) for one jitted dynamic round under the fused backend."""
     import jax.numpy as jnp
 
     from repro.dfl.engine import DFLConfig, build_round_fn, init_dfl_state
 
     topo, data, sched = _ring_fixture()
-    cfg = DFLConfig(aggregator=aggregator, attack="ipm_100", model="mlp",
-                    wfagg_backend=backend)
+    cfg = DFLConfig(aggregator=aggregator, attack="ipm_100", model="mlp")
     fn = build_round_fn(cfg, topo, data, dynamic=True)
     state = init_dfl_state(cfg, topo, degree=sched.width)
     args = (state, jnp.asarray(sched.neighbor_idx[0]),
@@ -118,13 +117,14 @@ MLP_D_PAD = MLP_D + (-MLP_D) % _SHARDS
 
 
 def _build_sharded_round():
-    """One sharded gossip round: the two-launch decomposition per shard
-    (local stats, O(N*K) psum, replicated scoring, local combine), the
-    (N, d) state pinned P(None, 'model') at the jit boundary."""
+    """One sharded gossip round: the round kernel's two phases as two
+    launches per shard (local stats, O(N*K) psum, replicated scoring,
+    local combine), the (N, d) state pinned P(None, 'model') at the jit
+    boundary."""
     from repro.core.wfagg import WFAggConfig
     from repro.distributed import spmd
 
-    cfg = WFAggConfig(backend="fused_two_launch", f=1, window=3, transient=1)
+    cfg = WFAggConfig(f=1, window=3, transient=1)
     mesh = spmd.aggregation_mesh(_SHARDS)
     return spmd.sharded_round_jit(cfg, mesh, n=_N, k=_DEGREE, d=MLP_D_PAD)
 
@@ -136,7 +136,7 @@ def _build_sharded_scan():
     from repro.core.wfagg import WFAggConfig
     from repro.distributed import spmd
 
-    cfg = WFAggConfig(backend="fused_two_launch", f=1, window=3, transient=1)
+    cfg = WFAggConfig(f=1, window=3, transient=1)
     mesh = spmd.aggregation_mesh(_SHARDS)
     return spmd.sharded_scan_jit(cfg, mesh, n=_N, k=_DEGREE, d=MLP_D_PAD,
                                  rounds=_ROUNDS)
@@ -231,7 +231,7 @@ def entry_points() -> Dict[str, EntryPoint]:
             name="one_launch_round",
             description="fused single-launch dynamic WFAgg round "
                         "(backend='fused', the default)",
-            build=lambda: _build_dynamic_round("wfagg", "fused"),
+            build=lambda: _build_dynamic_round("wfagg"),
             expected_launches=1, nkd=nkd,
             compile_once=_compile_once_probe,
             passes=(("single-launch indexed gossip round (the default)",
@@ -242,20 +242,10 @@ def entry_points() -> Dict[str, EntryPoint]:
             name="one_launch_round_alt",
             description="fused single-launch Alt-WFAgg round (in-kernel "
                         "Gram + Multi-Krum/Clustering)",
-            build=lambda: _build_dynamic_round("alt_wfagg", "fused"),
+            build=lambda: _build_dynamic_round("alt_wfagg"),
             expected_launches=1, nkd=nkd,
             passes=(("single-launch indexed Alt-WFAgg (Gram folded into "
                      "the stats phase)", alt_wfagg_config(),
-                     dict(include_gather=True, indexed=True), 2),),
-        ),
-        EntryPoint(
-            name="two_launch_round",
-            description="two-launch indexed fallback "
-                        "(backend='fused_two_launch', parity path)",
-            build=lambda: _build_dynamic_round("wfagg", "fused_two_launch"),
-            expected_launches=2, nkd=nkd,
-            passes=(("two-launch indexed fallback",
-                     WFAggConfig(backend="fused_two_launch"),
                      dict(include_gather=True, indexed=True), 2),),
         ),
         EntryPoint(
@@ -306,22 +296,22 @@ def entry_points() -> Dict[str, EntryPoint]:
             expected_launches=1, nkd=(1, _STACKED_K, _STACKED_D),
             passes=(("fused single-node aggregation (stats + combine)",
                      WFAggConfig(), {}, 2),
-                    ("fused single-node Alt-WFAgg (one extra Gram pass)",
-                     alt_wfagg_config(), {}, 3)),
+                    ("fused single-node Alt-WFAgg (Gram folded into the "
+                     "stats phase)", alt_wfagg_config(), {}, 2)),
         ),
         EntryPoint(
             name="sharded_one_launch_round",
             description="D-sharded gossip round under shard_map over the "
-                        "(1, 8) mesh: per-shard stats launch + O(N*K) "
-                        "psum + shard-local combine launch "
+                        "(1, 8) mesh: per-shard statistics-phase launch + "
+                        "O(N*K) psum + shard-local combine-phase launch "
                         "(distributed/spmd.py; needs 8 devices)",
             build=_build_sharded_round,
             expected_launches=2, nkd=(_N, _DEGREE, MLP_D_PAD),
             contract=collectives.wfagg_round_contract(
                 n=_N, k=_DEGREE, n_shards=_SHARDS, rounds=1),
             min_devices=_SHARDS,
-            passes=(("sharded round = two-launch shape per shard",
-                     WFAggConfig(backend="fused_two_launch"),
+            passes=(("sharded round = the two phases as two launches "
+                     "per shard", WFAggConfig(),
                      dict(include_gather=True, indexed=True), 2),),
         ),
         EntryPoint(

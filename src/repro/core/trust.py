@@ -3,8 +3,9 @@
 This is the scoring stage of WFAgg (Alg. 1 lines 9-22 + the valid-aware
 filter masks) factored out of ``core.wfagg``.  It runs in two places:
 
-  * on the host, between the stats and combine kernel launches of the
-    two-launch fused path and in the valid-aware reference
+  * on the host, between the round kernel's statistics and combine
+    phases run as two launches (the d-sharded round,
+    ``distributed.spmd``) and in the valid-aware reference
     (``core.wfagg._indexed_scoring``), vmapped over the N receiving
     nodes, through the sort-based ``fused_*_mask_valid`` selections;
   * INSIDE the single-launch round kernel
@@ -387,8 +388,9 @@ def derive_trust_weights(
 def combine_coefficients(weights: Array, alpha: float, valid: Array,
                          mean_fallback: bool) -> Tuple[Array, Array]:
     """Normalize trust weights into the WFAgg-E combine coefficients:
-    returns ``(alpha_eff * w_norm (K,), 1 - alpha_eff ())``, matching the
-    host-side preparation of the two-launch combine kernel bit-for-bit.
+    returns ``(alpha_eff * w_norm (K,), 1 - alpha_eff ())`` — what the
+    round kernel derives at its phase boundary, and what a caller hands
+    its combine phase when that phase runs alone.
 
     ``mean_fallback=True`` is the mode-B (robust all-reduce) convention:
     when every candidate is rejected the combine degrades to the uniform

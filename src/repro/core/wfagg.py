@@ -20,19 +20,16 @@ Execution backends (``WFAggConfig.backend``):
              candidate matrix again (~7 full passes per aggregation).
              With a ``valid`` mask it runs the valid-aware dynamic-count
              variant (the oracle for irregular/dynamic topologies).
-  fused      the Pallas path.  On the gather-free indexed batch entry
-             this is the SINGLE-LAUNCH round kernel: one pallas_call
-             streams the neighbor blocks, accumulates every filter
-             statistic, derives the WFAgg-E trust weights at an
+  fused      the WFAgg round kernel (``kernels.robust_stats``).  On the
+             gather-free indexed batch entry it is ONE launch of both
+             phases: it streams the neighbor blocks, accumulates every
+             filter statistic, derives the WFAgg-E trust weights at an
              in-kernel phase boundary (``core.trust``), and writes the
              trust-weighted combine — 2 candidate passes per round (the
-             combine phase gathers the neighbor rows again).  On
-             single-node / gathered entries it is the stats-kernel +
-             host-scoring + combine pipeline (2 passes).
-  fused_two_launch
-             forces the two-launch shape on the indexed entry as well
-             (stats launch, host scoring, combine launch) — the parity
-             fallback for validating the single-launch kernel.
+             combine phase gathers the neighbor rows again).  The
+             single-node and gathered entries run its statistics phase
+             alone over an identity slate, score on the host and combine
+             in jnp (2 passes).
 """
 from __future__ import annotations
 
@@ -45,24 +42,14 @@ import jax.numpy as jnp
 
 from repro.core import aggregators as agg
 from repro.core import trust
-from repro.kernels.pairwise_dist.ops import pairwise_gram
 from repro.kernels.robust_stats.ops import (
-    robust_stats, robust_stats_batch, robust_stats_indexed,
-    wfagg_round_indexed)
+    wfagg_round_indexed, wfagg_round_indexed_stats)
 from repro.kernels.robust_stats.ref import RobustStats, robust_stats_indexed_ref
-from repro.kernels.weighted_agg.ops import weighted_agg, weighted_agg_indexed
 from repro.obs import profile as obs_profile
 
 Array = jax.Array
 _EPS = 1e-12
-
-# Fused execution backends: "fused" routes the gather-free indexed path
-# through the SINGLE-LAUNCH round kernel (stats + in-kernel weight
-# derivation + combine in one pallas_call); "fused_two_launch" keeps the
-# separate stats and combine launches with the scoring stage on the host
-# — the parity fallback (and the shape every non-indexed fused entry
-# still uses, where no single-launch op exists).
-_FUSED_BACKENDS = ("fused", "fused_two_launch")
+BACKENDS = ("fused", "reference")
 
 
 def f32_dots(fn):
@@ -96,11 +83,10 @@ class WFAggConfig:
     distance_filter: str = "wfagg_d"     # or "multi_krum"
     similarity_filter: str = "wfagg_c"   # or "clustering"
     multi_krum_m: Optional[int] = None   # Multi-Krum m (default K//4)
-    # Execution backend: "fused" (Pallas filter bank; the gather-free
-    # indexed batch runs the SINGLE-LAUNCH round kernel),
-    # "fused_two_launch" (separate stats + combine launches — parity
-    # fallback), or "reference" (plain-jnp multi-pass pipeline).  Same
-    # masks/aggregate up to float tolerance; see memory_passes().
+    # Execution backend: "fused" (the WFAgg round kernel; the gather-free
+    # indexed batch runs both its phases in ONE launch) or "reference"
+    # (plain-jnp multi-pass pipeline).  Same masks/aggregate up to float
+    # tolerance; see memory_passes().
     backend: str = "fused"
     # Non-finite payload sanitizer (chaos transport, dfl/faults.py): a
     # NaN/Inf candidate row is zeroed and its edges demoted to invalid
@@ -109,6 +95,10 @@ class WFAggConfig:
     # otherwise leak through even a zero combine weight).  A no-op on
     # finite inputs (bit-exact), so it defaults on.
     sanitize: bool = True
+
+    def __post_init__(self):
+        if self.backend not in BACKENDS:
+            raise ValueError(f"unknown backend {self.backend!r}")
 
     @property
     def accept_threshold(self) -> float:
@@ -212,10 +202,11 @@ def wfagg_t_select(state: TemporalState, updates: Array, cfg: WFAggConfig) -> Tu
     """
     prev = state.prev
     # Both backends share this elementwise metric pass: standalone WFAgg-T
-    # is already single-pass in jnp, so launching the robust_stats kernel
-    # here would pay the sorting network for outputs nobody reads.  The
-    # fused gain for the temporal metrics comes from the FULL wfagg
-    # pipeline, where _wfagg_fused folds them into the shared kernel pass.
+    # is already single-pass in jnp, so launching the round kernel's
+    # statistics phase here would pay the sorting network for outputs
+    # nobody reads.  The fused gain for the temporal metrics comes from
+    # the FULL wfagg pipeline, where _wfagg_fused folds them into the
+    # shared kernel pass.
     s_t = jnp.sum((updates - prev) ** 2, axis=-1)
     num = jnp.sum(updates * prev, axis=-1)
     den = jnp.maximum(
@@ -272,7 +263,7 @@ def _similarity_mask(updates: Array, cfg: WFAggConfig) -> Array:
 
 
 # ---------------------------------------------------------------------------
-# fused backend: one-pass filter bank on the robust_stats Pallas kernel
+# fused backend: one-pass filter bank on the round kernel's phase 0
 # ---------------------------------------------------------------------------
 # The mask derivations live in ``core.trust`` — pure O(K)/O(K^2) logic on
 # the kernel's sufficient statistics, shared verbatim with the in-kernel
@@ -288,24 +279,46 @@ _fused_similarity_mask_valid = trust.fused_similarity_mask_valid
 _needs_gram = trust.needs_gram
 
 
+def _gathered_stats(updates: Array, prev: Optional[Array],
+                    need_gram: bool) -> RobustStats:
+    """Phase 0 of the round kernel over gathered (N, K, d) candidates: an
+    identity slate, ``models`` the N*K candidate rows and ``idx[n, k] =
+    n*K + k``, with a per-edge ``prev`` reshaped the same way.  ONE read
+    of the candidates (and of ``prev``) yields every filter statistic
+    and, with ``need_gram``, the (K, K) Gram; fields carry a leading N
+    axis."""
+    N, K, d = updates.shape
+    idx = jnp.arange(N * K, dtype=jnp.int32).reshape(N, K)
+    rows = lambda x: x.reshape(N * K, d)  # noqa: E731
+    return wfagg_round_indexed_stats(
+        rows(updates), idx, rows(prev) if prev is not None else None,
+        need_gram=need_gram)
+
+
+def _single_stats(updates: Array, prev: Optional[Array] = None,
+                  need_gram: bool = False) -> RobustStats:
+    """``_gathered_stats`` of one node's (K, d) candidates, fields
+    without the node axis."""
+    stats = _gathered_stats(updates[None],
+                            prev[None] if prev is not None else None,
+                            need_gram)
+    return jax.tree.map(lambda x: x[0], stats)
+
+
 def _wfagg_fused(
     local: Array,
     updates: Array,
     state: Optional[TemporalState],
     cfg: WFAggConfig,
 ) -> Tuple[Array, Optional[TemporalState], dict]:
-    """Single-node fused WFAgg: every filter statistic from ONE read of the
-    candidates (robust_stats kernel; + the pairwise Gram kernel when the
-    Alt-WFAgg filters need the (K, K) distances), one more read for the
-    fused WFAgg-E combine."""
+    """Single-node fused WFAgg: every filter statistic (and the Alt-WFAgg
+    Gram) from ONE read of the candidates (the round kernel's phase 0),
+    the masks on the host, one more read for the jnp WFAgg-E combine."""
     temporal = cfg.use_temporal and state is not None
     prev = state.prev if temporal else None
-    # need_center=False: the filter bank consumes only the O(K)
-    # accumulators, so the kernel skips its (D,)-sized median/trim writes
-    stats = robust_stats(updates, prev=prev, need_center=False)
-    gram = pairwise_gram(updates)[0] if _needs_gram(cfg) else None
-    mask_d = _fused_distance_mask(stats, gram, cfg)
-    mask_c = _fused_similarity_mask(stats, gram, cfg)
+    stats = _single_stats(updates, prev, _needs_gram(cfg))
+    mask_d = _fused_distance_mask(stats, stats.gram, cfg)
+    mask_c = _fused_similarity_mask(stats, stats.gram, cfg)
     if temporal:
         mask_t, hist_s, hist_b, count, t = wfagg_t_decide(
             state.hist_s, state.hist_b, state.count, state.t,
@@ -316,7 +329,7 @@ def _wfagg_fused(
         mask_t = jnp.zeros((updates.shape[0],), dtype=bool)
         new_state = state
     weights = wfagg_scores(mask_d, mask_c, mask_t, cfg)
-    out = weighted_agg(local, updates, weights, alpha=cfg.alpha)
+    out = wfagg_e(local, updates, weights, cfg.alpha)
     info = {
         "mask_d": mask_d,
         "mask_c": mask_c,
@@ -334,12 +347,8 @@ def wfagg(
     cfg: WFAggConfig,
 ) -> Tuple[Array, Optional[TemporalState], dict]:
     """Full WFAgg (Alg. 1).  Returns (aggregated, new_state, info)."""
-    if cfg.backend in _FUSED_BACKENDS:
-        # single-node calls have no single-launch variant — both fused
-        # flavors run the stats-kernel + host-scoring + combine pipeline
+    if cfg.backend == "fused":
         return _wfagg_fused(local, updates, state, cfg)
-    if cfg.backend != "reference":
-        raise ValueError(f"unknown backend {cfg.backend!r}")
     mask_d = _distance_mask(updates, cfg)
     mask_c = _similarity_mask(updates, cfg)
     if cfg.use_temporal and state is not None:
@@ -372,20 +381,19 @@ def wfagg_batch(
     """Batched full WFAgg over all N receiving nodes of a gossip round.
 
     ``local (N, d)``, ``updates (N, K, d)``, ``state`` with a leading N
-    axis on every leaf.  The fused backend runs ONE robust_stats kernel
-    launch with a 2-D (node, D-block) grid — a vmap of single-node Pallas
-    calls would serialize into an outer per-node loop instead — and one
-    batched combine; only the O(K)/O(K^2) mask logic is vmapped.  The
-    reference backend vmaps the plain-jnp pipeline (same semantics,
-    multi-pass traffic).
+    axis on every leaf.  The fused backend runs the round kernel's
+    statistics phase ONCE for all N nodes (``_gathered_stats``) — a vmap
+    of single-node Pallas calls would serialize into an outer per-node
+    loop instead — and one batched jnp combine; only the O(K)/O(K^2)
+    mask logic is vmapped.  The reference backend vmaps the plain-jnp
+    pipeline (same semantics, multi-pass traffic).
 
     Gather-free path: with ``neighbor_idx (N, K)``, ``updates`` is the
     (M, d) MODEL MATRIX instead of a gathered tensor — the fused kernels
     DMA each neighbor's d-blocks straight from it, so the (N, K, d)
     gossip tensor never exists in HBM.  Under the default
-    backend="fused" this is ONE single-launch round kernel (stats,
-    in-kernel trust weights, WFAgg-E combine — 2 candidate passes);
-    backend="fused_two_launch" keeps the stats + combine launch pair.
+    backend="fused" this is ONE launch of the round kernel (stats,
+    in-kernel trust weights, WFAgg-E combine — 2 candidate passes).
     ``valid (N, K)`` marks the real edges of padded irregular topologies
     (None = regular); the temporal ``prev`` state may be per-edge
     (N, K, d) or a previous-round model matrix (M, d) read through the
@@ -410,18 +418,13 @@ def wfagg_batch(
         out, _, info = jax.vmap(lambda l, u: wfagg(l, u, None, cfg))(
             local, updates)
         return out, None, info
-    if cfg.backend not in _FUSED_BACKENDS:
-        raise ValueError(f"unknown backend {cfg.backend!r}")
 
     N, K, _ = updates.shape
     temporal = cfg.use_temporal and state is not None
     prev = state.prev if temporal else None
-    stats = robust_stats_batch(updates, prev=prev, need_center=False)
-    gram = None
-    if _needs_gram(cfg):
-        # one extra read of the candidates: batched Gram via the MXU
-        gram = jnp.einsum("nkd,njd->nkj", updates, updates,
-                          preferred_element_type=jnp.float32)
+    stats = _gathered_stats(updates, prev, _needs_gram(cfg))
+    gram = stats.gram
+    stats = stats._replace(gram=None)  # keep the vmapped mask fns uniform
     if gram is not None:
         mask_d = jax.vmap(lambda s, g: _fused_distance_mask(s, g, cfg))(stats, gram)
         mask_c = jax.vmap(lambda s, g: _fused_similarity_mask(s, g, cfg))(stats, gram)
@@ -460,8 +463,9 @@ def _indexed_scoring(
     models: Array,
     neighbor_idx: Array,
 ) -> Tuple[Array, Array, Array, Array, Optional[TemporalState]]:
-    """Host-side scoring stage shared by the two-launch fused path and
-    the valid-aware reference oracle: vmapped trust masks, the WFAgg-T
+    """Host-side scoring stage shared by the d-sharded fused round
+    (``distributed.spmd``) and the valid-aware reference oracle: vmapped
+    trust masks, the WFAgg-T
     decision + ring-buffer update, and the tau-weighted scores.  Returns
     (mask_d, mask_c, mask_t, weights, new_state)."""
     N, K = valid_b.shape
@@ -538,9 +542,7 @@ def _wfagg_batch_indexed(
     backend="fused" (default): ONE kernel launch per gossip round — the
     round kernel streams neighbor blocks (phase 0), derives the trust
     weights at the in-kernel phase boundary, and writes the WFAgg-E
-    combine (phase 1).  backend="fused_two_launch": the previous shape —
-    a stats launch, the scoring stage on the host, a combine launch —
-    kept as the parity fallback.  backend="reference": pure-jnp oracle;
+    combine (phase 1).  backend="reference": pure-jnp oracle;
     with a ``valid`` mask it runs the valid-aware multi-pass pipeline
     (same dynamic keep counts as the fused paths), without one it keeps
     the bit-parity static-count per-node pipeline.
@@ -581,38 +583,24 @@ def _wfagg_batch_indexed(
             local, gathered)
         return out, None, info
 
-    if cfg.backend == "fused_two_launch":
-        # the Alt-WFAgg (K, K) Gram rides along in the SAME kernel pass,
-        # accumulated from the resident candidate tile — no extra read
-        stats = robust_stats_indexed(
-            models, neighbor_idx, valid_b if cfg.sanitize else valid,
-            prev=prev, need_gram=_needs_gram(cfg), prev_idx=prev_idx)
-        mask_d, mask_c, mask_t, weights, new_state = _indexed_scoring(
-            stats, valid_b, state, cfg, models, neighbor_idx)
-        # gather-free WFAgg-E combine: neighbor rows DMA'd by the same table
-        out = weighted_agg_indexed(local, models, neighbor_idx, weights,
-                                   alpha=cfg.alpha)
-    elif cfg.backend == "fused":
-        # single launch: stats, in-kernel weight derivation AND combine in
-        # one pallas_call.  The WFAgg-T EWMA bands are the only O(K)
-        # precompute (they need the host-resident metric history); the
-        # ring buffers advance afterwards off the kernel's temporal tail.
-        tbands = None
-        if temporal:
-            tbands = jax.vmap(
-                lambda hs, hb, c, tt: trust.temporal_bands(hs, hb, c, tt, cfg)
-            )(state.hist_s, state.hist_b, state.count, state.t)
-        out, weights, mask_d, mask_c, mask_t, stats = wfagg_round_indexed(
-            local, models, neighbor_idx,
-            valid_b if cfg.sanitize else valid, cfg,
-            prev=prev, tbands=tbands, prev_idx=prev_idx)
-        new_state = state
-        if temporal:
-            new_state = _push_temporal_history(
-                state, models if matrix_prev else models[neighbor_idx],
-                stats.prev_dist2, stats.cosine_to_prev())
-    else:
-        raise ValueError(f"unknown backend {cfg.backend!r}")
+    # single launch: stats, in-kernel weight derivation AND combine in
+    # one pallas_call.  The WFAgg-T EWMA bands are the only O(K)
+    # precompute (they need the host-resident metric history); the ring
+    # buffers advance afterwards off the kernel's temporal tail.
+    tbands = None
+    if temporal:
+        tbands = jax.vmap(
+            lambda hs, hb, c, tt: trust.temporal_bands(hs, hb, c, tt, cfg)
+        )(state.hist_s, state.hist_b, state.count, state.t)
+    out, weights, mask_d, mask_c, mask_t, stats = wfagg_round_indexed(
+        local, models, neighbor_idx,
+        valid_b if cfg.sanitize else valid, cfg,
+        prev=prev, tbands=tbands, prev_idx=prev_idx)
+    new_state = state
+    if temporal:
+        new_state = _push_temporal_history(
+            state, models if matrix_prev else models[neighbor_idx],
+            stats.prev_dist2, stats.cosine_to_prev())
 
     info = {
         "mask_d": mask_d,
@@ -704,32 +692,27 @@ def memory_passes(cfg: WFAggConfig, include_gather: bool = False,
     (median sort + distances = 2, or 1 Gram pass for Multi-Krum),
     similarity filter (median + norms/clip + cosine dots = 3, or 1 Gram
     pass for Clustering), temporal metrics (1), WFAgg-E combine (1).
-    fused: ONE robust_stats read covers D/C/T statistics, plus the
-    combine (+ 1 Gram pass only when an Alt-WFAgg filter needs K x K
-    distances).  See kernels/README.md for the accounting.
+    fused: ONE read by the round kernel's statistics phase covers the
+    D/C/T statistics and the Alt-WFAgg (K, K) Gram (accumulated off the
+    resident tile), plus the combine: 2.  On the indexed path the
+    combine is the same launch's phase 1, which gathers each (K, T) tile
+    from HBM again (the tiles of a node's (K, d) slab do not stay
+    resident from phase 0) — the single launch saves the host round-trip
+    and a second launch, not a pass; the d-sharded round runs the two
+    phases as two launches per shard, 2 passes over d/S each.  See
+    kernels/README.md for the accounting.
 
     ``include_gather`` also counts the gossip-exchange materialization a
     DFL round pays BEFORE aggregating: building the (N, K, d) gathered
     tensor costs one more candidate-sized pass (write ~= read) — unless
     ``indexed`` (the gather-free neighbor-indexed path), which DMAs
     neighbor blocks straight from the (N, d) model matrix and never
-    materializes the tensor.  The indexed path also folds the Alt-WFAgg
-    (K, K) Gram into the stats pass (accumulated off the resident tile),
-    dropping the separate Gram pass as well.
-
-    On the indexed path, backend="fused" is the SINGLE-LAUNCH round
-    kernel: stats, in-kernel weight derivation and combine in one
-    pallas_call — 2 candidate passes, like the separate stats + combine
-    launches of backend="fused_two_launch": the phase-1 combine gathers
-    each (K, T) tile from HBM again (the tiles of a node's (K, d) slab
-    do not stay resident from phase 0).  The single launch saves the
-    host round-trip and the second launch, not a pass.
+    materializes the tensor.
     """
     t = 1 if cfg.use_temporal else 0
     gather = 1 if (include_gather and not indexed) else 0
-    if cfg.backend in _FUSED_BACKENDS:
-        gram = 1 if (_needs_gram(cfg) and not indexed) else 0
-        return 2 + gram + gather
+    if cfg.backend == "fused":
+        return 2 + gather
     d_passes = 1 if cfg.distance_filter == "multi_krum" else 2
     c_passes = 1 if cfg.similarity_filter == "clustering" else 3
     return d_passes + c_passes + t + 1 + gather
@@ -744,7 +727,7 @@ def alt_wfagg_config(**kw) -> WFAggConfig:
 def wfagg_d_agg(updates: Array, f: int = 2,
                 backend: str = "reference") -> Tuple[Array, Array]:
     if backend == "fused":
-        stats = robust_stats(updates, need_center=False)
+        stats = _single_stats(updates)
         mask = agg.smallest_k_mask(stats.dist2, updates.shape[0] - int(f) - 1)
     else:
         mask = wfagg_d_select(updates, f)
@@ -754,7 +737,7 @@ def wfagg_d_agg(updates: Array, f: int = 2,
 def wfagg_c_agg(updates: Array, f: int = 2,
                 backend: str = "reference") -> Tuple[Array, Array]:
     if backend == "fused":
-        stats = robust_stats(updates, need_center=False)
+        stats = _single_stats(updates)
         mask = agg.smallest_k_mask(stats.cosine_to_median(),
                                    updates.shape[0] - int(f) - 1)
     else:

@@ -62,10 +62,9 @@ class DFLConfig:
     # WFAgg execution backend: "fused" runs the whole gossip round —
     # stats, in-kernel trust-weight derivation AND the WFAgg-E combine —
     # through ONE single-launch Pallas kernel (see core.wfagg.wfagg_batch
-    # / kernels.robust_stats.ops.wfagg_round_indexed);
-    # "fused_two_launch" keeps the separate stats + combine launches
-    # (parity fallback); "reference" is the multi-pass jnp oracle (valid-
-    # aware, so irregular and dynamic topologies run under it too).
+    # / kernels.robust_stats.ops.wfagg_round_indexed); "reference" is
+    # the multi-pass jnp oracle (valid-aware, so irregular and dynamic
+    # topologies run under it too).
     wfagg_backend: str = "fused"
     # > 1 shards the model dimension of the WFAgg gossip round over that
     # many devices of a (1, S) ('data', 'model') mesh via shard_map

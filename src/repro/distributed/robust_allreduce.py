@@ -70,10 +70,9 @@ from repro.core.wfagg import (
     wfagg_t_select)
 from repro.distributed.logical import current_mesh
 from repro.distributed.spmd import SHARD_AXIS, psum_stats
-from repro.kernels.pairwise_dist.ops import pairwise_gram
 from repro.kernels.robust_stats.kernel import round_padded_width
 from repro.kernels.robust_stats.ops import (
-    robust_stats, wfagg_round_indexed, wfagg_round_indexed_stats)
+    wfagg_round_indexed, wfagg_round_indexed_stats)
 from repro.obs import decision as obs_decision
 
 Array = jax.Array
@@ -102,21 +101,19 @@ class RobustAggConfig:
     #          gradient, candidate-sharded) instead of
     #          count-sketch-approximate.
     layout: str = "flat"
-    gather_dtype: Optional[str] = None   # e.g. "bfloat16": gather candidates
-                                         # in low precision (stats stay f32)
     # statistics backend for layout='stacked': "fused" runs the whole
     # wfagg/alt_wfagg aggregation — statistics, in-kernel trust-weight
-    # derivation AND the weighted combine — through ONE single-launch
-    # Pallas kernel over the concatenated (K, P) candidates (falls back
-    # to the two-launch shape when gather_dtype quantization is on: the
-    # temporal metrics must stay full-precision while the D/C stats
-    # quantize, which one read cannot provide); "fused_two_launch"
-    # forces the separate stats launch + host scoring + jnp combine;
-    # "reference" keeps the per-leaf jnp loop.  On a mesh of several
-    # candidate devices "fused" runs the round sliced over the
-    # parameters (module docstring); the two-launch shapes run whole on
-    # every device.
+    # derivation AND the weighted combine — through ONE launch of the
+    # WFAgg round kernel over the concatenated (K, P) candidates, or, on
+    # a mesh of several candidate devices, sliced over the parameters
+    # (module docstring); Krum/Multi-Krum/Clustering take their
+    # statistics and Gram from the kernel's statistics phase, whole on
+    # every device.  "reference" keeps the per-leaf jnp loop.
     backend: str = "reference"
+
+    def __post_init__(self):
+        if self.backend not in ("fused", "reference"):
+            raise ValueError(f"unknown backend {self.backend!r}")
 
     @property
     def needs_stats(self) -> bool:
@@ -371,7 +368,7 @@ def init_tree_agg_state(cfg: RobustAggConfig, n_candidates: int, grads_like: Any
     )
 
 
-def _stacked_stats(stacked: Any, cfg: RobustAggConfig) -> ChunkStats:
+def _stacked_stats(stacked: Any) -> ChunkStats:
     """WFAgg/Krum/Clustering statistics over stacked candidates.
 
     ``stacked`` leaves are (K, *param_shape), candidate axis sharded over
@@ -383,14 +380,13 @@ def _stacked_stats(stacked: Any, cfg: RobustAggConfig) -> ChunkStats:
     """
     leaves = jax.tree.leaves(stacked)
     K = leaves[0].shape[0]
-    gd = jnp.dtype(cfg.gather_dtype) if cfg.gather_dtype else None
 
     dist2 = jnp.zeros((K,), jnp.float32)
     dot_med = jnp.zeros((K,), jnp.float32)
     med2 = jnp.zeros((), jnp.float32)
     gram = jnp.zeros((K, K), jnp.float32)
     for leaf in leaves:
-        g = (leaf.astype(gd) if gd is not None else leaf).astype(jnp.float32)
+        g = leaf.astype(jnp.float32)
         rest = tuple(range(1, g.ndim))
         med = jnp.median(g, axis=0)
         diff = g - med[None]
@@ -402,14 +398,11 @@ def _stacked_stats(stacked: Any, cfg: RobustAggConfig) -> ChunkStats:
                       sketch=jnp.zeros((0,), jnp.float32))
 
 
-def _concat_candidates(tree: Any, dtype=None) -> Array:
+def _concat_candidates(tree: Any) -> Array:
     """Flatten a stacked candidate pytree to one (K, P) matrix (fused path)."""
     leaves = jax.tree.leaves(tree)
     K = leaves[0].shape[0]
-    parts = [
-        (l.astype(dtype) if dtype is not None else l).astype(jnp.float32).reshape(K, -1)
-        for l in leaves
-    ]
+    parts = [l.astype(jnp.float32).reshape(K, -1) for l in leaves]
     return jnp.concatenate(parts, axis=1)
 
 
@@ -442,42 +435,20 @@ def _effective_wfagg_config(cfg: RobustAggConfig, K: int) -> WFAggConfig:
     return w
 
 
-def _stacked_stats_fused(
-    stacked: Any, cfg: RobustAggConfig, prev: Optional[Any] = None,
-):
-    """One-pass statistics for the stacked layout via the robust_stats
-    Pallas kernel: a single read of the concatenated (K, P) candidates
-    yields the WFAgg-D/C metrics AND (with ``prev``) the exact WFAgg-T
-    round-over-round metrics; the (K, K) Gram comes from the blocked
-    pairwise kernel only when a Krum/Clustering-family rule needs it.
-
-    Returns (ChunkStats, RobustStats) — the latter carries the temporal
-    tail the caller feeds to wfagg_t_decide.
-    """
-    gd = jnp.dtype(cfg.gather_dtype) if cfg.gather_dtype else None
-    flat = _concat_candidates(stacked, gd)
-    pflat = _concat_candidates(prev) if prev is not None else None
-    stats = _whole_on_each_device(
-        lambda f, p: robust_stats(f, prev=p, need_center=False))(flat, pflat)
-    w = cfg.wfagg
-    needs_gram = (
-        cfg.method in ("krum", "multi_krum", "clustering", "alt_wfagg")
-        or w.distance_filter == "multi_krum"
-        or w.similarity_filter == "clustering"
-    )
-    if needs_gram:
-        gram, _ = _whole_on_each_device(pairwise_gram)(flat)
-    else:
-        # _weights_from_stats only reads the diagonal (norm2) in this case
-        gram = jnp.diag(stats.norm2)
-    chunk = ChunkStats(
-        dist2_med=stats.dist2,
-        dot_med=stats.dotmed,
-        med2=stats.mednorm2,
-        gram=gram,
-        sketch=jnp.zeros((0,), jnp.float32),
-    )
-    return chunk, stats
+def _stacked_stats_fused(stacked: Any) -> ChunkStats:
+    """One-pass statistics for the Krum/Multi-Krum/Clustering rules of
+    the stacked layout: the round kernel's statistics phase over the
+    concatenated (K, P) candidates (an N = 1 identity slate), whole on
+    every device — one read yields the median statistics and the (K, K)
+    Gram."""
+    flat = _concat_candidates(stacked)
+    K = flat.shape[0]
+    nidx = jnp.arange(K, dtype=jnp.int32)[None, :]   # identity slate
+    st = _whole_on_each_device(
+        lambda f: wfagg_round_indexed_stats(f, nidx, need_gram=True))(flat)
+    return ChunkStats(dist2_med=st.dist2[0], dot_med=st.dotmed[0],
+                      med2=st.mednorm2[0], gram=st.gram[0],
+                      sketch=jnp.zeros((0,), jnp.float32))
 
 
 def _stacked_temporal_metrics(stacked: Any, prev: Any) -> Tuple[Array, Array]:
@@ -576,7 +547,6 @@ def robust_allreduce_stacked(
         return out, state, {"weights": jnp.ones((K,), jnp.float32),
                             "n_accepted": jnp.asarray(K)}
 
-    fused = cfg.backend in ("fused", "fused_two_launch")
     temporal = (cfg.method in ("wfagg", "alt_wfagg") and cfg.wfagg.use_temporal
                 and state is not None)
     # Round-kernel route (backend="fused"): the whole wfagg/alt_wfagg
@@ -584,10 +554,7 @@ def robust_allreduce_stacked(
     # through the round kernel.  Where the active mesh's candidate axes
     # hold more than one device, each device runs it on its 1/n slice of the
     # parameters (``_stacked_sliced_round``); else it is ONE launch over
-    # the concatenated (K, P) candidates.  gather_dtype forces the
-    # two-launch shape instead: the temporal metrics must stay
-    # full-precision while the D/C statistics quantize, which a single
-    # candidate read cannot provide.
+    # the concatenated (K, P) candidates.
     if _runs_round_kernel(cfg):
         mesh = current_mesh()
         axes, n = _candidate_axes(mesh)
@@ -595,25 +562,17 @@ def robust_allreduce_stacked(
             return _stacked_sliced_round(stacked, cfg, state, temporal,
                                          mesh, axes, n)
         return _stacked_one_launch(stacked, cfg, state, temporal)
-    # The temporal metrics are computed on FULL-precision candidates in
-    # the reference path (gather_dtype only quantizes the D/C/Gram
-    # statistics), so the fused kernel may only fold them into its pass
-    # when no gather_dtype rounding is in effect — otherwise the masks
-    # would diverge between backends.
-    fuse_temporal = fused and temporal and cfg.gather_dtype is None
-    if fused:
-        stats, kstats = _stacked_stats_fused(
-            stacked, cfg, prev=state.prev if fuse_temporal else None)
+    # the rest: the Krum-family rules under "fused" (no temporal state),
+    # every rule under "reference"
+    if cfg.backend == "fused":
+        stats = _stacked_stats_fused(stacked)
     else:
-        stats = _stacked_stats(stacked, cfg)
+        stats = _stacked_stats(stacked)
 
     new_state = state
     temporal_mask = None
     if temporal:
-        if fuse_temporal:
-            s_all, b_all = kstats.prev_dist2, kstats.cosine_to_prev()
-        else:
-            s_all, b_all = _stacked_temporal_metrics(stacked, state.prev)
+        s_all, b_all = _stacked_temporal_metrics(stacked, state.prev)
         temporal_mask, hist_s, hist_b, count, t = wfagg_t_decide(
             state.hist_s, state.hist_b, state.count, state.t,
             s_all, b_all, cfg.wfagg)
@@ -686,8 +645,7 @@ def _stacked_one_launch(
 
 def _runs_round_kernel(cfg: RobustAggConfig) -> bool:
     """The stacked aggregation goes through the WFAgg round kernel."""
-    return (cfg.backend == "fused" and cfg.method in ("wfagg", "alt_wfagg")
-            and cfg.gather_dtype is None)
+    return cfg.backend == "fused" and cfg.method in ("wfagg", "alt_wfagg")
 
 
 def _candidate_axes(mesh) -> Tuple[Tuple[str, ...], int]:

@@ -4,26 +4,29 @@ contract the SPMD linter rules enforce).
 The one-launch round kernel derives its trust weights at an in-kernel
 phase boundary from GLOBAL filter statistics, so it cannot survive
 model-dim sharding as a single launch: a shard only sees its d/S
-coordinate slice.  What DOES survive — exactly — is the two-launch
-decomposition, because every statistic the scoring stage consumes is a
-coordinate-additive accumulator (``RobustStats``: dist2 / dotmed / norm2
-/ mednorm2 / prev_* / gram are all sums over coordinates, and the
-coordinate-wise median is computed per coordinate, i.e. shard-locally):
+coordinate slice.  What DOES survive — exactly — is its two phases run
+as two launches with the scoring between them, because every statistic
+the scoring stage consumes is a coordinate-additive accumulator
+(``RobustStats``: dist2 / dotmed / norm2 / mednorm2 / prev_* / gram are
+all sums over coordinates, and the coordinate-wise median is computed
+per coordinate, i.e. shard-locally):
 
-  phase 0 (shard-local)  ``robust_stats_indexed`` on the (M, d/S) model
-                         shard — one Pallas launch per shard, no comm;
+  phase 0 (shard-local)  ``wfagg_round_indexed_stats`` on the (M, d/S)
+                         model shard — one Pallas launch per shard, no
+                         comm;
   psum                   ONE all-reduce of the O(N·K) statistic partials
                          across the 'model' axis reconstructs the full-d
                          statistics bit-for-bit up to float summation
                          order — this is the ONLY cross-shard collective
                          the contract allows;
-  scoring (replicated)   ``core.wfagg._indexed_scoring`` — the same
-                         host-side trust stage the two-launch backend
-                         uses, now computed redundantly on every shard
-                         (O(N·K) work, no comm);
-  phase 1 (shard-local)  ``weighted_agg_indexed`` combines each node's
-                         d/S slice with its neighbors' — the WFAgg-E
-                         combine never crosses shards.
+  scoring (replicated)   ``core.wfagg._indexed_scoring`` — the host-side
+                         trust stage of the valid-aware reference,
+                         computed redundantly on every shard (O(N·K)
+                         work, no comm), then each node's combine
+                         coefficients (``core.trust.combine_coefficients``);
+  phase 1 (shard-local)  ``wfagg_round_indexed_combine`` combines each
+                         node's d/S slice with its neighbors' — the
+                         WFAgg-E combine never crosses shards.
 
 Per-device wire traffic per round is therefore O(N·K) — independent of
 d — versus the O(N·d) a naive GSPMD gather would pay.  Zero-padding d
@@ -46,11 +49,11 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
+from repro.core import trust
 from repro.core import wfagg as wf
-from repro.core.trust import needs_gram
-from repro.kernels.robust_stats.ops import robust_stats_indexed
+from repro.kernels.robust_stats.ops import (
+    wfagg_round_indexed_combine, wfagg_round_indexed_stats)
 from repro.kernels.robust_stats.ref import RobustStats
-from repro.kernels.weighted_agg.ops import weighted_agg_indexed
 
 Array = jax.Array
 
@@ -139,15 +142,17 @@ def _shard_round_body(cfg: wf.WFAggConfig, axis: str):
 
     def body(local, models, state, neighbor_idx, valid_b):
         temporal = cfg.use_temporal and state is not None
-        stats = robust_stats_indexed(
-            models, neighbor_idx, valid_b,
-            prev=state.prev if temporal else None,
-            need_gram=needs_gram(cfg))
+        stats = wfagg_round_indexed_stats(
+            models, neighbor_idx, state.prev if temporal else None,
+            valid=valid_b, need_gram=trust.needs_gram(cfg))
         stats = psum_stats(stats, axis)
         mask_d, mask_c, mask_t, weights, new_state = wf._indexed_scoring(
             stats, valid_b, state, cfg, models, neighbor_idx)
-        out = weighted_agg_indexed(local, models, neighbor_idx, weights,
-                                   alpha=cfg.alpha)
+        wcomb, lcoef = jax.vmap(
+            lambda w, v: trust.combine_coefficients(w, cfg.alpha, v, False)
+        )(weights, valid_b)
+        out = wfagg_round_indexed_combine(local, models, neighbor_idx,
+                                          wcomb, lcoef)
         return out, new_state, (mask_d, mask_c, mask_t, weights)
 
     return body
@@ -175,9 +180,9 @@ def wfagg_batch_sharded(
     """Drop-in for ``wfagg_batch(..., neighbor_idx=...)`` with the model
     dimension sharded over ``mesh``'s 'model' axis.
 
-    Semantics match ``backend='fused_two_launch'`` (same scoring stage on
-    the psum-reconstructed statistics, same combine) up to float
-    summation order.  d is zero-padded to a shard multiple internally
+    Semantics match ``backend='reference'`` with a ``valid`` mask (the
+    same scoring stage, on the psum-reconstructed statistics) up to
+    float summation order.  d is zero-padded to a shard multiple internally
     and the pad sliced back off, so callers with replicated inputs (the
     DFL engine) can use any d; the lint entry points pre-pad and keep
     everything sharded instead."""

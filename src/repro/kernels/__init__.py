@@ -1,24 +1,25 @@
-"""Pallas TPU kernels for the WFAgg aggregation hot-spots.
+"""Pallas TPU kernels.
 
 The paper's complexity analysis (Sections IV-C/D) identifies the
 coordinate-wise median over K candidates as the dominant O(dK log K)
 cost of every filter.  At production scale (d = 1e9..1e11) the candidate
-tensor must stream HBM->VMEM exactly once, so we fuse the order
-statistics with every other per-candidate statistic the filters need:
+tensor must stream HBM->VMEM as few times as possible, so ONE kernel
+runs the WFAgg round: its phase 0 fuses the order statistics with every
+other per-candidate statistic the filters need (and the Alt-WFAgg Gram),
+its phase 1 is the WFAgg-E combine (Eq. 3).
 
-  robust_stats   fused median + trimmed-mean + WFAgg-D/C statistics
-  pairwise_dist  blocked K x K sq-distance Gram (Krum / Multi-Krum)
-  weighted_agg   fused WFAgg-E trust-weighted combine (Eq. 3)
+  robust_stats  the WFAgg round kernel (kernel.py), its jitted wrappers
+                for both phases or either alone (ops.py) and its
+                pure-jnp oracles (ref.py)
+  flash_attn    attention for the mode-B trainer (unrelated to
+                aggregation)
 
-Each package ships kernel.py (pl.pallas_call + BlockSpec), ops.py (jitted
-wrapper) and ref.py (pure-jnp oracle); validated with interpret=True.
+Validated against the oracles with interpret=True off a TPU.
 """
 from repro.kernels.common import default_interpret, resolve_interpret
 from repro.kernels.robust_stats.ops import (
-    robust_stats, robust_stats_batch, wfagg_round_indexed)
-from repro.kernels.robust_stats.ref import RobustStats, robust_stats_ref
-from repro.kernels.pairwise_dist.ops import pairwise_gram
-from repro.kernels.pairwise_dist.ops import pairwise_sq_dists as pairwise_sq_dists_kernel
-from repro.kernels.pairwise_dist.ref import pairwise_dist_ref
-from repro.kernels.weighted_agg.ops import weighted_agg
-from repro.kernels.weighted_agg.ref import weighted_agg_ref
+    wfagg_round_indexed, wfagg_round_indexed_combine,
+    wfagg_round_indexed_stats)
+from repro.kernels.robust_stats.ref import (
+    RobustStats, pairwise_dist_ref, robust_stats_indexed_ref,
+    robust_stats_ref, weighted_agg_ref)

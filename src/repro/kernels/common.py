@@ -1,13 +1,8 @@
 """Shared kernel-launch policy.
 
-Every Pallas wrapper used to hardcode ``interpret=True`` (correct on CPU,
-but it silently ran the interpreter on real TPUs too).  The single policy
-lives here: compile for real when the default backend is a TPU, interpret
-everywhere else, and let callers still force either mode explicitly.
-
-The D-axis padding and block-size policy is also single-sourced here:
-every ops wrapper used to carry its own ``_pad_d`` / ``(-D) % block_d``
-copy, which is exactly the kind of plumbing that drifts apart.
+Compile for real when the default backend is a TPU, interpret
+everywhere else, and let callers still force either mode explicitly;
+and the zero padding of the D axis up to a tile multiple.
 """
 from __future__ import annotations
 
@@ -27,34 +22,11 @@ def resolve_interpret(interpret: Optional[bool]) -> bool:
     return default_interpret() if interpret is None else bool(interpret)
 
 
-def auto_block_d(D: int, interpret: bool, interpret_blocks: int = 2) -> int:
-    """Pick a D block size: ~``interpret_blocks`` large blocks in interpret
-    mode (the interpreter carries whole output buffers through its grid
-    scan, so many small steps thrash — kernels whose grid revisits a
-    d-sized output on EVERY step, like the single-launch round kernel,
-    pass ``interpret_blocks=1``), 1024-lane tiles for compiled TPU."""
-    if not interpret:
-        return 1024
-    part = -(-D // max(1, interpret_blocks))
-    return max(128, -(-part // 128) * 128)
-
-
 def pad_d(x: jax.Array, block_d: int) -> jax.Array:
     """Zero-pad the trailing (D) axis up to a multiple of ``block_d`` and
-    promote to f32.  Zero padding is exact for every kernel in this
-    package: a zero column has median 0 and contributes nothing to any
-    accumulated statistic, distance, dot product, or weighted combine."""
+    promote to f32.  Zero padding is exact for the round kernel: a zero
+    column has median 0 and contributes nothing to any accumulated
+    statistic, distance, dot product, or weighted combine."""
     pad = (-x.shape[-1]) % block_d
     cfgpad = [(0, 0)] * (x.ndim - 1) + [(0, pad)]
     return jnp.pad(x.astype(jnp.float32), cfgpad)
-
-
-def resolve_block_d(D: int, block_d: Optional[int],
-                    interpret: Optional[bool],
-                    interpret_blocks: int = 2) -> tuple[int, bool]:
-    """Resolve the (block_d, interpret) pair most wrappers need: None
-    means 'pick per backend' for both."""
-    itp = resolve_interpret(interpret)
-    if block_d is None:
-        block_d = auto_block_d(D, itp, interpret_blocks)
-    return block_d, itp

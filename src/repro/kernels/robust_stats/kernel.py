@@ -1,28 +1,27 @@
-"""Pallas TPU kernel: fused robust statistics over K candidates.
+"""Pallas TPU kernel: the WFAgg round over K neighbor candidates.
 
-Tiling: the candidate matrix (K, D) streams HBM->VMEM in (K, T) blocks
-(T a multiple of 128 lanes; K <= 32 candidates sit on the sublane axis).
-Inside a block we run an odd-even-transposition sorting network over the
-K axis — K is static and small, so the network fully unrolls into ~K^2/2
-vectorized min/max pairs on (T,)-shaped vregs, which the VPU executes at
-full lane width.  The median/trimmed-mean reductions and all per-candidate
-partial statistics (distance-to-median, dot-with-median, norms) come out
-of the same VMEM-resident block, so the whole WFAgg filter bank costs ONE
-HBM read of the candidates.
+One kernel, ``wfagg_round_indexed_pallas``, over a 3-D (node, phase,
+D tile) grid.  Each grid step gathers one node's K neighbor rows of a
+(K, T) tile straight from the (M, D) model matrix with row DMAs, so the
+(N, K, D) gossip tensor never exists in HBM.
 
-Temporal extension: when the previous-round candidates ``prev (K, D)``
-are supplied, the same VMEM-resident block also accumulates the WFAgg-T
-metrics — s_t = ||u - prev||^2 plus the dot/norm terms of b_t — so the
-full WFAgg-D/C/T filter bank still costs one read of the candidates (plus
-the unavoidable one read of ``prev``).
+  phase 0  the statistics: an odd-even-transposition sorting network
+           over the K rows (K is static and small, so it unrolls into
+           ~K^2/2 min/max pairs on lane-wide rows) gives the valid-masked
+           coordinate-wise median, and the same resident tile yields
+           every per-candidate accumulator WFAgg-D/C/T read —
+           distance-to-median, dot-with-median, norms, the round-over-
+           round metrics against ``prev`` and, for Alt-WFAgg, the (K, K)
+           Gram.  One read of the candidates (and one of ``prev``).
+  phase 1  the WFAgg-E combine ``lcoef * local + sum_k w_k u_k``, which
+           gathers the tiles again.
 
-Grids:
-  single  1-D over D/T blocks, candidates (K, D)
-  batched 2-D over (node, D/T block), candidates (N, K, D) — all N
-          per-node gossip aggregations in ONE kernel launch.  The D axis
-          is the innermost grid dimension, so each node's revisited (K,)
-          accumulator blocks are initialized at its first D block and
-          complete before the grid moves to the next node.
+``phases`` picks what one launch runs: both (``ROUND``: the trust
+weights are derived in-kernel at the phase boundary — the whole gossip
+round in one launch), phase 0 alone (``STATS``: for a caller that sums
+the accumulators over slices of D before it scores them, or that scores
+on the host) or phase 1 alone (``COMBINE``: the combine coefficients
+come in as an input).
 """
 from __future__ import annotations
 
@@ -82,131 +81,11 @@ def _row_sums(x: Array) -> Array:
     return trust.col_to_row(jnp.sum(x, axis=1, keepdims=True))
 
 
-def _robust_stats_kernel(*refs, n_trim: int, has_prev: bool,
-                         emit_center: bool, d_axis: int):
-    """Shared kernel body for the single (d_axis=0) and batched (d_axis=1)
-    launches.  Block shapes may carry a leading node axis of size 1; every
-    read/write goes through a reshape so one body serves both layouts.
-    ``emit_center=False`` drops the streaming (1, D) median/trimmed-mean
-    outputs — the WFAgg filter bank only consumes the O(K) accumulators,
-    so skipping those writes keeps the fused path at one read + no
-    d-sized writes."""
-    if has_prev:
-        u_ref, prev_ref = refs[0], refs[1]
-        outs = refs[2:]
-    else:
-        u_ref, prev_ref = refs[0], None
-        outs = refs[1:]
-    if emit_center:
-        med_ref, trim_ref = outs[:2]
-        acc_refs = outs[2:]
-    else:
-        med_ref = trim_ref = None
-        acc_refs = outs
-    dist2_ref, dotmed_ref, norm2_ref, mednorm2_ref = acc_refs[:4]
-
-    u = u_ref[...].astype(jnp.float32)
-    u = u.reshape(u.shape[-2], u.shape[-1])            # (K, T)
-    K = u.shape[0]
-
-    srt = sort_rows(u)
-    if K % 2 == 1:
-        med = srt[K // 2]
-    else:
-        med = 0.5 * (srt[K // 2 - 1] + srt[K // 2])
-    if emit_center:
-        kept = srt[n_trim:K - n_trim] if n_trim > 0 else srt
-        trim = sum(kept) / len(kept)
-        med_ref[...] = med.reshape(med_ref.shape).astype(med_ref.dtype)
-        trim_ref[...] = trim.reshape(trim_ref.shape).astype(trim_ref.dtype)
-
-    diff = u - med
-    p_dist2 = _row_sums(diff * diff)                 # (1, K)
-    p_dot = _row_sums(u * med)
-    p_norm2 = _row_sums(u * u)
-    p_med2 = jnp.sum(med * med, keepdims=True)       # (1, 1)
-
-    @pl.when(pl.program_id(d_axis) == 0)
-    def _init():
-        for ref in acc_refs:
-            ref[...] = jnp.zeros_like(ref)
-
-    dist2_ref[...] += p_dist2.reshape(dist2_ref.shape)
-    dotmed_ref[...] += p_dot.reshape(dotmed_ref.shape)
-    norm2_ref[...] += p_norm2.reshape(norm2_ref.shape)
-    mednorm2_ref[...] += p_med2.reshape(mednorm2_ref.shape)
-
-    if has_prev:
-        pdist2_ref, pdot_ref, pnorm2_ref = acc_refs[4:]
-        pv = prev_ref[...].astype(jnp.float32)
-        pv = pv.reshape(pv.shape[-2], pv.shape[-1])
-        dprev = u - pv
-        pdist2_ref[...] += _row_sums(dprev * dprev).reshape(pdist2_ref.shape)
-        pdot_ref[...] += _row_sums(u * pv).reshape(pdot_ref.shape)
-        pnorm2_ref[...] += _row_sums(pv * pv).reshape(pnorm2_ref.shape)
-
-
-def robust_stats_pallas(
-    updates: Array,
-    prev: Array | None = None,
-    *,
-    n_trim: int,
-    block_d: int = 1024,
-    interpret: bool | None = None,
-    emit_center: bool = True,
-):
-    """Launch the fused robust-stats kernel.  D must be a multiple of block_d.
-
-    Returns ([med, trim,] dist2, dotmed, norm2, mednorm2[, prev_dist2,
-    prev_dot, prev_norm2]) — med/trim only with ``emit_center``, the
-    temporal tail only when ``prev`` is given.
-    """
-    K, D = updates.shape
-    assert D % block_d == 0, (D, block_d)
-    has_prev = prev is not None
-    grid = (D // block_d,)
-    kernel = functools.partial(
-        _robust_stats_kernel, n_trim=n_trim, has_prev=has_prev,
-        emit_center=emit_center, d_axis=0
-    )
-    d_spec = pl.BlockSpec((1, block_d), lambda i: (0, i))
-    k_spec = pl.BlockSpec((1, K), lambda i: (0, 0))
-    out_shapes, out_specs = [], []
-    if emit_center:
-        out_shapes += [jax.ShapeDtypeStruct((1, D), jnp.float32)] * 2  # med, trim
-        out_specs += [d_spec, d_spec]
-    out_shapes += [
-        jax.ShapeDtypeStruct((1, K), jnp.float32),   # dist2
-        jax.ShapeDtypeStruct((1, K), jnp.float32),   # dotmed
-        jax.ShapeDtypeStruct((1, K), jnp.float32),   # norm2
-        jax.ShapeDtypeStruct((1, 1), jnp.float32),   # mednorm2
-    ]
-    out_specs += [k_spec, k_spec, k_spec,
-                  pl.BlockSpec((1, 1), lambda i: (0, 0))]
-    in_specs = [pl.BlockSpec((K, block_d), lambda i: (0, i))]
-    args = [updates]
-    if has_prev:
-        assert prev.shape == updates.shape, (prev.shape, updates.shape)
-        in_specs.append(pl.BlockSpec((K, block_d), lambda i: (0, i)))
-        args.append(prev)
-        out_shapes += [jax.ShapeDtypeStruct((1, K), jnp.float32)] * 3
-        out_specs += [k_spec] * 3
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=tuple(out_specs),
-        out_shape=tuple(out_shapes),
-        interpret=resolve_interpret(interpret),
-    )(*args)
-
-
 def _flush_indexed_stats(u: Array, valid_row: Array, acc_refs,
                          is_first_d, need_gram: bool, pv: Array | None):
     """Accumulate one D block of the indexed statistics off the resident
     (K, T) candidate tile ``u`` (and ``pv``, the matching previous-round
-    tile): the shared phase-0 flush of the indexed stats kernel and the
-    round kernel.  The median honors the node's valid row: invalid
+    tile): phase 0 of the round kernel.  The median honors the node's valid row: invalid
     (padded) rows sort to +inf and the median picks the dynamic middle of
     the v valid rows.  Per-candidate statistics are computed on the RAW
     rows (padded slots hold the node's own finite model), so they stay
@@ -250,38 +129,6 @@ def _flush_indexed_stats(u: Array, valid_row: Array, acc_refs,
         pnorm2_ref[...] += _row_sums(pv * pv)
 
 
-def _robust_stats_indexed_kernel(*refs, K: int, has_prev: bool,
-                                 need_gram: bool):
-    """Gather-free body: grid (node, D block, neighbor slot).  Each step
-    DMAs ONE neighbor row block — models[neighbor_idx[n, k], d-block],
-    resolved by the scalar-prefetch index map — into a VMEM scratch row;
-    at the last slot the full (K, T) candidate tile is resident and the
-    stats flush (``_flush_indexed_stats``), so the (N, K, d) gossip
-    tensor never exists in HBM."""
-    refs = list(refs[1:])  # refs[0]: the prefetched table, read by index maps
-    valid_ref = refs.pop(0)
-    u_ref = refs.pop(0)
-    prev_ref = refs.pop(0) if has_prev else None
-    scratch_p = refs.pop() if has_prev else None
-    scratch_u = refs.pop()
-    acc_refs = refs
-
-    # program ids are read outside the pl.when bodies
-    k = pl.program_id(2)
-    is_last = k == K - 1
-    is_first_d = pl.program_id(1) == 0
-
-    scratch_u[pl.ds(k, 1), :] = u_ref[...].astype(jnp.float32)
-    if has_prev:
-        scratch_p[pl.ds(k, 1), :] = prev_ref[...].astype(jnp.float32)
-
-    @pl.when(is_last)
-    def _flush():
-        _flush_indexed_stats(
-            scratch_u[...], valid_ref[...], acc_refs, is_first_d, need_gram,
-            scratch_p[...] if has_prev else None)
-
-
 def _prev_rows(prev: Array, models: Array, neighbor_idx: Array,
                prev_idx: Array | None):
     """(row-view array, index table, row map) for the ``prev`` input of
@@ -308,87 +155,6 @@ def _prev_rows(prev: Array, models: Array, neighbor_idx: Array,
         raise ValueError("prev_idx requires a matrix-form prev")
     assert prev.shape == (N, K, D), (prev.shape, (N, K, D))
     return prev.reshape(N * K, 1, D), table, lambda ir, n, k: n * K + k
-
-
-def robust_stats_indexed_pallas(
-    models: Array,        # (M, D) model matrix (row per node)
-    neighbor_idx: Array,  # (N, K) int32 rows into ``models``
-    valid: Array,         # (N, K) float32, 1.0 on real edges
-    prev: Array | None = None,   # (N, K, D) per-edge, or (M, D) matrix
-    prev_idx: Array | None = None,  # (N, K) rows into matrix ``prev``
-    *,
-    block_d: int = 1024,
-    interpret: bool | None = None,
-    need_gram: bool = False,
-):
-    """Gather-free robust-stats launch over a 3-D (node, D block, slot)
-    grid via ``PrefetchScalarGridSpec``: the neighbor table rides in SMEM
-    and the models input's index map reads it, so each grid step streams
-    one neighbor row block straight from the (M, D) matrix.  ``prev`` may
-    be per-edge (N, K, D) or a previous-round model matrix (M, D) read
-    through the same index table — or, with ``prev_idx``, through its OWN
-    (N, K) table (fault-injected transport: the payload an edge served
-    last round need not be the row it reads this round).
-    ``need_gram`` also accumulates each node's (K, K) candidate Gram off
-    the same resident tile (Alt-WFAgg).
-    Returns (dist2, dotmed, norm2, mednorm2[, gram][, prev_dist2,
-    prev_dot, prev_norm2]) shaped like the batched launch ((N, 1, K) /
-    (N, 1, 1) / (N, K, K)).
-    """
-    M, D = models.shape
-    N, K = neighbor_idx.shape
-    assert D % block_d == 0, (D, block_d)
-    has_prev = prev is not None
-    if prev_idx is not None and not (has_prev and prev.ndim == 2):
-        raise ValueError("prev_idx requires a matrix-form prev")
-    kernel = functools.partial(
-        _robust_stats_indexed_kernel, K=K, has_prev=has_prev,
-        need_gram=need_gram,
-    )
-    k_spec = pl.BlockSpec((None, 1, K), lambda n, i, k, ir: (n, 0, 0))
-    in_specs = [
-        k_spec,                                                        # valid
-        pl.BlockSpec((None, 1, block_d),
-                     lambda n, i, k, ir: (ir[n, k], 0, i)),            # models
-    ]
-    args = [valid.astype(jnp.float32).reshape(N, 1, K), _row_view(models)]
-    table = neighbor_idx
-    if has_prev:
-        view, table, row = _prev_rows(prev, models, neighbor_idx, prev_idx)
-        in_specs.append(pl.BlockSpec(
-            (None, 1, block_d), lambda n, i, k, ir: (row(ir, n, k), 0, i)))
-        args.append(view)
-    out_shapes = [
-        jax.ShapeDtypeStruct((N, 1, K), jnp.float32),   # dist2
-        jax.ShapeDtypeStruct((N, 1, K), jnp.float32),   # dotmed
-        jax.ShapeDtypeStruct((N, 1, K), jnp.float32),   # norm2
-        jax.ShapeDtypeStruct((N, 1, 1), jnp.float32),   # mednorm2
-    ]
-    out_specs = [k_spec, k_spec, k_spec,
-                 pl.BlockSpec((None, 1, 1), lambda n, i, k, ir: (n, 0, 0))]
-    if need_gram:
-        out_shapes.append(jax.ShapeDtypeStruct((N, K, K), jnp.float32))
-        out_specs.append(
-            pl.BlockSpec((None, K, K), lambda n, i, k, ir: (n, 0, 0)))
-    if has_prev:
-        out_shapes += [jax.ShapeDtypeStruct((N, 1, K), jnp.float32)] * 3
-        out_specs += [k_spec] * 3
-    scratch_shapes = [pltpu.VMEM((K, block_d), jnp.float32)]
-    if has_prev:
-        scratch_shapes.append(pltpu.VMEM((K, block_d), jnp.float32))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(N, D // block_d, K),
-        in_specs=in_specs,
-        out_specs=tuple(out_specs),
-        scratch_shapes=scratch_shapes,
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=tuple(out_shapes),
-        interpret=resolve_interpret(interpret),
-    )(table.astype(jnp.int32), *args)
 
 
 # VMEM the round kernel's tile-sized buffers may take: the double-buffered
@@ -434,11 +200,17 @@ def round_padded_width(K: int, d: int, has_prev: bool) -> int:
     return 1024 * n_t * -(-nb // n_t)
 
 
+# what one launch of the round kernel runs: the grid's phase axis walks
+# these phases in order (0 = statistics, 1 = combine)
+ROUND, STATS, COMBINE = (0, 1), (0,), (1,)
+
+
 def _wfagg_round_indexed_kernel(*refs, K: int, n_t: int, T: int, chunk: int,
-                                has_prev: bool, prev_row, has_tbands: bool,
-                                need_gram: bool, cfg, alpha: float,
-                                mean_fallback: bool, stats_only: bool):
-    """Single-launch WFAgg round body: grid (node, PHASE, D tile).
+                                phases: tuple, has_prev: bool, prev_row,
+                                has_tbands: bool, need_gram: bool, cfg,
+                                alpha: float, mean_fallback: bool):
+    """WFAgg round body: grid (node, PHASE, D tile), the phase axis
+    walking ``phases``.
 
     Each step gathers the node's K neighbor rows of one (K, T) tile with
     K row DMAs, ``models[table[n, k], tile] -> land_u[slot, k]`` (in
@@ -447,49 +219,51 @@ def _wfagg_round_indexed_kernel(*refs, K: int, n_t: int, T: int, chunk: int,
     node boundaries too, so only the launch's first tile is exposed.
     Phase 0 flushes the D/C/T accumulators (and the Alt-WFAgg Gram) off
     the resident tile, ``chunk`` lanes at a time
-    (``_flush_indexed_stats``, as the two-launch stats kernel does).  At
-    the phase boundary (phase 0, last tile) the WFAgg scoring stage runs
-    IN-KERNEL on the VMEM-resident (1, K) accumulators
+    (``_flush_indexed_stats``).  Under ``ROUND``, at the phase boundary
+    (phase 0, last tile) the WFAgg scoring stage runs IN-KERNEL on the
+    VMEM-resident (1, K) accumulators
     (``core.trust.derive_trust_weights``), the masks/weights are written
     to their O(K) outputs, and the normalized combine coefficients land
     in a VMEM scratch.  Phase 1 gathers the tiles again and writes
     ``lcoef * local + sum_k w_k u_k`` to the (1, T) output block — no
-    host round-trip and no second kernel launch.
+    host round-trip and no second kernel launch.  ``STATS`` stops after
+    phase 0's accumulators; ``COMBINE`` reads the coefficients from its
+    (1, K) and (1, 1) inputs instead of the scratch.
 
     The WFAgg-T decision is four compares against the precomputed flat
     (1, 4K) EWMA band input (``core.trust.temporal_bands`` — the history
     lives outside the kernel); the ring-buffer push happens on the host
     off the emitted temporal statistics.
-
-    ``stats_only`` runs phase 0 alone (grid (node, 1, D tile)) and emits
-    only the accumulators: the launch of a caller that holds a slice of
-    the columns and sums the accumulators across slices before it scores
-    (``distributed.robust_allreduce``'s sliced round).
     """
     # deferred import: core.wfagg -> robust_stats.ops -> this module at
     # package-init time; by kernel-trace time repro.core is fully loaded
     from repro.core import trust
 
+    run_stats, run_combine = 0 in phases, 1 in phases
     table = refs[0]
     refs = list(refs[1:])
-    valid_ref = refs.pop(0)
+    valid_ref = refs.pop(0) if run_stats else None
     tbands_ref = refs.pop(0) if has_tbands else None
-    local_ref = None if stats_only else refs.pop(0)
+    coef_in = (refs.pop(0), refs.pop(0)) if not run_stats else None
+    local_ref = refs.pop(0) if run_combine else None
     models_hbm = refs.pop(0)
     prev_hbm = refs.pop(0) if has_prev else None
-    n_out = 0 if stats_only else 5
-    out_ref, w_ref, md_ref, mc_ref, mt_ref = refs[:n_out] or (None,) * 5
-    n_acc = 4 + (1 if need_gram else 0) + (3 if has_prev else 0)
+    n_out = 5 if phases == ROUND else int(run_combine)
+    out_ref, w_ref, md_ref, mc_ref, mt_ref = (refs[:n_out] + [None] * 5)[:5]
+    n_acc = (4 + (1 if need_gram else 0) + (3 if has_prev else 0)
+             if run_stats else 0)
     acc_refs = refs[n_out:n_out + n_acc]
     scratch = refs[n_out + n_acc:]
-    dist2_ref, dotmed_ref, norm2_ref, mednorm2_ref = acc_refs[:4]
-    gram_ref = acc_refs[4] if need_gram else None
-    prev_acc = acc_refs[5 if need_gram else 4:] if has_prev else ()
+    if run_stats:
+        dist2_ref, dotmed_ref, norm2_ref, mednorm2_ref = acc_refs[:4]
+        gram_ref = acc_refs[4] if need_gram else None
+        prev_acc = acc_refs[5 if need_gram else 4:] if has_prev else ()
     land_u, sem_u = scratch[0], scratch[1]
     land_p, sem_p = (scratch[2], scratch[3]) if has_prev else (None, None)
-    wcomb_ref, lcoef_ref = (None, None) if stats_only else scratch[-2:]
+    wcomb_ref, lcoef_ref = coef_in or (
+        scratch[-2:] if run_combine else (None, None))
 
-    n_phase = 1 if stats_only else 2
+    n_phase = len(phases)
     n, p, i = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     step = (n_phase * n + p) * n_t + i
     slot = jax.lax.rem(step, 2)
@@ -526,50 +300,52 @@ def _wfagg_round_indexed_kernel(*refs, K: int, n_t: int, T: int, chunk: int,
 
     gather(n, p, i, slot, "wait")
 
-    @pl.when(p == 0)
-    def _stats():
-        valid_row = valid_ref[...]
+    if run_stats:
+        @pl.when(p == 0)
+        def _stats():
+            valid_row = valid_ref[...]
 
-        def flush(c, carry):
-            cols = pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
-            u = land_u[slot, :, :, cols].reshape(K, chunk)
-            pv = (land_p[slot, :, :, cols].reshape(K, chunk)
-                  if has_prev else None)
-            _flush_indexed_stats(u, valid_row, acc_refs, (i == 0) & (c == 0),
-                                 need_gram, pv)
-            return carry
+            def flush(c, carry):
+                cols = pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
+                u = land_u[slot, :, :, cols].reshape(K, chunk)
+                pv = (land_p[slot, :, :, cols].reshape(K, chunk)
+                      if has_prev else None)
+                _flush_indexed_stats(u, valid_row, acc_refs,
+                                     (i == 0) & (c == 0), need_gram, pv)
+                return carry
 
-        jax.lax.fori_loop(0, T // chunk, flush, 0)
+            jax.lax.fori_loop(0, T // chunk, flush, 0)
 
-    if stats_only:
+    if not run_combine:
         return
 
-    @pl.when((p == 0) & last_t)
-    def _derive():
-        valid_f = valid_ref[...]                              # (1, K)
-        tail = [r[...] for r in prev_acc] if has_prev else [None] * 3
-        stats = RobustStats(
-            med=None, trim=None,
-            dist2=dist2_ref[...], dotmed=dotmed_ref[...],
-            norm2=norm2_ref[...], mednorm2=mednorm2_ref[...],
-            prev_dist2=tail[0], prev_dot=tail[1], prev_norm2=tail[2],
-        )
-        gram = gram_ref[...] if need_gram else None
-        tb = tbands_ref[...] if has_tbands else None
-        mask_d, mask_c, mask_t, w = trust.derive_trust_weights(
-            stats, gram, valid_f, tb, cfg)
-        md_ref[...] = mask_d.astype(jnp.float32)
-        mc_ref[...] = mask_c.astype(jnp.float32)
-        mt_ref[...] = mask_t.astype(jnp.float32)
-        w_ref[...] = w
-        wcomb, lcoef = trust.combine_coefficients(w, alpha, valid_f,
-                                                  mean_fallback)
-        wcomb_ref[...] = wcomb
-        lcoef_ref[...] = jnp.broadcast_to(lcoef, (1, 1))
+    if run_stats:
+        @pl.when((p == 0) & last_t)
+        def _derive():
+            valid_f = valid_ref[...]                              # (1, K)
+            tail = [r[...] for r in prev_acc] if has_prev else [None] * 3
+            stats = RobustStats(
+                med=None, trim=None,
+                dist2=dist2_ref[...], dotmed=dotmed_ref[...],
+                norm2=norm2_ref[...], mednorm2=mednorm2_ref[...],
+                prev_dist2=tail[0], prev_dot=tail[1], prev_norm2=tail[2],
+            )
+            gram = gram_ref[...] if need_gram else None
+            tb = tbands_ref[...] if has_tbands else None
+            mask_d, mask_c, mask_t, w = trust.derive_trust_weights(
+                stats, gram, valid_f, tb, cfg)
+            md_ref[...] = mask_d.astype(jnp.float32)
+            mc_ref[...] = mask_c.astype(jnp.float32)
+            mt_ref[...] = mask_t.astype(jnp.float32)
+            w_ref[...] = w
+            wcomb, lcoef = trust.combine_coefficients(w, alpha, valid_f,
+                                                      mean_fallback)
+            wcomb_ref[...] = wcomb
+            lcoef_ref[...] = jnp.broadcast_to(lcoef, (1, 1))
 
-    # ---- phase 1: trust-weighted combine over the resident tile, in the
-    # slot order of _weighted_agg_indexed_kernel
-    @pl.when(p == 1)
+    # ---- phase 1: trust-weighted combine over the resident tile, f32 in
+    # slot order
+    @pl.when(p == n_phase - 1)
     def _combine():
         kio = jax.lax.broadcasted_iota(jnp.int32, (1, K), 1)
         wcomb = wcomb_ref[...]
@@ -581,10 +357,10 @@ def _wfagg_round_indexed_kernel(*refs, K: int, n_t: int, T: int, chunk: int,
 
 
 def wfagg_round_indexed_pallas(
-    local: Array,         # (N, D) local models (combine anchors)
+    local: Array | None,  # (N, D) local models (combine anchors)
     models: Array,        # (M, D) model matrix (row per node)
     neighbor_idx: Array,  # (N, K) int32 rows into ``models``
-    valid: Array,         # (N, K) float32, 1.0 on real edges
+    valid: Array | None,  # (N, K) float32, 1.0 on real edges
     cfg,                  # duck-typed WFAggConfig (static)
     prev: Array | None = None,    # (N, K, D) per-edge, or (M, D) matrix
     tbands: Array | None = None,  # (N, 4K) flat WFAgg-T EWMA bands
@@ -595,45 +371,53 @@ def wfagg_round_indexed_pallas(
     need_gram: bool = False,
     block_d: int,
     interpret: bool | None = None,
-    stats_only: bool = False,
+    phases: tuple = ROUND,
+    coef: tuple | None = None,  # COMBINE: (wcomb (N, K), lcoef (N, 1))
 ):
-    """Launch the single-launch WFAgg round kernel over a 3-D
-    (node, phase, D tile) grid with (K, block_d) tiles.  ``models`` and
-    ``prev`` stay in HBM (``pl.ANY``) and the body gathers each tile's K
-    rows with row DMAs; the other operands are BlockSpec-pipelined.
-    Phase 0 accumulates the indexed robust statistics, the phase
-    boundary derives the trust weights in-kernel, and phase 1 writes the
-    WFAgg-E combine — one launch for the entire gossip round.
+    """Launch the WFAgg round kernel over a 3-D (node, phase, D tile)
+    grid with (K, block_d) tiles.  ``models`` and ``prev`` stay in HBM
+    (``pl.ANY``) and the body gathers each tile's K rows with row DMAs;
+    the other operands are BlockSpec-pipelined.
+
+    ``phases=ROUND``: phase 0 accumulates the indexed robust statistics,
+    the phase boundary derives the trust weights in-kernel, and phase 1
+    writes the WFAgg-E combine — one launch for the entire gossip round.
+    Returns (out (N, 1, D), weights, mask_d, mask_c, mask_t (each
+    (N, 1, K)), dist2, dotmed, norm2 ((N, 1, K)), mednorm2 ((N, 1, 1))
+    [, gram (N, K, K)][, prev_dist2, prev_dot, prev_norm2 ((N, 1, K))]).
+
+    ``phases=STATS`` (``local``, ``tbands`` None) runs phase 0 alone over
+    a (node, 1, D tile) grid and returns the accumulators only, from
+    ``dist2`` on.  ``phases=COMBINE`` (``valid``, ``prev``, ``tbands``
+    None) runs phase 1 alone with the combine coefficients of ``coef``
+    and returns ``(out,)``.
 
     With ``prev_idx`` the matrix-form ``prev`` reads through its own
     (N, K) table (concatenated after ``neighbor_idx`` into one (N, 2K)
     SMEM prefetch block) instead of re-using the models table — the
     fault-injected transport's staleness pricing, still one launch.
-
-    Returns (out (N, 1, D), weights, mask_d, mask_c, mask_t (each
-    (N, 1, K)), dist2, dotmed, norm2 ((N, 1, K)), mednorm2 ((N, 1, 1))
-    [, gram (N, K, K)][, prev_dist2, prev_dot, prev_norm2 ((N, 1, K))]).
-
-    ``stats_only`` (``local`` and ``tbands`` None) runs phase 0 alone
-    over a (node, 1, D tile) grid and returns the accumulators only,
-    from ``dist2`` on.
     """
     M, D = models.shape
     N, K = neighbor_idx.shape
     assert D % block_d == 0, (D, block_d)
-    if stats_only:
-        assert local is None and tbands is None
-    else:
+    stats, combine = 0 in phases, 1 in phases
+    assert (local is not None) == combine, phases
+    assert (valid is not None) == stats and (coef is None) == stats, phases
+    if combine:
         assert local.shape == (N, D), (local.shape, (N, D))
     n_t = D // block_d
     has_prev = prev is not None
     has_tbands = tbands is not None
+    assert stats or not (has_prev or has_tbands), phases
     if prev_idx is not None and not (has_prev and prev.ndim == 2):
         raise ValueError("prev_idx requires a matrix-form prev")
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     k_spec = pl.BlockSpec((None, 1, K), lambda n, p, i, ir: (n, 0, 0))
-    in_specs = [k_spec]                                            # valid
-    args = [valid.astype(jnp.float32).reshape(N, 1, K)]
+    one_spec = pl.BlockSpec((None, 1, 1), lambda n, p, i, ir: (n, 0, 0))
+    in_specs, args = [], []
+    if stats:
+        in_specs.append(k_spec)                                    # valid
+        args.append(valid.astype(jnp.float32).reshape(N, 1, K))
     if has_tbands:
         # bands ride flat, (N, 1, 4K): a (N, 4, K) buffer would read as a
         # gossip tensor to the rank-based (N, K, d)-free scan when K == 4
@@ -641,12 +425,19 @@ def wfagg_round_indexed_pallas(
         in_specs.append(
             pl.BlockSpec((None, 1, 4 * K), lambda n, p, i, ir: (n, 0, 0)))
         args.append(tbands.astype(jnp.float32).reshape(N, 1, 4 * K))
-    # local and the combine output: pinned to tile 0 during phase 0
-    # (only phase 1 touches them) — `i * p` keeps the block constant
-    # until the combine phase
-    row_spec = pl.BlockSpec((None, 1, block_d),
-                            lambda n, p, i, ir: (n, 0, i * p))
-    if not stats_only:
+    if not stats:
+        wcomb, lcoef = coef
+        in_specs += [k_spec, one_spec]
+        args += [wcomb.astype(jnp.float32).reshape(N, 1, K),
+                 lcoef.astype(jnp.float32).reshape(N, 1, 1)]
+    # local and the combine output: under ROUND pinned to tile 0 during
+    # phase 0 (only phase 1 touches them) — `i * p` keeps the block
+    # constant until the combine phase
+    row_spec = pl.BlockSpec(
+        (None, 1, block_d),
+        (lambda n, p, i, ir: (n, 0, i * p)) if stats
+        else (lambda n, p, i, ir: (n, 0, i)))
+    if combine:
         in_specs.append(row_spec)
         args.append(_row_view(local))
     in_specs.append(hbm)
@@ -660,50 +451,50 @@ def wfagg_round_indexed_pallas(
     chunk = ROUND_CHUNK if block_d % ROUND_CHUNK == 0 else block_d
     kernel = functools.partial(
         _wfagg_round_indexed_kernel, K=K, n_t=n_t, T=block_d, chunk=chunk,
-        has_prev=has_prev, prev_row=prev_row, has_tbands=has_tbands,
-        need_gram=need_gram, cfg=cfg, alpha=alpha,
-        mean_fallback=mean_fallback, stats_only=stats_only,
+        phases=phases, has_prev=has_prev, prev_row=prev_row,
+        has_tbands=has_tbands, need_gram=need_gram, cfg=cfg, alpha=alpha,
+        mean_fallback=mean_fallback,
     )
 
-    out_shapes = [] if stats_only else [
-        jax.ShapeDtypeStruct((N, 1, D), jnp.float32),   # combined models
-        jax.ShapeDtypeStruct((N, 1, K), jnp.float32),   # trust weights
-        jax.ShapeDtypeStruct((N, 1, K), jnp.float32),   # mask_d
-        jax.ShapeDtypeStruct((N, 1, K), jnp.float32),   # mask_c
-        jax.ShapeDtypeStruct((N, 1, K), jnp.float32),   # mask_t
-    ]
-    out_shapes += [
-        jax.ShapeDtypeStruct((N, 1, K), jnp.float32),   # dist2
-        jax.ShapeDtypeStruct((N, 1, K), jnp.float32),   # dotmed
-        jax.ShapeDtypeStruct((N, 1, K), jnp.float32),   # norm2
-        jax.ShapeDtypeStruct((N, 1, 1), jnp.float32),   # mednorm2
-    ]
-    out_specs = [] if stats_only else [
-        row_spec,
-        k_spec, k_spec, k_spec, k_spec,                  # weights + masks
-    ]
-    out_specs += [
-        k_spec, k_spec, k_spec,
-        pl.BlockSpec((None, 1, 1), lambda n, p, i, ir: (n, 0, 0)),
-    ]
-    if need_gram:
-        out_shapes.append(jax.ShapeDtypeStruct((N, K, K), jnp.float32))
-        out_specs.append(
-            pl.BlockSpec((None, K, K), lambda n, p, i, ir: (n, 0, 0)))
-    if has_prev:
-        out_shapes += [jax.ShapeDtypeStruct((N, 1, K), jnp.float32)] * 3
-        out_specs += [k_spec] * 3
+    out_shapes, out_specs = [], []
+    if combine:
+        out_shapes.append(
+            jax.ShapeDtypeStruct((N, 1, D), jnp.float32))  # combined models
+        out_specs.append(row_spec)
+    if phases == ROUND:
+        out_shapes += [
+            jax.ShapeDtypeStruct((N, 1, K), jnp.float32),   # trust weights
+            jax.ShapeDtypeStruct((N, 1, K), jnp.float32),   # mask_d
+            jax.ShapeDtypeStruct((N, 1, K), jnp.float32),   # mask_c
+            jax.ShapeDtypeStruct((N, 1, K), jnp.float32),   # mask_t
+        ]
+        out_specs += [k_spec] * 4
+    if stats:
+        out_shapes += [
+            jax.ShapeDtypeStruct((N, 1, K), jnp.float32),   # dist2
+            jax.ShapeDtypeStruct((N, 1, K), jnp.float32),   # dotmed
+            jax.ShapeDtypeStruct((N, 1, K), jnp.float32),   # norm2
+            jax.ShapeDtypeStruct((N, 1, 1), jnp.float32),   # mednorm2
+        ]
+        out_specs += [k_spec, k_spec, k_spec, one_spec]
+        if need_gram:
+            out_shapes.append(jax.ShapeDtypeStruct((N, K, K), jnp.float32))
+            out_specs.append(
+                pl.BlockSpec((None, K, K), lambda n, p, i, ir: (n, 0, 0)))
+        if has_prev:
+            out_shapes += [jax.ShapeDtypeStruct((N, 1, K), jnp.float32)] * 3
+            out_specs += [k_spec] * 3
     # landing tiles: two slots of K (1, block_d) rows, each row its own
     # (1, 128)-tiled block, so a row DMA lands on whole tiles
     land = [pltpu.VMEM((2, K, 1, block_d), jnp.float32),
             pltpu.SemaphoreType.DMA((2,))]
     scratch_shapes = land * (2 if has_prev else 1)
-    if not stats_only:
+    if phases == ROUND:
         scratch_shapes += [pltpu.VMEM((1, K), jnp.float32),  # combine weights
                            pltpu.VMEM((1, 1), jnp.float32)]  # local coefficient
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(N, 1 if stats_only else 2, n_t),
+        grid=(N, len(phases), n_t),
         in_specs=in_specs,
         out_specs=tuple(out_specs),
         scratch_shapes=scratch_shapes,
@@ -714,55 +505,3 @@ def wfagg_round_indexed_pallas(
         out_shape=tuple(out_shapes),
         interpret=resolve_interpret(interpret),
     )(table.astype(jnp.int32), *args)
-
-
-def robust_stats_batch_pallas(
-    updates: Array,
-    prev: Array | None = None,
-    *,
-    n_trim: int,
-    block_d: int = 1024,
-    interpret: bool | None = None,
-    emit_center: bool = True,
-):
-    """Batched launch: one kernel over (N, K, D) computes every node's
-    statistics.  2-D grid (node, D block); same outputs as the single
-    launch with a leading N axis."""
-    N, K, D = updates.shape
-    assert D % block_d == 0, (D, block_d)
-    has_prev = prev is not None
-    grid = (N, D // block_d)
-    kernel = functools.partial(
-        _robust_stats_kernel, n_trim=n_trim, has_prev=has_prev,
-        emit_center=emit_center, d_axis=1
-    )
-    d_spec = pl.BlockSpec((1, 1, block_d), lambda n, i: (n, 0, i))
-    k_spec = pl.BlockSpec((1, 1, K), lambda n, i: (n, 0, 0))
-    out_shapes, out_specs = [], []
-    if emit_center:
-        out_shapes += [jax.ShapeDtypeStruct((N, 1, D), jnp.float32)] * 2
-        out_specs += [d_spec, d_spec]
-    out_shapes += [
-        jax.ShapeDtypeStruct((N, 1, K), jnp.float32),   # dist2
-        jax.ShapeDtypeStruct((N, 1, K), jnp.float32),   # dotmed
-        jax.ShapeDtypeStruct((N, 1, K), jnp.float32),   # norm2
-        jax.ShapeDtypeStruct((N, 1, 1), jnp.float32),   # mednorm2
-    ]
-    out_specs += [k_spec, k_spec, k_spec,
-                  pl.BlockSpec((1, 1, 1), lambda n, i: (n, 0, 0))]
-    in_specs = [pl.BlockSpec((1, K, block_d), lambda n, i: (n, 0, i))]
-    args = [updates]
-    if has_prev:
-        assert prev.shape == updates.shape, (prev.shape, updates.shape)
-        in_specs.append(pl.BlockSpec((1, K, block_d), lambda n, i: (n, 0, i)))
-        args.append(prev)
-        out_shapes += [jax.ShapeDtypeStruct((N, 1, K), jnp.float32)] * 3
-        out_specs += [k_spec] * 3
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=tuple(out_specs),
-        out_shape=tuple(out_shapes),
-        interpret=resolve_interpret(interpret),
-    )(*args)

@@ -1,17 +1,18 @@
-"""Jitted public wrappers for the fused robust-stats kernel.
+"""Jitted public wrappers for the WFAgg round kernel.
 
-Handles D padding to the block size (zero padding is exact: a zero column
-has median 0, contributing nothing to any accumulated statistic — and
-this extends to the temporal statistics, since ``prev`` is padded with
-zeros too) and returns the same ``RobustStats`` namedtuple as the oracle
-in ref.py.
+Each pads D to the tile width (zero padding is exact: a zero column has
+median 0 and contributes nothing to any accumulated statistic, the
+temporal ones included since ``prev`` is padded with zeros too, nor to
+the combine) and returns the same ``RobustStats`` namedtuple as the
+oracles in ref.py, with a leading N axis:
 
-``robust_stats`` operates on one (K, D) candidate matrix;
-``robust_stats_batch`` runs all N nodes of a gossip round through ONE
-kernel launch over the gathered (N, K, D) tensor (2-D grid), instead of
-a vmap of single-node calls — vmapping a pallas_call serializes into a
-per-node outer loop, while the batched grid streams every node's blocks
-through the same kernel instance.
+  wfagg_round_indexed          both phases — the whole gossip round in
+                               one launch, trust weights derived in-kernel
+  wfagg_round_indexed_stats    phase 0 alone: the statistics
+  wfagg_round_indexed_combine  phase 1 alone: the WFAgg-E combine with
+                               caller-given coefficients
+
+The HLO names of their launches all start with ``wfagg_round_indexed``.
 """
 from __future__ import annotations
 
@@ -21,192 +22,27 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.common import (
-    auto_block_d, pad_d, resolve_block_d, resolve_interpret)
+from repro.kernels.common import pad_d, resolve_interpret
 from repro.kernels.robust_stats.kernel import (
-    robust_stats_batch_pallas,
-    robust_stats_indexed_pallas,
-    robust_stats_pallas,
+    COMBINE,
+    STATS,
     round_tile_width,
     wfagg_round_indexed_pallas,
 )
-from repro.kernels.robust_stats.ref import (
-    RobustStats,
-    robust_stats_indexed_ref,
-    robust_stats_ref,
-    trim_count,
-)
+from repro.kernels.robust_stats.ref import RobustStats
 
 
-@functools.partial(jax.jit, static_argnames=(
-    "beta", "block_d", "interpret", "use_kernel", "need_center"))
-def robust_stats(
-    updates: jax.Array,
-    prev: Optional[jax.Array] = None,
-    beta: float = 0.1,
-    block_d: Optional[int] = None,
-    interpret: Optional[bool] = None,
-    use_kernel: bool = True,
-    need_center: bool = True,
-) -> RobustStats:
-    """Fused median / trimmed-mean / WFAgg filter statistics over (K, D).
-
-    With ``prev`` (the previous-round candidates), the same single pass
-    also emits the WFAgg-T temporal metrics (prev_dist2/prev_dot/
-    prev_norm2); without it those fields are None.  ``block_d=None``
-    picks a backend-appropriate tile (see auto_block_d).
-    ``need_center=False`` skips the streaming (D,)-sized median/trim
-    outputs (med/trim come back None) — the WFAgg filter bank consumes
-    only the O(K) accumulators, so its fused path writes nothing d-sized.
-    """
-    if not use_kernel:
-        return robust_stats_ref(updates, beta, prev=prev)
-    K, D = updates.shape
-    n_trim = trim_count(K, beta)
-    block_d, itp = resolve_block_d(D, block_d, interpret)
-    u = pad_d(updates, block_d)
-    p = pad_d(prev, block_d) if prev is not None else None
-    outs = robust_stats_pallas(
-        u, p, n_trim=n_trim, block_d=block_d, interpret=itp,
-        emit_center=need_center,
-    )
-    if need_center:
-        med, trim = outs[0][0, :D], outs[1][0, :D]
-        outs = outs[2:]
-    else:
-        med = trim = None
-    dist2, dotmed, norm2, mednorm2 = outs[:4]
-    tail = (None, None, None)
-    if prev is not None:
-        tail = tuple(o[0] for o in outs[4:])
-    return RobustStats(
-        med=med,
-        trim=trim,
-        dist2=dist2[0],
-        dotmed=dotmed[0],
-        norm2=norm2[0],
-        mednorm2=mednorm2[0, 0],
-        prev_dist2=tail[0],
-        prev_dot=tail[1],
-        prev_norm2=tail[2],
-    )
-
-
-@functools.partial(jax.jit, static_argnames=(
-    "block_d", "interpret", "use_kernel", "need_gram"))
-def robust_stats_indexed(
-    models: jax.Array,
-    neighbor_idx: jax.Array,
-    valid: Optional[jax.Array] = None,
-    prev: Optional[jax.Array] = None,
-    block_d: Optional[int] = None,
-    interpret: Optional[bool] = None,
-    use_kernel: bool = True,
-    need_gram: bool = False,
-    prev_idx: Optional[jax.Array] = None,
-) -> RobustStats:
-    """Gather-free batched statistics: ``models (M, d)`` + ``neighbor_idx
-    (N, K)`` replace the gathered (N, K, d) tensor — the kernel DMAs each
-    neighbor's d-block straight from the model matrix (scalar-prefetch
-    index map), so the K-fold gossip tensor never exists in HBM.
-
-    ``valid (N, K)`` marks real edges on irregular (padded) topologies:
-    the in-kernel median spans only valid rows; per-candidate stats of
-    padded slots are finite garbage the caller masks out.  ``prev`` may
-    be per-edge (N, K, d) or a previous-round model matrix (M, d) read
-    through the same index table.  Output layout matches
-    ``robust_stats_batch`` (leading N axis; med/trim are None — the
-    filter bank never reads a d-sized center).  ``need_gram`` also emits
-    the per-node (K, K) candidate Gram, accumulated from the SAME
-    resident tile — no extra pass, and nothing quadratic in the total
-    node count M (the Alt-WFAgg filters consume it).  ``prev_idx (N, K)``
-    points matrix-form ``prev`` reads at rows OTHER than the live
-    neighbor table — the chaos transport's staleness pricing (see
-    dfl/faults.py).
-    """
-    if not use_kernel:
-        return robust_stats_indexed_ref(models, neighbor_idx, valid, prev,
-                                        need_gram=need_gram,
-                                        prev_idx=prev_idx)
-    N, K = neighbor_idx.shape
-    block_d, itp = resolve_block_d(models.shape[-1], block_d, interpret)
-    m = pad_d(models, block_d)
-    p = pad_d(prev, block_d) if prev is not None else None
-    v = (jnp.ones((N, K), jnp.float32) if valid is None
-         else valid.astype(jnp.float32))
-    outs = robust_stats_indexed_pallas(
-        m, neighbor_idx, v, p, block_d=block_d, interpret=itp,
-        need_gram=need_gram, prev_idx=prev_idx)
-    dist2, dotmed, norm2, mednorm2 = outs[:4]
-    rest = outs[4:]
-    gram = None
-    if need_gram:
-        gram, rest = rest[0], rest[1:]
-    tail = (None, None, None)
-    if prev is not None:
-        tail = tuple(o[:, 0, :] for o in rest)
-    return RobustStats(
-        med=None,
-        trim=None,
-        dist2=dist2[:, 0, :],
-        dotmed=dotmed[:, 0, :],
-        norm2=norm2[:, 0, :],
-        mednorm2=mednorm2[:, 0, 0],
-        prev_dist2=tail[0],
-        prev_dot=tail[1],
-        prev_norm2=tail[2],
-        gram=gram,
-    )
-
-
-@functools.partial(jax.jit, static_argnames=(
-    "beta", "block_d", "interpret", "use_kernel", "need_center"))
-def robust_stats_batch(
-    updates: jax.Array,
-    prev: Optional[jax.Array] = None,
-    beta: float = 0.1,
-    block_d: Optional[int] = None,
-    interpret: Optional[bool] = None,
-    use_kernel: bool = True,
-    need_center: bool = True,
-) -> RobustStats:
-    """Batched fused statistics over (N, K, D): one kernel launch for all
-    N per-node aggregations.  Every ``RobustStats`` field gains a leading
-    N axis (``mednorm2`` becomes (N,))."""
-    if not use_kernel:
-        return jax.vmap(lambda u, p: robust_stats_ref(u, beta, prev=p))(
-            updates, prev
-        ) if prev is not None else jax.vmap(
-            lambda u: robust_stats_ref(u, beta))(updates)
-    N, K, D = updates.shape
-    n_trim = trim_count(K, beta)
-    block_d, itp = resolve_block_d(D, block_d, interpret)
-    u = pad_d(updates, block_d)
-    p = pad_d(prev, block_d) if prev is not None else None
-    outs = robust_stats_batch_pallas(
-        u, p, n_trim=n_trim, block_d=block_d, interpret=itp,
-        emit_center=need_center,
-    )
-    if need_center:
-        med, trim = outs[0][:, 0, :D], outs[1][:, 0, :D]
-        outs = outs[2:]
-    else:
-        med = trim = None
-    dist2, dotmed, norm2, mednorm2 = outs[:4]
-    tail = (None, None, None)
-    if prev is not None:
-        tail = tuple(o[:, 0, :] for o in outs[4:])
-    return RobustStats(
-        med=med,
-        trim=trim,
-        dist2=dist2[:, 0, :],
-        dotmed=dotmed[:, 0, :],
-        norm2=norm2[:, 0, :],
-        mednorm2=mednorm2[:, 0, 0],
-        prev_dist2=tail[0],
-        prev_dot=tail[1],
-        prev_norm2=tail[2],
-    )
+def _tile(K: int, d: int, has_prev: bool, block_d: Optional[int],
+          interpret: Optional[bool]) -> tuple[int, bool]:
+    """(tile width, interpret) of a round-kernel launch: ``block_d`` when
+    given; else on a TPU ``round_tile_width`` (K, d and a fixed VMEM
+    budget), and in interpret mode ONE tile — the interpreter carries
+    whole output buffers through every grid step, so fewer steps win."""
+    itp = resolve_interpret(interpret)
+    if block_d is None:
+        block_d = (max(128, -(-d // 128) * 128) if itp
+                   else round_tile_width(K, d, has_prev))
+    return block_d, itp
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -225,8 +61,7 @@ def wfagg_round_indexed(
     block_d: Optional[int] = None,
     interpret: Optional[bool] = None,
 ):
-    """One-launch gossip round: the fused WFAgg-E combine folded into the
-    indexed robust_stats kernel.
+    """One-launch gossip round: both phases of the round kernel.
 
     A single 3-D (node, phase, D tile) Pallas launch gathers each
     node's K neighbor rows of a (K, T) tile with row DMAs, accumulates
@@ -247,10 +82,7 @@ def wfagg_round_indexed(
     all-rejected behavior: local model (DFL, Eq. 3) vs uniform valid
     mean (robust all-reduce).
 
-    Tile width T: ``block_d`` when given; else on a TPU
-    ``round_tile_width`` (K, d and a fixed VMEM budget), and in
-    interpret mode ONE tile — the interpreter carries the (N, d)
-    combine output through every grid step, so fewer steps win.
+    Tile width T: see ``_tile``.
     """
     from repro.core import trust  # deferred: see kernel.py
 
@@ -262,10 +94,7 @@ def wfagg_round_indexed(
             "reads the kernel's own prev_dist2/cosine temporal statistics")
     if alpha is None:
         alpha = cfg.alpha
-    itp = resolve_interpret(interpret)
-    if block_d is None:
-        block_d = (auto_block_d(d, itp, interpret_blocks=1) if itp
-                   else round_tile_width(K, d, prev is not None))
+    block_d, itp = _tile(K, d, prev is not None, block_d, interpret)
     m = pad_d(models, block_d)
     loc = pad_d(local, block_d)
     p = pad_d(prev, block_d) if prev is not None else None
@@ -311,28 +140,56 @@ def _round_stats(accs, need_gram: bool, has_prev: bool) -> RobustStats:
 def wfagg_round_indexed_stats(
     models: jax.Array,         # (M, d) model matrix
     neighbor_idx: jax.Array,   # (N, K) rows into models
-    prev: Optional[jax.Array] = None,    # (M, d) matrix, rows as models'
+    prev: Optional[jax.Array] = None,    # (N, K, d) or (M, d) matrix
+    valid: Optional[jax.Array] = None,   # (N, K); None = all valid
+    prev_idx: Optional[jax.Array] = None,  # (N, K) rows into matrix prev
     need_gram: bool = False,
     block_d: Optional[int] = None,
     interpret: Optional[bool] = None,
 ) -> RobustStats:
-    """Phase 0 of ``wfagg_round_indexed`` alone, every slot valid: the
-    round kernel's statistics (the Gram with ``need_gram``, the WFAgg-T
-    tail with ``prev``) and no scoring or combine.  For a caller that
-    holds a slice of d and sums the accumulators across slices before
-    it scores them.  The fields carry a leading N axis, as
-    ``wfagg_round_indexed``'s statistics do; the tile is its too, so a d
-    that ``round_padded_width`` rounded needs no padded copy."""
+    """Phase 0 of ``wfagg_round_indexed`` alone: the round kernel's
+    statistics (the Gram with ``need_gram``, the WFAgg-T tail with
+    ``prev``) and no scoring or combine — the oracle is
+    ``robust_stats_indexed_ref``.  For a caller that holds a slice of d
+    and sums the accumulators across slices before it scores them, or
+    that scores on the host.  ``valid``, ``prev`` and ``prev_idx`` mean
+    what they mean to ``wfagg_round_indexed``.  The fields carry a
+    leading N axis; the tile is the round's too, so a d that
+    ``round_padded_width`` rounded needs no padded copy."""
     N, K = neighbor_idx.shape
-    d = models.shape[-1]
-    itp = resolve_interpret(interpret)
-    if block_d is None:
-        block_d = (auto_block_d(d, itp, interpret_blocks=1) if itp
-                   else round_tile_width(K, d, prev is not None))
+    block_d, itp = _tile(K, models.shape[-1], prev is not None, block_d,
+                         interpret)
     m = pad_d(models, block_d)
     p = pad_d(prev, block_d) if prev is not None else None
+    v = (jnp.ones((N, K), jnp.float32) if valid is None
+         else valid.astype(jnp.float32))
     outs = wfagg_round_indexed_pallas(
-        None, m, neighbor_idx, jnp.ones((N, K), jnp.float32), None, p,
+        None, m, neighbor_idx, v, None, p, prev_idx=prev_idx,
         alpha=1.0, need_gram=need_gram, block_d=block_d, interpret=itp,
-        stats_only=True)
+        phases=STATS)
     return _round_stats(outs, need_gram, prev is not None)
+
+
+@functools.partial(jax.jit, static_argnames=("block_d", "interpret"))
+def wfagg_round_indexed_combine(
+    local: jax.Array,          # (N, d) combine anchors (local models)
+    models: jax.Array,         # (M, d) model matrix
+    neighbor_idx: jax.Array,   # (N, K) rows into models
+    wcomb: jax.Array,          # (N, K) neighbor coefficients
+    lcoef: jax.Array,          # (N,) local coefficients
+    block_d: Optional[int] = None,
+    interpret: Optional[bool] = None,
+) -> jax.Array:
+    """Phase 1 of ``wfagg_round_indexed`` alone: ``out_n = lcoef_n *
+    local_n + sum_k wcomb_nk * models[neighbor_idx[n, k]]`` in f32, slot
+    by slot, the (N, K, d) gossip tensor never built.  The coefficients
+    are what ``core.trust.combine_coefficients`` makes of each node's
+    trust weights (the oracle is ``weighted_agg_ref``)."""
+    N, K = neighbor_idx.shape
+    d = models.shape[-1]
+    block_d, itp = _tile(K, d, False, block_d, interpret)
+    (out,) = wfagg_round_indexed_pallas(
+        pad_d(local, block_d), pad_d(models, block_d), neighbor_idx, None,
+        None, alpha=1.0, block_d=block_d, interpret=itp, phases=COMBINE,
+        coef=(wcomb, lcoef))
+    return out[:, 0, :d]

@@ -1,4 +1,4 @@
-"""Pure-jnp oracle for the fused robust-statistics kernel.
+"""Pure-jnp oracles for the WFAgg round kernel (kernel.py).
 
 Given a candidate matrix ``updates (K, D)`` computes, in one logical pass:
   med       (D,)  coordinate-wise median (mean of the two middles, K even)
@@ -16,6 +16,11 @@ and, when the previous-round candidates ``prev (K, D)`` are supplied:
 These are exactly the sufficient statistics of WFAgg-D (Alg. 2), WFAgg-C
 (Alg. 3) and WFAgg-T (Alg. 4) plus the Median / Trimmed-Mean baselines —
 one HBM read of the candidate block serves all of them.
+
+The oracles of the round kernel (``kernel.py``): ``robust_stats_ref``
+and ``robust_stats_indexed_ref`` (the valid-aware, neighbor-indexed
+form) for phase 0's accumulators, ``pairwise_dist_ref`` for its Gram,
+and ``weighted_agg_ref`` for phase 1's combine.
 """
 from __future__ import annotations
 
@@ -132,3 +137,22 @@ def robust_stats_ref(updates: Array, beta: float = 0.1,
         prev_norm2 = jnp.sum(prev * prev, axis=-1)
     return RobustStats(med, trim, dist2, dotmed, norm2, mednorm2,
                        prev_dist2, prev_dot, prev_norm2)
+
+
+def pairwise_dist_ref(updates: Array) -> Array:
+    """(K, D) -> (K, K) squared Euclidean distances."""
+    diff = updates[:, None, :] - updates[None, :, :]
+    return jnp.sum(diff * diff, axis=-1)
+
+
+def weighted_agg_ref(local: Array, updates: Array, weights: Array,
+                     alpha: float) -> Array:
+    """Eq. 3: (1-a)*local + a * sum_j w'_j theta_j with w' normalized.
+
+    If all weights are zero the neighbor term vanishes and the local model
+    is returned unchanged.
+    """
+    wsum = weights.sum()
+    w_norm = weights / jnp.maximum(wsum, 1e-12)
+    eff_alpha = jnp.where(wsum > 0, alpha, 0.0)
+    return (1.0 - eff_alpha) * local + eff_alpha * (w_norm @ updates)

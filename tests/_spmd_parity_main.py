@@ -6,7 +6,11 @@ environment (set BEFORE jax imports — hence the subprocess; the tier-1
 suite itself runs on however many devices the session has).
 
 Modes:
-  round   one sharded gossip round vs wfagg_batch(fused_two_launch)
+Every mode compares against ``backend="reference"``: the sharded round
+scores on the host with the valid-aware reference's
+``core.wfagg._indexed_scoring``.
+
+  round   one sharded gossip round vs wfagg_batch(reference)
   scan    R sharded rounds (lax.scan inside shard_map, with temporal
           slot-history realignment) vs the same loop single-process
   stacked mode-B robust_allreduce_stacked(backend="reference") jitted
@@ -41,8 +45,7 @@ N, K, D, ROUNDS, SEED = 10, 4, 50890, 3, 7
 
 
 def _cfg():
-    return wf.WFAggConfig(backend="fused_two_launch", f=1, window=3,
-                          transient=1)
+    return wf.WFAggConfig(backend="reference", f=1, window=3, transient=1)
 
 
 def _fixture(rng, rounds=1):
@@ -80,9 +83,10 @@ def check_round():
     models, sched_idx, _ = _fixture(rng)
     idx = sched_idx[0]
     state = _state(prev=models * 0.97)
+    valid = jnp.ones((N, K), bool)   # the valid-aware reference
 
     ref_out, ref_state, ref_info = wf.wfagg_batch(
-        models, models, state, cfg, neighbor_idx=idx)
+        models, models, state, cfg, neighbor_idx=idx, valid=valid)
     out, new_state, info = spmd.wfagg_batch_sharded(
         models, models, state, cfg, idx, mesh=mesh)
 
@@ -104,9 +108,10 @@ def check_nonfinite():
     models = models.at[5, 17].set(jnp.nan)
     idx = sched_idx[0]
     state = _state(prev=models * 0.97)
+    valid = jnp.ones((N, K), bool)   # the valid-aware reference
 
     ref_out, _, ref_info = wf.wfagg_batch(
-        models, models, state, cfg, neighbor_idx=idx)
+        models, models, state, cfg, neighbor_idx=idx, valid=valid)
     out, _, info = spmd.wfagg_batch_sharded(
         models, models, state, cfg, idx, mesh=mesh)
     if np.asarray(info["valid"]).all():
@@ -192,17 +197,18 @@ def check_engine():
     data = SyntheticImages()
     sched = dyn.churn_schedule(topo, 2, seed=1)
     finals, compute = [], []
-    for shards in (0, 8):
+    # the reference for the parameters; the one-device fused round, whose
+    # aggregation is all Pallas as the sharded round's is, for the dots
+    for backend, shards in (("reference", 0), ("fused", 0), ("fused", 8)):
         cfg = DFLConfig(aggregator="wfagg", attack="ipm_100", model="mlp",
-                        wfagg_backend="fused_two_launch",
-                        mesh_model_shards=shards)
+                        wfagg_backend=backend, mesh_model_shards=shards)
         fn = build_round_fn(cfg, topo, data, dynamic=True)
         state = init_dfl_state(cfg, topo, degree=sched.width)
         for r in range(2):
             args = (jnp.asarray(sched.neighbor_idx[r]),
                     jnp.asarray(sched.valid[r]),
                     jnp.asarray(sched.malicious[r]))
-            if r == 0:
+            if r == 0 and backend == "fused":
                 hlo = fn.lower(state, *args).compile().as_text()
                 compute.append(sorted(set(re.findall(
                     r"= (\S+) (?:dot|convolution)\(", hlo))))
@@ -213,7 +219,7 @@ def check_engine():
     if compute[0] != compute[1]:
         raise SystemExit(f"parity FAIL [engine compute]: one-device dots "
                          f"{compute[0]} vs sharded {compute[1]}")
-    ref, sh = finals
+    ref, _, sh = finals
     for (path, a), (_, b) in zip(
             jax.tree_util.tree_leaves_with_path(ref.node_params),
             jax.tree_util.tree_leaves_with_path(sh.node_params)):

@@ -19,7 +19,7 @@ from repro.dfl import dynamics as dyn
 from repro.dfl.engine import (
     DFLConfig, build_round_fn, init_dfl_state, run_dynamic_experiment,
     run_experiment)
-from repro.kernels.robust_stats.ops import robust_stats_indexed
+from repro.kernels.robust_stats.ops import wfagg_round_indexed_stats
 from repro.kernels.robust_stats.ref import robust_stats_indexed_ref
 
 ATOL = 2e-5
@@ -147,11 +147,12 @@ def test_indexed_stats_degree0_finite_zero_median():
     models = jax.random.normal(jax.random.PRNGKey(0), (N, d), jnp.float32)
     idx = np.array([[1, 2, 3], [0, 2, 3], [2, 2, 2], [0, 1, 2]], np.int32)
     valid = np.array([[1, 1, 1], [1, 1, 0], [0, 0, 0], [1, 1, 1]], bool)
-    for fn in (robust_stats_indexed, robust_stats_indexed_ref):
+    kernel = lambda m, i, v: wfagg_round_indexed_stats(m, i, valid=v)  # noqa: E731
+    for which, fn in (("kernel", kernel), ("oracle", robust_stats_indexed_ref)):
         st = fn(models, jnp.asarray(idx), jnp.asarray(valid))
         for name in ("dist2", "dotmed", "norm2", "mednorm2"):
             arr = np.asarray(getattr(st, name))
-            assert np.isfinite(arr).all(), (fn.__name__, name)
+            assert np.isfinite(arr).all(), (which, name)
         # node 2: empty median = 0 => dist2 == norm2, dotmed == 0
         np.testing.assert_allclose(np.asarray(st.dist2)[2],
                                    np.asarray(st.norm2)[2], rtol=1e-5)

@@ -11,7 +11,7 @@ import pytest
 
 from repro.core import attacks as atk
 from repro.core import wfagg as wf
-from repro.kernels.robust_stats.ops import robust_stats, robust_stats_batch
+from repro.kernels.robust_stats.ops import wfagg_round_indexed_stats
 from repro.kernels.robust_stats.ref import robust_stats_ref
 
 ATOL = 1e-5
@@ -64,7 +64,8 @@ def test_wfagg_temporal_filter_activates():
 
 @pytest.mark.parametrize("K", [7, 8])
 def test_alt_wfagg_backend_parity(K):
-    """Multi-Krum + Clustering filters, fused via the pairwise Gram kernel."""
+    """Multi-Krum + Clustering filters, fused via the statistics phase's
+    Gram."""
     d = 600
     cfg_r = wf.alt_wfagg_config(backend="reference")
     cfg_f = wf.alt_wfagg_config(backend="fused")
@@ -135,20 +136,26 @@ def test_wfagg_t_select_backend_parity():
 
 @pytest.mark.parametrize("with_prev", [True, False])
 def test_batched_stats_match_single_node_kernel(with_prev):
+    """The statistics phase over all N gathered slates at once (an
+    identity slate of N*K rows, ``prev`` per edge) matches it one node
+    at a time, and the oracle."""
     N, K, D = 5, 8, 1000
     u = jax.random.normal(jax.random.PRNGKey(0), (N, K, D), jnp.float32)
     p = jax.random.normal(jax.random.PRNGKey(1), (N, K, D), jnp.float32) \
         if with_prev else None
-    got = robust_stats_batch(u, p)
+    got = wf._gathered_stats(u, p, need_gram=False)
+    slate = jnp.arange(K, dtype=jnp.int32)[None, :]
+    tail = ("prev_dist2", "prev_dot", "prev_norm2")
     for n in range(N):
-        one = robust_stats(u[n], p[n] if with_prev else None)
+        one = wfagg_round_indexed_stats(u[n], slate,
+                                        p[n] if with_prev else None)
         ref = robust_stats_ref(u[n], prev=p[n] if with_prev else None)
-        for name in got._fields:
+        for name in ("dist2", "dotmed", "norm2", "mednorm2") + tail:
             g, s, r = getattr(got, name), getattr(one, name), getattr(ref, name)
-            if g is None:
-                assert s is None and r is None
+            if name in tail and not with_prev:
+                assert g is None and s is None and r is None
                 continue
-            np.testing.assert_allclose(g[n], s, rtol=3e-5, atol=3e-5,
+            np.testing.assert_allclose(g[n], s[0], rtol=3e-5, atol=3e-5,
                                        err_msg=f"batch-vs-single {name}")
             np.testing.assert_allclose(g[n], r, rtol=3e-5, atol=3e-5,
                                        err_msg=f"batch-vs-oracle {name}")
@@ -210,32 +217,6 @@ def test_stacked_fused_matches_reference(method):
                                        rtol=1e-4, atol=ATOL)
 
 
-def test_stacked_fused_gather_dtype_keeps_temporal_masks():
-    """gather_dtype quantizes the D/C/Gram statistics only: the WFAgg-T
-    round-over-round metrics stay full-precision in both backends, so the
-    temporal masks must agree even under bfloat16 gathers."""
-    from repro.distributed.robust_allreduce import (
-        RobustAggConfig, init_tree_agg_state, robust_allreduce_stacked)
-
-    K = 6
-    g = {"w": jax.random.normal(jax.random.PRNGKey(3), (K, 64))}
-    wcfg = wf.WFAggConfig(f=1, transient=1, window=2)
-    cfg_r = RobustAggConfig(method="wfagg", wfagg=wcfg, layout="stacked",
-                            backend="reference", gather_dtype="bfloat16")
-    cfg_f = dataclasses.replace(cfg_r, backend="fused")
-    like = jax.tree.map(lambda x: x[0], g)
-    s_r = init_tree_agg_state(cfg_r, K, like)
-    s_f = init_tree_agg_state(cfg_f, K, like)
-    for r in range(4):
-        gr = jax.tree.map(lambda x: x + 0.05 * r, g)
-        _, s_r, i_r = robust_allreduce_stacked(gr, cfg_r, s_r)
-        _, s_f, i_f = robust_allreduce_stacked(gr, cfg_f, s_f)
-        assert np.array_equal(np.asarray(i_r["mask_t"]),
-                              np.asarray(i_f["mask_t"])), r
-        np.testing.assert_allclose(np.asarray(s_r.hist_s), np.asarray(s_f.hist_s),
-                                   rtol=1e-5, atol=1e-5)
-
-
 # ---------------------------------------------------------------------------
 # DFL engine: fused backend end-to-end
 # ---------------------------------------------------------------------------
@@ -266,5 +247,5 @@ def test_memory_passes_accounting():
     cfg_f = wf.WFAggConfig(backend="fused")
     assert wf.memory_passes(cfg_f) == 2
     assert wf.memory_passes(cfg_r) >= 2 * wf.memory_passes(cfg_f)
-    # Alt-WFAgg needs one extra Gram pass in both backends
-    assert wf.memory_passes(wf.alt_wfagg_config(backend="fused")) == 3
+    # Alt-WFAgg's Gram rides the statistics pass: no extra pass
+    assert wf.memory_passes(wf.alt_wfagg_config(backend="fused")) == 2

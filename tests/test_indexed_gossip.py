@@ -10,9 +10,11 @@ import pytest
 
 from repro.core import wfagg as wf
 from repro.core.topology import make_topology, padded_neighbor_table
-from repro.kernels.robust_stats.ops import robust_stats_batch, robust_stats_indexed
-from repro.kernels.robust_stats.ref import robust_stats_indexed_ref
-from repro.kernels.weighted_agg.ops import weighted_agg, weighted_agg_indexed
+from repro.core import trust
+from repro.kernels.robust_stats.ops import (
+    wfagg_round_indexed_combine, wfagg_round_indexed_stats)
+from repro.kernels.robust_stats.ref import (
+    robust_stats_indexed_ref, weighted_agg_ref)
 
 ATOL = 2e-5
 
@@ -46,28 +48,33 @@ def _irregular(N, K, seed=0):
 
 
 # ---------------------------------------------------------------------------
-# indexed robust_stats kernel
+# the round kernel's statistics phase, read through the neighbor table
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("K", [5, 8])
 @pytest.mark.parametrize("prev_kind", ["none", "edge", "matrix"])
 def test_indexed_stats_match_gathered_batch(K, prev_kind):
+    """Phase 0 through the neighbor table equals phase 0 over the
+    gathered (N, K, d) slates, and the oracle."""
     N, d = 9, 900
     models = jax.random.normal(jax.random.PRNGKey(0), (N, d), jnp.float32)
     idx = _ring_idx(N, K) if K < N - 1 else _ring_idx(N, N - 1)
     prev_m = jax.random.normal(jax.random.PRNGKey(1), (N, d), jnp.float32)
     prev_arg = {"none": None, "edge": prev_m[idx], "matrix": prev_m}[prev_kind]
-    got = robust_stats_indexed(models, idx, None, prev_arg)
-    exp = robust_stats_batch(models[idx],
+    got = wfagg_round_indexed_stats(models, idx, prev_arg)
+    exp = wf._gathered_stats(models[idx],
                              prev_m[idx] if prev_kind != "none" else None,
-                             need_center=False)
+                             need_gram=False)
+    ref = robust_stats_indexed_ref(models, idx, None, prev_arg)
     for name in got._fields:
-        g, e = getattr(got, name), getattr(exp, name)
+        g, e, r = getattr(got, name), getattr(exp, name), getattr(ref, name)
         if g is None:
-            assert e is None, name
+            assert e is None and r is None, name
             continue
         np.testing.assert_allclose(np.asarray(g), np.asarray(e),
                                    rtol=1e-6, atol=1e-6, err_msg=name)
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r),
+                                   rtol=3e-5, atol=3e-5, err_msg=name)
 
 
 @pytest.mark.parametrize("with_prev", [False, True])
@@ -77,7 +84,7 @@ def test_indexed_stats_irregular_match_oracle(with_prev):
     idx, valid = _irregular(N, K, seed=4)
     prev = (jax.random.normal(jax.random.PRNGKey(3), (N, d), jnp.float32)
             if with_prev else None)
-    got = robust_stats_indexed(models, idx, valid, prev)
+    got = wfagg_round_indexed_stats(models, idx, prev, valid=valid)
     ref = robust_stats_indexed_ref(models, idx, valid, prev)
     vmask = np.asarray(valid)
     for name in got._fields:
@@ -97,7 +104,7 @@ def test_indexed_median_spans_valid_rows_only():
     models = models.at[3].set(1e6)  # the row the padded slot points at
     idx = jnp.array([[1, 2, 3], [0, 2, 3], [0, 1, 3], [0, 1, 2]], jnp.int32)
     valid = jnp.array([[1, 1, 0], [1, 1, 0], [1, 1, 0], [1, 1, 1]], bool)
-    got = robust_stats_indexed(models, idx, valid)
+    got = wfagg_round_indexed_stats(models, idx, valid=valid)
     # node 0 median = median(models[[1, 2]]) — row 3 excluded
     med2 = 0.5 * (models[1] + models[2])
     exp_d2 = np.sum((np.asarray(models[idx[0]]) - np.asarray(med2)) ** 2, -1)
@@ -106,19 +113,29 @@ def test_indexed_median_spans_valid_rows_only():
 
 
 # ---------------------------------------------------------------------------
-# indexed WFAgg-E combine kernel
+# the round kernel's combine phase, read through the neighbor table
 # ---------------------------------------------------------------------------
 
 def test_weighted_agg_indexed_matches_single_node_kernel():
+    """Phase 1 through the neighbor table equals phase 1 over each
+    node's gathered slate, and the oracle."""
     N, K, d = 7, 6, 800
     models = jax.random.normal(jax.random.PRNGKey(6), (N, d), jnp.float32)
     local = jax.random.normal(jax.random.PRNGKey(7), (N, d), jnp.float32)
     idx = _ring_idx(N, K)
     w = jax.random.uniform(jax.random.PRNGKey(8), (N, K))
     w = w.at[2].set(0.0)   # all-rejected node keeps its local model
-    got = weighted_agg_indexed(local, models, idx, w, alpha=0.8)
+    wcomb, lcoef = jax.vmap(
+        lambda wn: trust.combine_coefficients(wn, 0.8, wn >= 0, False))(w)
+    got = wfagg_round_indexed_combine(local, models, idx, wcomb, lcoef)
+    slate = jnp.arange(K, dtype=jnp.int32)[None, :]
     for n in range(N):
-        exp = weighted_agg(local[n], models[idx[n]], w[n], alpha=0.8)
+        one = wfagg_round_indexed_combine(local[n:n + 1], models[idx[n]],
+                                          slate, wcomb[n:n + 1],
+                                          lcoef[n:n + 1])[0]
+        exp = weighted_agg_ref(local[n], models[idx[n]], w[n], 0.8)
+        np.testing.assert_allclose(np.asarray(got[n]), np.asarray(one),
+                                   rtol=ATOL, atol=ATOL, err_msg=str(n))
         np.testing.assert_allclose(np.asarray(got[n]), np.asarray(exp),
                                    rtol=ATOL, atol=ATOL, err_msg=str(n))
     np.testing.assert_allclose(np.asarray(got[2]), np.asarray(local[2]),
@@ -381,7 +398,7 @@ def test_mode_b_multi_krum_m_prefers_wfagg_config():
                                    multi_krum_m=wf_m)
         cfg = RobustAggConfig(method="alt_wfagg", wfagg=wcfg,
                               multi_krum_m=ra_m, layout="stacked")
-        stats = _stacked_stats({"w": u}, cfg)
+        stats = _stacked_stats({"w": u})
         _, _, info = _weights_from_stats(stats, None, None, cfg)
         mask_a = wf._distance_mask(                   # mode-A path
             u, dc.replace(wcfg, multi_krum_m=eff_m))

@@ -2,6 +2,10 @@
 
 Every Pallas kernel is executed in interpret=True mode (the kernel body
 runs in Python on CPU) and compared against its pure-jnp oracle in ref.py.
+The WFAgg round kernel's phases run alone here over an identity slate
+(one node, its K candidates as the model matrix): phase 0 against
+``robust_stats_ref``'s accumulators and ``pairwise_dist_ref``, phase 1
+against ``weighted_agg_ref``.
 """
 import jax
 import jax.numpy as jnp
@@ -9,16 +13,18 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from repro.kernels.pairwise_dist.ops import pairwise_sq_dists
-from repro.kernels.pairwise_dist.ref import pairwise_dist_ref
-from repro.kernels.robust_stats.ops import robust_stats
-from repro.kernels.robust_stats.ref import robust_stats_ref
-from repro.kernels.weighted_agg.ops import weighted_agg
-from repro.kernels.weighted_agg.ref import weighted_agg_ref
+from repro.core import trust
+from repro.kernels.robust_stats.ops import (
+    wfagg_round_indexed_combine, wfagg_round_indexed_stats)
+from repro.kernels.robust_stats.ref import (
+    pairwise_dist_ref, robust_stats_ref, weighted_agg_ref)
 
 KS = [4, 5, 8, 9, 16, 20, 32]
 DS = [128, 777, 2048]
 BLOCKS = [256, 512]
+# phase 0's accumulators: the median reaches them through dist2, dotmed
+# and mednorm2
+ACCS = ("dist2", "dotmed", "norm2", "mednorm2")
 
 
 def _rand(key, shape, dtype, scale=3.0):
@@ -26,16 +32,37 @@ def _rand(key, shape, dtype, scale=3.0):
     return x.astype(dtype)
 
 
+def _slate(K):
+    return jnp.arange(K, dtype=jnp.int32)[None, :]
+
+
+def _stats(u, block_d=256, need_gram=False):
+    """Phase 0 over one node's (K, D) candidates, the node axis dropped."""
+    got = wfagg_round_indexed_stats(u, _slate(u.shape[0]),
+                                    need_gram=need_gram, block_d=block_d)
+    return jax.tree.map(lambda x: x[0], got)
+
+
+def _combine(local, u, w, alpha, block_d=256):
+    """Phase 1 over one node's candidates with the coefficients
+    ``core.trust`` derives from trust weights ``w``."""
+    wcomb, lcoef = trust.combine_coefficients(
+        w, alpha, jnp.ones(w.shape, bool), False)
+    return wfagg_round_indexed_combine(
+        local[None], u, _slate(u.shape[0]), wcomb[None], lcoef[None],
+        block_d=block_d)[0]
+
+
 @pytest.mark.parametrize("K", KS)
 @pytest.mark.parametrize("D", DS)
 def test_robust_stats_matches_oracle(K, D):
     u = _rand(jax.random.PRNGKey(K * 1000 + D), (K, D), jnp.float32)
-    got = robust_stats(u, beta=0.1, block_d=256)
+    got = _stats(u)
     ref = robust_stats_ref(u, beta=0.1)
     for name in got._fields:
         g = getattr(got, name)
-        if g is None:  # temporal tail absent without a prev input
-            assert getattr(ref, name) is None
+        if g is None:  # no temporal tail without prev, no Gram, no centers
+            assert name not in ACCS, name
             continue
         np.testing.assert_allclose(
             g, getattr(ref, name), rtol=3e-5, atol=3e-5, err_msg=name
@@ -45,10 +72,12 @@ def test_robust_stats_matches_oracle(K, D):
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_robust_stats_dtypes(dtype):
     u = _rand(jax.random.PRNGKey(7), (8, 512), dtype)
-    got = robust_stats(u, beta=0.1, block_d=256)
+    got = _stats(u)
     ref = robust_stats_ref(u.astype(jnp.float32), beta=0.1)
     tol = 1e-5 if dtype == jnp.float32 else 5e-2
-    np.testing.assert_allclose(got.med, ref.med, rtol=tol, atol=tol)
+    for name in ("dotmed", "mednorm2"):
+        np.testing.assert_allclose(getattr(got, name), getattr(ref, name),
+                                   rtol=tol, atol=tol * 512, err_msg=name)
     np.testing.assert_allclose(got.dist2, ref.dist2, rtol=tol, atol=tol * 512)
 
 
@@ -56,8 +85,8 @@ def test_robust_stats_dtypes(dtype):
 def test_robust_stats_block_invariance(block_d):
     """Kernel output must not depend on the VMEM block size."""
     u = _rand(jax.random.PRNGKey(3), (16, 1024), jnp.float32)
-    a = robust_stats(u, beta=0.1, block_d=block_d)
-    b = robust_stats(u, beta=0.1, block_d=1024)
+    a = _stats(u, block_d=block_d)
+    b = _stats(u, block_d=1024)
     for name in a._fields:
         if getattr(a, name) is None:
             continue
@@ -68,7 +97,8 @@ def test_robust_stats_block_invariance(block_d):
 @pytest.mark.parametrize("D", [128, 1000])
 def test_pairwise_matches_oracle(K, D):
     u = _rand(jax.random.PRNGKey(K + D), (K, D), jnp.float32)
-    got = pairwise_sq_dists(u, block_d=256)
+    st_ = _stats(u, need_gram=True)
+    got = trust.sq_dists_from_gram(st_.gram, st_.norm2)
     ref = pairwise_dist_ref(u)
     np.testing.assert_allclose(got, ref, rtol=1e-4, atol=1e-2)
     assert np.all(np.diag(np.asarray(got)) == 0.0)
@@ -82,7 +112,7 @@ def test_weighted_agg_matches_oracle(K, alpha):
     u = _rand(k1, (K, 700), jnp.float32)
     local = _rand(k2, (700,), jnp.float32)
     w = jnp.abs(_rand(k3, (K,), jnp.float32))
-    got = weighted_agg(local, u, w, alpha=alpha, block_d=256)
+    got = _combine(local, u, w, alpha)
     ref = weighted_agg_ref(local, u, w, alpha)
     np.testing.assert_allclose(got, ref, rtol=3e-5, atol=3e-5)
 
@@ -90,7 +120,7 @@ def test_weighted_agg_matches_oracle(K, alpha):
 def test_weighted_agg_zero_weights_returns_local():
     u = _rand(jax.random.PRNGKey(0), (8, 300), jnp.float32)
     local = _rand(jax.random.PRNGKey(1), (300,), jnp.float32)
-    got = weighted_agg(local, u, jnp.zeros((8,)), alpha=0.8, block_d=256)
+    got = _combine(local, u, jnp.zeros((8,)), alpha=0.8)
     np.testing.assert_allclose(got, local, rtol=1e-6, atol=1e-6)
 
 
@@ -103,15 +133,18 @@ def test_weighted_agg_zero_weights_returns_local():
     seed=st.integers(min_value=0, max_value=2**31 - 1),
 )
 def test_median_permutation_invariance(K, D, seed):
-    """The fused stats must be invariant to candidate order (median, trim)
-    and equivariant (row-permuted) for the per-candidate statistics."""
+    """The fused stats must be invariant to candidate order (the median,
+    through mednorm2) and equivariant (row-permuted) for the
+    per-candidate statistics."""
     u = np.asarray(_rand(jax.random.PRNGKey(seed), (K, D), jnp.float32))
     perm = np.random.default_rng(seed).permutation(K)
-    a = robust_stats(jnp.asarray(u), beta=0.1, block_d=256)
-    b = robust_stats(jnp.asarray(u[perm]), beta=0.1, block_d=256)
-    np.testing.assert_allclose(a.med, b.med, rtol=1e-5, atol=1e-5)
-    np.testing.assert_allclose(a.trim, b.trim, rtol=1e-5, atol=1e-5)
-    np.testing.assert_allclose(np.asarray(a.dist2)[perm], b.dist2, rtol=1e-4, atol=1e-4)
+    a = _stats(jnp.asarray(u))
+    b = _stats(jnp.asarray(u[perm]))
+    np.testing.assert_allclose(a.mednorm2, b.mednorm2, rtol=1e-5, atol=1e-5)
+    for name in ("dist2", "dotmed"):
+        np.testing.assert_allclose(np.asarray(getattr(a, name))[perm],
+                                   getattr(b, name), rtol=1e-4, atol=1e-4,
+                                   err_msg=name)
 
 
 @settings(max_examples=20, deadline=None)
@@ -121,11 +154,15 @@ def test_median_permutation_invariance(K, D, seed):
     shift=st.floats(min_value=-5.0, max_value=5.0, allow_nan=False),
 )
 def test_median_translation_equivariance(K, seed, shift):
-    """median(u + c) == median(u) + c."""
+    """median(u + c) == median(u) + c: distances to the median do not
+    move, and the median's norm is the shifted oracle median's."""
     u = _rand(jax.random.PRNGKey(seed), (K, 256), jnp.float32)
-    a = robust_stats(u, beta=0.1, block_d=256)
-    b = robust_stats(u + shift, beta=0.1, block_d=256)
-    np.testing.assert_allclose(np.asarray(a.med) + shift, b.med, rtol=1e-4, atol=1e-4)
+    a = _stats(u)
+    b = _stats(u + shift)
+    np.testing.assert_allclose(a.dist2, b.dist2, rtol=1e-4, atol=1e-4)
+    med = np.asarray(robust_stats_ref(u).med, np.float64) + shift
+    np.testing.assert_allclose(b.mednorm2, np.sum(med * med), rtol=1e-4,
+                               atol=1e-4)
 
 
 # ---------------------------------------------------------------------------

@@ -1,11 +1,12 @@
-"""Single-launch gossip round: the fused WFAgg-E combine folded into the
-indexed robust_stats kernel (backend="fused") must reproduce the
-two-launch fallback (backend="fused_two_launch") and the valid-aware
-pure-jnp reference oracle — masks bit-equal, aggregates within fp32
-tolerance — across every dynamics scenario (including degree-0
-churned-out rows), irregular erdos_renyi-style degrees, both filter
-families, and the stacked (mode-B) layout; and the jitted round must
-lower to exactly ONE aggregation pallas_call with no (N, K, d) buffer."""
+"""Single-launch gossip round: both phases of the WFAgg round kernel in
+one launch (backend="fused") must reproduce the valid-aware pure-jnp
+reference oracle — masks bit-equal, aggregates within fp32 tolerance —
+across every dynamics scenario (including degree-0 churned-out rows),
+irregular erdos_renyi-style degrees, both filter families, and the
+stacked (mode-B) layout; its two phases run as two launches with the
+scoring between them (the d-sharded round's arithmetic) must reproduce
+the one launch; and the jitted round must lower to exactly ONE
+aggregation pallas_call with no (N, K, d) buffer."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -18,7 +19,7 @@ from repro.dfl import dynamics as dyn
 from repro.dfl.engine import DFLConfig, build_round_fn, init_dfl_state
 
 ATOL = 3e-5
-BACKENDS = ("fused", "fused_two_launch", "reference")
+BACKENDS = ("fused", "reference")
 
 
 def _matrix_state(N, K, d, cfg):
@@ -48,13 +49,13 @@ def _irregular(N, K, seed=0, min_degree=0):
 
 
 # ---------------------------------------------------------------------------
-# parity across every dynamics scenario (single vs two-launch vs reference)
+# parity across every dynamics scenario (single launch vs reference)
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("scenario", dyn.SCENARIO_NAMES)
 def test_one_launch_parity_across_scenarios(scenario):
     """Drive the schedule's round-varying slates through the gather-free
-    aggregation under all three backends, with live temporal state
+    aggregation under both backends, with live temporal state
     re-keyed between rounds exactly like the engine: masks bit-equal,
     aggregates within fp32 tolerance, degree-0 rows keep their local
     model."""
@@ -81,7 +82,7 @@ def test_one_launch_parity_across_scenarios(scenario):
             outs[b], states[b], infos[b] = wf.wfagg_batch(
                 u, u, st, c, neighbor_idx=idx, valid=val)
         prev_idx, prev_val = idx, val
-        for b in ("fused_two_launch", "reference"):
+        for b in ("reference",):
             for m in ("mask_d", "mask_c", "mask_t"):
                 assert np.array_equal(np.asarray(infos["fused"][m]),
                                       np.asarray(infos[b][m])), (r, b, m)
@@ -120,7 +121,7 @@ def test_one_launch_irregular_parity(filters):
         for b, c in cfgs.items():
             outs[b], states[b], infos[b] = wf.wfagg_batch(
                 u, u, states[b], c, neighbor_idx=idx, valid=val)
-        for b in ("fused_two_launch", "reference"):
+        for b in ("reference",):
             for m in ("mask_d", "mask_c", "mask_t"):
                 assert np.array_equal(np.asarray(infos["fused"][m]),
                                       np.asarray(infos[b][m])), (r, b, m)
@@ -172,7 +173,7 @@ def test_one_launch_multiblock_d_matches_single_block():
 
 
 # ---------------------------------------------------------------------------
-# the (node, phase, D tile) grid: forced tile widths against the fallbacks
+# the (node, phase, D tile) grid: forced tile widths against the reference
 # ---------------------------------------------------------------------------
 
 def _live_state(models, idx, prev, prev_idx, cfg, seed):
@@ -222,8 +223,8 @@ def test_retiled_round_parity(case):
     """Several (K, T) tiles per node, d not a multiple of T, K off the
     8-row sublane tile, padded slots and degree-0 nodes, a matrix prev
     read through its own table, and the Alt-WFAgg Gram: the round kernel
-    at a forced tile width must match the two-launch fallback and the
-    valid-aware reference (masks bit-equal, aggregates within fp32)."""
+    at a forced tile width must match the valid-aware reference (masks
+    bit-equal, aggregates within fp32)."""
     N, K, d, block_d, min_deg, prev_rows, filters = RETILED[case]
     assert d % block_d != 0 or case == "chunks_per_tile"
     idx, val = _irregular(N, K, seed=21, min_degree=min_deg)
@@ -255,7 +256,7 @@ def test_retiled_round_parity(case):
              for b, c in cfgs.items()}
     out, info = _tiled_round(local, models, idx, val, state["fused"],
                              cfgs["fused"], prev_idx, block_d)
-    for b in ("fused_two_launch", "reference"):
+    for b in ("reference",):
         o_b, _, i_b = wf.wfagg_batch(
             local, models, state[b], cfgs[b], neighbor_idx=idx, valid=val,
             prev_idx=prev_idx)
@@ -332,6 +333,87 @@ def test_retiled_round_trainer_shape(block_d):
 
 
 # ---------------------------------------------------------------------------
+# the two phases as two launches: the d-sharded round's arithmetic
+# ---------------------------------------------------------------------------
+
+SPLIT = {
+    # name: (irregular valid mask, prev rows)
+    "static": (False, "table"),
+    "irregular": (True, "table"),
+    "chaos_prev_idx": (True, "prev_idx"),
+}
+
+
+@pytest.mark.parametrize("case", list(SPLIT))
+@pytest.mark.parametrize("filters", ["wfagg", "alt_wfagg"])
+def test_split_phases_match_one_launch(filters, case):
+    """The d-sharded round's arithmetic without a mesh: the statistics
+    phase on each column half of d, the halves' accumulators summed (the
+    psum), the host scoring stage (``core.wfagg._indexed_scoring``) and
+    ``core.trust.combine_coefficients``, then the combine phase on each
+    half — the same masks as the one launch, which scores in-kernel, and
+    the same aggregate within fp32."""
+    from repro.kernels.robust_stats.ops import (
+        wfagg_round_indexed_combine, wfagg_round_indexed_stats)
+
+    irregular, prev_rows = SPLIT[case]
+    N, K, d = 9, 5, 640
+    if irregular:
+        idx, val = _irregular(N, K, seed=21, min_degree=0)
+        val = val.at[2].set(False)                  # a degree-0 node
+        idx = idx.at[2].set(2)
+    else:
+        idx = jnp.asarray((np.arange(N)[:, None] + np.arange(1, K + 1)) % N,
+                          jnp.int32)
+        val = None
+    models = jax.random.normal(jax.random.PRNGKey(31), (N, d)) + 0.2
+    prev_idx = None
+    if prev_rows == "prev_idx":
+        # the chaos transport's shape: a stacked (2N, d) matrix read
+        # through its own table, rows of the older half
+        prev = jnp.concatenate(
+            [models, models + 0.05 * jax.random.normal(
+                jax.random.PRNGKey(32), (N, d))])
+        prev_idx = jnp.asarray(
+            np.random.default_rng(5).integers(N, 2 * N, (N, K)), jnp.int32)
+    else:
+        prev = models + 0.05 * jax.random.normal(jax.random.PRNGKey(33),
+                                                 (N, d))
+    mk = wf.alt_wfagg_config if filters == "alt_wfagg" else wf.WFAggConfig
+    extra = {"multi_krum_m": 2} if filters == "alt_wfagg" else {}
+    cfg = mk(transient=1, f=1, **extra)
+    state = _live_state(models, idx, prev, prev_idx, cfg, 3)
+    local = models + 0.01
+    out_1, info_1 = _tiled_round(local, models, idx, val, state, cfg,
+                                 prev_idx, None)
+
+    valid_b = jnp.ones((N, K), bool) if val is None else val
+    halves = (slice(0, d // 2), slice(d // 2, d))
+    parts = [wfagg_round_indexed_stats(
+        models[:, c], idx, prev[:, c], valid=valid_b, prev_idx=prev_idx,
+        need_gram=wf.trust.needs_gram(cfg)) for c in halves]
+    stats = jax.tree.map(jnp.add, *parts)
+    mask_d, mask_c, mask_t, weights, _ = wf._indexed_scoring(
+        stats, valid_b, state, cfg, models, idx)
+    wcomb, lcoef = jax.vmap(
+        lambda w, v: wf.trust.combine_coefficients(w, cfg.alpha, v, False)
+    )(weights, valid_b)
+    out = jnp.concatenate([wfagg_round_indexed_combine(
+        local[:, c], models[:, c], idx, wcomb, lcoef) for c in halves],
+        axis=1)
+    for name, got in (("mask_d", mask_d), ("mask_c", mask_c),
+                      ("mask_t", mask_t)):
+        assert np.array_equal(np.asarray(got),
+                              np.asarray(info_1[name])), (case, name)
+    mt = np.asarray(mask_t)[np.asarray(valid_b)]
+    assert mt.any() and not mt.all(), mt
+    np.testing.assert_allclose(np.asarray(weights),
+                               np.asarray(info_1["weights"]), atol=ATOL)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(out_1),
+                               rtol=ATOL, atol=ATOL, err_msg=case)
+
+
+# ---------------------------------------------------------------------------
 # satellite: the reference backend's valid-aware oracle
 # ---------------------------------------------------------------------------
 
@@ -386,51 +468,53 @@ def test_reference_backend_degree0_keeps_local():
 @pytest.mark.parametrize("aggregator", ["wfagg", "alt_wfagg"])
 def test_round_is_single_pallas_launch(aggregator):
     """The jitted dynamic round must contain exactly ONE aggregation
-    pallas_call under the single-launch backend (the two-launch fallback
-    keeps two — sanity check that the counter sees them), and its
-    compiled HLO must stay (N, K, d)-free.  Both properties are asserted
-    through the shared ``repro.analysis`` rule API (the same walkers the
-    ``python -m repro.analysis`` gate runs)."""
+    pallas_call under the fused backend (the split phases of the
+    d-sharded round, on one device, keep two — sanity check that the
+    counter sees them), and its compiled HLO must stay (N, K, d)-free.
+    Both properties are asserted through the shared ``repro.analysis``
+    rule API (the same walkers the ``python -m repro.analysis`` gate
+    runs)."""
     from repro.analysis import count_pallas_calls, scan_nkd_buffers
+    from repro.dfl.engine import _wfagg_full_config
+    from repro.distributed import spmd
 
     topo = make_topology(n_nodes=10, degree=4, n_malicious=2, kind="ring",
                          seed=0)
     data = SyntheticImages()
     sched = dyn.churn_schedule(topo, 3, seed=1)
     N, K = topo.n_nodes, sched.width
-    counts = {}
-    for backend in ("fused", "fused_two_launch"):
-        cfg = DFLConfig(aggregator=aggregator, attack="ipm_100", model="mlp",
-                        wfagg_backend=backend)
-        fn = build_round_fn(cfg, topo, data, dynamic=True)
-        state = init_dfl_state(cfg, topo, degree=K)
-        args = (state, jnp.asarray(sched.neighbor_idx[0]),
-                jnp.asarray(sched.valid[0]), jnp.asarray(sched.malicious[0]))
-        jaxpr = jax.make_jaxpr(fn)(*args)
-        counts[backend] = count_pallas_calls(jaxpr.jaxpr)
-        if backend == "fused":
-            hlo = fn.lower(*args).compile().as_text()
-            # d-sized (N, K, d) buffers only: the alt_wfagg (N, K, K)
-            # Gram is a legit O(K^2) statistic, not a gossip tensor
-            hits = scan_nkd_buffers(hlo, N, K, min_d=16 * K)
-            assert hits == [], hits
-    assert counts["fused"] == 1, counts
-    assert counts["fused_two_launch"] >= 2, counts
+    cfg = DFLConfig(aggregator=aggregator, attack="ipm_100", model="mlp")
+    fn = build_round_fn(cfg, topo, data, dynamic=True)
+    state = init_dfl_state(cfg, topo, degree=K)
+    args = (state, jnp.asarray(sched.neighbor_idx[0]),
+            jnp.asarray(sched.valid[0]), jnp.asarray(sched.malicious[0]))
+    assert count_pallas_calls(jax.make_jaxpr(fn)(*args).jaxpr) == 1
+    hlo = fn.lower(*args).compile().as_text()
+    # d-sized (N, K, d) buffers only: the alt_wfagg (N, K, K) Gram is a
+    # legit O(K^2) statistic, not a gossip tensor
+    hits = scan_nkd_buffers(hlo, N, K, min_d=16 * K)
+    assert hits == [], hits
+
+    wcfg = _wfagg_full_config(cfg, K)
+    u = jnp.zeros((N, 64), jnp.float32)
+    split = jax.make_jaxpr(
+        lambda m, st, i, v: spmd.wfagg_batch_sharded(
+            m, m, st, wcfg, i, v, mesh=spmd.aggregation_mesh(1)))(
+        u, _matrix_state(N, K, 64, wcfg), args[1], args[2])
+    assert count_pallas_calls(split.jaxpr) == 2
 
 
 def test_memory_passes_one_launch_accounting():
     """The indexed single-launch round reports 2 candidate passes (its
-    combine phase gathers the tiles again), as the two-launch fallback
-    does; Alt-WFAgg folds its Gram in-kernel, so it adds none."""
+    combine phase gathers the tiles again); Alt-WFAgg folds its Gram
+    into the statistics phase, so it adds none."""
     one = wf.WFAggConfig()
-    two = wf.WFAggConfig(backend="fused_two_launch")
     assert wf.memory_passes(one, include_gather=True, indexed=True) == 2
-    assert wf.memory_passes(two, include_gather=True, indexed=True) == 2
     assert wf.memory_passes(
         wf.alt_wfagg_config(), include_gather=True, indexed=True) == 2
-    # non-indexed entries keep the two-launch accounting
+    # the gathered entries run the same two phases: stats, then combine
     assert wf.memory_passes(one) == 2
-    assert wf.memory_passes(wf.alt_wfagg_config()) == 3
+    assert wf.memory_passes(wf.alt_wfagg_config()) == 2
 
 
 # ---------------------------------------------------------------------------
@@ -458,7 +542,7 @@ def test_stacked_one_launch_matches_fallbacks(method):
         for b, c in cfgs.items():
             out, states[b], info = robust_allreduce_stacked(gr, c, states[b])
             res[b] = (out, info)
-        for b in ("fused_two_launch", "reference"):
+        for b in ("reference",):
             np.testing.assert_allclose(
                 np.asarray(res["fused"][1]["weights"]),
                 np.asarray(res[b][1]["weights"]), atol=ATOL)

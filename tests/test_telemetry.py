@@ -1,7 +1,7 @@
 """Flight recorder (repro.obs): the decision plane must be a pure
 *observer* — verdict bits bit-equal to the masks the aggregation already
 computes (reference backend as oracle, across every dynamics scenario
-and all three WFAgg backends), model trajectories bit-identical with
+and both WFAgg backends), model trajectories bit-identical with
 telemetry on or off — and the export plane must round-trip its own
 schema (JSONL log, Perfetto trace, audit rates on hand-built verdicts).
 docs/OBSERVABILITY.md documents the planes; the launch-count/purity
@@ -26,7 +26,7 @@ from repro.obs import recorder as obs_recorder
 from repro.obs import report as obs_report
 from repro.obs import trace as obs_trace
 
-BACKENDS = ("fused", "fused_two_launch", "reference")
+BACKENDS = ("fused", "reference")
 
 
 # ---------------------------------------------------------------------------
@@ -395,7 +395,7 @@ def test_render_audit_smoke():
 
 
 # ---------------------------------------------------------------------------
-# timing plane + microbench methodology
+# timing plane
 # ---------------------------------------------------------------------------
 
 def test_round_traffic_bytes_joins_memory_passes():
@@ -406,9 +406,3 @@ def test_round_traffic_bytes_joins_memory_passes():
     assert got == passes * N * K * d * 4
     assert obs_profile.achieved_bytes_per_s(got, 2.0) == got / 2.0
 
-
-def test_microbench_timeit_median():
-    from benchmarks.agg_microbench import _timeit
-    fn = jax.jit(lambda x: x + 1.0)
-    comp_s, med_s = _timeit(fn, jnp.ones((64,)), reps=3)
-    assert comp_s > 0 and med_s > 0

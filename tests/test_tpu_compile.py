@@ -19,8 +19,8 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core.wfagg import WFAggConfig, alt_wfagg_config
 from repro.kernels.robust_stats.ops import (
-    robust_stats_indexed, wfagg_round_indexed)
-from repro.kernels.weighted_agg.ops import weighted_agg_indexed
+    wfagg_round_indexed, wfagg_round_indexed_combine,
+    wfagg_round_indexed_stats)
 
 N, K, D = 20, 8, 44_426
 
@@ -198,16 +198,21 @@ def test_trainer_step_reads_only_its_slice(trainer_step_four_chips):
 
 @pytest.mark.parametrize("need_gram", [False, True], ids=["stats", "gram"])
 def test_stats_kernel_compiles(one_chip, need_gram):
+    """The round kernel's statistics phase alone (the d-sharded round's
+    first launch), with a ``valid`` mask, a matrix-form ``prev`` and,
+    for Alt-WFAgg, the Gram."""
     s = lambda shape, dt=jnp.float32: _spec(one_chip, shape, dt)  # noqa: E731
     _assert_mosaic(
-        lambda m, i, v, p: robust_stats_indexed(
-            m, i, v, prev=p, need_gram=need_gram, interpret=False),
+        lambda m, i, v, p: wfagg_round_indexed_stats(
+            m, i, p, valid=v, need_gram=need_gram, interpret=False),
         s((N, D)), s((N, K), jnp.int32), s((N, K)), s((N, D)))
 
 
 def test_combine_kernel_compiles(one_chip):
+    """The round kernel's combine phase alone (the d-sharded round's
+    second launch), its coefficients an input."""
     s = lambda shape, dt=jnp.float32: _spec(one_chip, shape, dt)  # noqa: E731
     _assert_mosaic(
-        lambda loc, m, i, w: weighted_agg_indexed(loc, m, i, w,
-                                                  interpret=False),
-        s((N, D)), s((N, D)), s((N, K), jnp.int32), s((N, K)))
+        lambda loc, m, i, w, lc: wfagg_round_indexed_combine(
+            loc, m, i, w, lc, interpret=False),
+        s((N, D)), s((N, D)), s((N, K), jnp.int32), s((N, K)), s((N,)))
