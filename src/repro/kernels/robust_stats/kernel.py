@@ -38,11 +38,12 @@ from repro.kernels.robust_stats.ref import RobustStats
 Array = jax.Array
 
 
-def sort_rows(x: Array) -> list:
-    """Odd-even transposition sort along axis 0 of a (K, T) tile (static
-    K, fully unrolled); returns the K sorted rows, each (1, T)."""
-    K = x.shape[0]
-    rows = [x[r:r + 1] for r in range(K)]
+def sort_rows(rows: list) -> list:
+    """Odd-even transposition sort of K lane-wide rows (static K, fully
+    unrolled): ~K^2/2 elementwise min/max pairs; returns the K sorted
+    rows."""
+    rows = list(rows)
+    K = len(rows)
     for p in range(K):
         for r in range(p % 2, K - 1, 2):
             a, b = rows[r], rows[r + 1]
@@ -58,20 +59,23 @@ def _row_view(x: Array) -> Array:
     return x.reshape(x.shape[0], 1, x.shape[-1])
 
 
-def _valid_median(u: Array, vcol: Array) -> Array:
-    """Valid-masked median (1, T) of a resident (K, T) tile: invalid rows
-    sort to +inf, the two dynamic middles of the v valid rows are one-hot
-    selected, and the degree-0 guard zeroes the empty median (an
-    all-invalid row would otherwise pick +inf and 0 * inf would poison
-    dotmed with NaNs).  Zero is the safe empty median: every accumulated
-    statistic stays finite and the caller's valid mask rejects all slots,
-    so the node keeps its local model."""
-    rows = sort_rows(jnp.where(vcol, u, jnp.inf))
-    v = jnp.sum(vcol.astype(jnp.int32))
+def _valid_median(rows: list, ok: list, v) -> Array:
+    """Valid-masked median of K lane-wide candidate rows: invalid rows
+    (``ok[k]`` false) sort to +inf, the two dynamic middles of the ``v``
+    valid rows are selected, and the degree-0 guard zeroes the empty
+    median (an all-invalid slate would otherwise pick +inf and 0 * inf
+    would poison dotmed with NaNs).  Zero is the safe empty median: every
+    accumulated statistic stays finite and the caller's valid mask
+    rejects all slots, so the node keeps its local model.  ``ok`` and
+    ``v`` are scalars."""
+    srt = sort_rows([jnp.where(o, r, jnp.inf) for o, r in zip(ok, rows)])
     # truncating division: lo is wrong only at v == 0, which the guard zeroes
     lo, hi = jax.lax.div(v - 1, 2), jax.lax.div(v, 2)
-    med = 0.5 * (sum(jnp.where(lo == r, row, 0.0) for r, row in enumerate(rows))
-                 + sum(jnp.where(hi == r, row, 0.0) for r, row in enumerate(rows)))
+    pick_lo = pick_hi = srt[0]
+    for r, row in enumerate(srt[1:], 1):
+        pick_lo = jnp.where(lo == r, row, pick_lo)
+        pick_hi = jnp.where(hi == r, row, pick_hi)
+    med = 0.5 * (pick_lo + pick_hi)
     return jnp.where(v > 0, med, jnp.zeros_like(med))
 
 
@@ -81,52 +85,63 @@ def _row_sums(x: Array) -> Array:
     return trust.col_to_row(jnp.sum(x, axis=1, keepdims=True))
 
 
-def _flush_indexed_stats(u: Array, valid_row: Array, acc_refs,
-                         is_first_d, need_gram: bool, pv: Array | None):
-    """Accumulate one D block of the indexed statistics off the resident
-    (K, T) candidate tile ``u`` (and ``pv``, the matching previous-round
-    tile): phase 0 of the round kernel.  The median honors the node's valid row: invalid
-    (padded) rows sort to +inf and the median picks the dynamic middle of
-    the v valid rows.  Per-candidate statistics are computed on the RAW
-    rows (padded slots hold the node's own finite model), so they stay
-    finite and the caller's mask logic drops them by ``valid``.
-    ``acc_refs`` are (dist2, dotmed, norm2, mednorm2[, gram][, prev_dist2,
-    prev_dot, prev_norm2]) — (1, K) rows, a (1, 1) and a (K, K)."""
-    from repro.core import trust  # deferred: see _wfagg_round_indexed_kernel
-    dist2_ref, dotmed_ref, norm2_ref, mednorm2_ref = acc_refs[:4]
-    vcol = trust.row_to_col(valid_row) > 0.0             # (K, 1)
-    med = _valid_median(u, vcol)        # degree-0 guard: empty median = 0
+def _n_partials(K: int, has_prev: bool) -> int:
+    """Rows of the flush's partial-sum scratch: ``mednorm2``, then K
+    each of dist2, dotmed, norm2 and, with ``prev``, prev_dist2,
+    prev_dot, prev_norm2."""
+    return 1 + K * (6 if has_prev else 3)
 
+
+def _flush_indexed_stats(land_u, land_p, slot, cols, ok: list, v,
+                         part_ref, gram_ref):
+    """Accumulate one chunk of the indexed statistics: phase 0 of the
+    round kernel.  The chunk is read as (K, 1, chunk) off the resident
+    landing tile, so candidate k is the lane-wide row ``u[k]`` — whole
+    vregs — and the sort, the median pick and the products are dense
+    elementwise work.  The median honors the node's valid slots: invalid
+    (padded) rows sort to +inf and the median picks the dynamic middle
+    of the v valid rows.  Per-candidate statistics are computed on the
+    RAW rows (padded slots hold the node's own finite model), so they
+    stay finite and the caller's mask logic drops them by ``valid``.
+    The products are added lane by lane into ``part_ref``
+    (``_n_partials`` rows of the chunk's width); ``_reduce_partials``
+    sums them across lanes once a node.  ``gram_ref``, when given, takes
+    the (K, K) candidate Gram off the same chunk."""
+    K = land_u.shape[1]
+    u = land_u[slot, :, :, cols]                          # (K, 1, chunk)
+    med = _valid_median([u[k] for k in range(K)], ok, v)  # (1, chunk)
+    rows = lambda j: pl.ds(1 + j * K, K)                  # noqa: E731
     diff = u - med
-    p_dist2 = _row_sums(diff * diff)
-    p_dot = _row_sums(u * med)
-    p_norm2 = _row_sums(u * u)
-    p_med2 = jnp.sum(med * med, keepdims=True)
-
-    @pl.when(is_first_d)
-    def _init():
-        for ref in acc_refs:
-            ref[...] = jnp.zeros_like(ref)
-
-    dist2_ref[...] += p_dist2
-    dotmed_ref[...] += p_dot
-    norm2_ref[...] += p_norm2
-    mednorm2_ref[...] += p_med2
-
-    if need_gram:
+    part_ref[0] += med * med
+    part_ref[rows(0)] += diff * diff
+    part_ref[rows(1)] += u * med
+    part_ref[rows(2)] += u * u
+    if land_p is not None:
+        pv = land_p[slot, :, :, cols]
+        dprev = u - pv
+        part_ref[rows(3)] += dprev * dprev
+        part_ref[rows(4)] += u * pv
+        part_ref[rows(5)] += pv * pv
+    if gram_ref is not None:
         # the (K, K) candidate Gram comes free off the resident tile
         # (MXU matmul) — no extra pass for the Alt-WFAgg filters, and
         # nothing quadratic in the TOTAL node count M
-        acc_refs[4][...] += jax.lax.dot_general(
-            u, u, (((1,), (1,)), ((), ())),
+        u2 = u.reshape(K, u.shape[-1])
+        gram_ref[...] += jax.lax.dot_general(
+            u2, u2, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    if pv is not None:
-        pdist2_ref, pdot_ref, pnorm2_ref = acc_refs[5 if need_gram else 4:]
-        dprev = u - pv
-        pdist2_ref[...] += _row_sums(dprev * dprev)
-        pdot_ref[...] += _row_sums(u * pv)
-        pnorm2_ref[...] += _row_sums(pv * pv)
+
+def _reduce_partials(part_ref, acc_refs, K: int, need_gram: bool):
+    """Sum the flush's lane-wide partials across lanes into the (1, K)
+    accumulator rows (and the (1, 1) ``mednorm2``) — once a node, at its
+    last tile."""
+    mednorm2_ref = acc_refs[3]
+    rows = list(acc_refs[:3]) + list(acc_refs[5 if need_gram else 4:])
+    mednorm2_ref[...] = jnp.sum(part_ref[0], keepdims=True)
+    for j, ref in enumerate(rows):
+        block = part_ref[pl.ds(1 + j * K, K)]
+        ref[...] = _row_sums(block.reshape(K, block.shape[-1]))
 
 
 def _prev_rows(prev: Array, models: Array, neighbor_idx: Array,
@@ -157,14 +172,17 @@ def _prev_rows(prev: Array, models: Array, neighbor_idx: Array,
     return prev.reshape(N * K, 1, D), table, lambda ir, n, k: n * K + k
 
 
-# VMEM the round kernel's tile-sized buffers may take: the double-buffered
-# (K, T) landing tiles of the candidates (and of ``prev``) plus the
-# pipelined (1, T) ``local`` and output blocks — well under the 16 MiB of
+# VMEM the round kernel's tile-sized buffers and the flush's partial sums
+# may take: the double-buffered (K, T) landing tiles of the candidates
+# (and of ``prev``) plus the pipelined (1, T) ``local`` and output
+# blocks, 4 MiB; and the (_n_partials, 1, ROUND_CHUNK) partial sums,
+# 128 KiB, which holds K = 4's with ``prev`` (100 KiB) — a wider slate
+# gives up tile width for the rest of its own.  Well under the 16 MiB of
 # scoped VMEM, which also holds the flush's live rows
-ROUND_TILE_BUDGET = 4 * 1024 * 1024
-# lanes one flush of the statistics works on: the sort network runs on
-# (K, ROUND_CHUNK) slices of the resident tile, so its code and live rows
-# do not grow with the tile width
+ROUND_TILE_BUDGET = 4 * 1024 * 1024 + 128 * 1024
+# lanes one flush of the statistics works on: one vreg (8 x 128 floats)
+# of each candidate row, so the sort network's code and live rows do not
+# grow with the tile width
 ROUND_CHUNK = 1024
 
 
@@ -183,9 +201,11 @@ def round_tile_width(K: int, d: int, has_prev: bool) -> int:
 
 
 def _round_tile_cap(K: int, has_prev: bool) -> int:
-    """The widest tile ``ROUND_TILE_BUDGET`` allows, in 1024-lane blocks."""
+    """The widest tile ``ROUND_TILE_BUDGET`` allows, in 1024-lane blocks,
+    once the flush's partial sums are counted."""
     lane_bytes = 4 * (2 * K * (2 if has_prev else 1) + 4)
-    return max(1, ROUND_TILE_BUDGET // (1024 * lane_bytes))
+    partial_bytes = 4 * _n_partials(K, has_prev) * ROUND_CHUNK
+    return max(1, (ROUND_TILE_BUDGET - partial_bytes) // (1024 * lane_bytes))
 
 
 def round_padded_width(K: int, d: int, has_prev: bool) -> int:
@@ -217,9 +237,11 @@ def _wfagg_round_indexed_kernel(*refs, K: int, n_t: int, T: int, chunk: int,
     phase 0 also the K ``prev`` rows), and starts the NEXT step's
     gathers into the other slot before it computes — across phase and
     node boundaries too, so only the launch's first tile is exposed.
-    Phase 0 flushes the D/C/T accumulators (and the Alt-WFAgg Gram) off
+    Phase 0 flushes the D/C/T statistics (and the Alt-WFAgg Gram) off
     the resident tile, ``chunk`` lanes at a time
-    (``_flush_indexed_stats``).  Under ``ROUND``, at the phase boundary
+    (``_flush_indexed_stats``), into lane-wide partial sums that the
+    node's last tile sums across lanes into the (1, K) accumulators
+    (``_reduce_partials``).  Under ``ROUND``, at the phase boundary
     (phase 0, last tile) the WFAgg scoring stage runs IN-KERNEL on the
     VMEM-resident (1, K) accumulators
     (``core.trust.derive_trust_weights``), the masks/weights are written
@@ -258,10 +280,12 @@ def _wfagg_round_indexed_kernel(*refs, K: int, n_t: int, T: int, chunk: int,
         dist2_ref, dotmed_ref, norm2_ref, mednorm2_ref = acc_refs[:4]
         gram_ref = acc_refs[4] if need_gram else None
         prev_acc = acc_refs[5 if need_gram else 4:] if has_prev else ()
-    land_u, sem_u = scratch[0], scratch[1]
-    land_p, sem_p = (scratch[2], scratch[3]) if has_prev else (None, None)
+    land_u, sem_u = scratch.pop(0), scratch.pop(0)
+    land_p, sem_p = ((scratch.pop(0), scratch.pop(0)) if has_prev
+                     else (None, None))
+    part_ref = scratch.pop(0) if run_stats else None
     wcomb_ref, lcoef_ref = coef_in or (
-        scratch[-2:] if run_combine else (None, None))
+        scratch if run_combine else (None, None))
 
     n_phase = len(phases)
     n, p, i = pl.program_id(0), pl.program_id(1), pl.program_id(2)
@@ -303,18 +327,27 @@ def _wfagg_round_indexed_kernel(*refs, K: int, n_t: int, T: int, chunk: int,
     if run_stats:
         @pl.when(p == 0)
         def _stats():
-            valid_row = valid_ref[...]
+            # the node's valid slots as scalars (``valid`` rides in SMEM)
+            ok = [valid_ref[0, k] > 0.0 for k in range(K)]
+            v = sum(o.astype(jnp.int32) for o in ok)
+
+            @pl.when(i == 0)
+            def _init():
+                part_ref[...] = jnp.zeros_like(part_ref)
+                if need_gram:
+                    gram_ref[...] = jnp.zeros_like(gram_ref)
 
             def flush(c, carry):
                 cols = pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
-                u = land_u[slot, :, :, cols].reshape(K, chunk)
-                pv = (land_p[slot, :, :, cols].reshape(K, chunk)
-                      if has_prev else None)
-                _flush_indexed_stats(u, valid_row, acc_refs,
-                                     (i == 0) & (c == 0), need_gram, pv)
+                _flush_indexed_stats(land_u, land_p, slot, cols, ok, v,
+                                     part_ref, gram_ref)
                 return carry
 
             jax.lax.fori_loop(0, T // chunk, flush, 0)
+
+            @pl.when(last_t)
+            def _reduce():
+                _reduce_partials(part_ref, acc_refs, K, need_gram)
 
     if not run_combine:
         return
@@ -322,7 +355,9 @@ def _wfagg_round_indexed_kernel(*refs, K: int, n_t: int, T: int, chunk: int,
     if run_stats:
         @pl.when((p == 0) & last_t)
         def _derive():
-            valid_f = valid_ref[...]                              # (1, K)
+            kio = jax.lax.broadcasted_iota(jnp.int32, (1, K), 1)
+            valid_f = sum(jnp.where(kio == k, valid_ref[0, k], 0.0)
+                          for k in range(K))                     # (1, K)
             tail = [r[...] for r in prev_acc] if has_prev else [None] * 3
             stats = RobustStats(
                 med=None, trim=None,
@@ -416,7 +451,10 @@ def wfagg_round_indexed_pallas(
     one_spec = pl.BlockSpec((None, 1, 1), lambda n, p, i, ir: (n, 0, 0))
     in_specs, args = [], []
     if stats:
-        in_specs.append(k_spec)                                    # valid
+        # valid: read as K scalars a grid step (SMEM)
+        in_specs.append(pl.BlockSpec((None, 1, K),
+                                     lambda n, p, i, ir: (n, 0, 0),
+                                     memory_space=pltpu.SMEM))
         args.append(valid.astype(jnp.float32).reshape(N, 1, K))
     if has_tbands:
         # bands ride flat, (N, 1, 4K): a (N, 4, K) buffer would read as a
@@ -489,6 +527,10 @@ def wfagg_round_indexed_pallas(
     land = [pltpu.VMEM((2, K, 1, block_d), jnp.float32),
             pltpu.SemaphoreType.DMA((2,))]
     scratch_shapes = land * (2 if has_prev else 1)
+    if stats:
+        # the flush's lane-wide partial sums (``_flush_indexed_stats``)
+        scratch_shapes.append(pltpu.VMEM(
+            (_n_partials(K, has_prev), 1, chunk), jnp.float32))
     if phases == ROUND:
         scratch_shapes += [pltpu.VMEM((1, K), jnp.float32),  # combine weights
                            pltpu.VMEM((1, 1), jnp.float32)]  # local coefficient

@@ -97,6 +97,59 @@ def test_indexed_stats_irregular_match_oracle(with_prev):
         assert np.isfinite(g).all(), name  # padded slots stay finite
 
 
+def _padded_slate(N, K, M, seed):
+    """Padded (idx, valid) over an (M, d) matrix: degrees in [1, K], rows
+    drawn with replacement (so equal rows tie in the sort), node 2 at
+    degree 0; padded slots index the node's own row."""
+    rng = np.random.default_rng(seed)
+    idx = np.tile(np.arange(N, dtype=np.int32)[:, None], (1, K))
+    valid = np.zeros((N, K), bool)
+    for n in range(N):
+        v = 0 if n == 2 else int(rng.integers(1, K + 1))
+        idx[n, :v] = rng.integers(0, M, v)
+        valid[n, :v] = True
+    return jnp.asarray(idx), jnp.asarray(valid)
+
+
+@pytest.mark.parametrize("need_gram", [False, True], ids=["stats", "gram"])
+@pytest.mark.parametrize("prev_kind", ["edge", "prev_idx"])
+@pytest.mark.parametrize("K", [4, 8, 30])
+def test_indexed_stats_tiles_and_chunks_match_oracle(K, prev_kind,
+                                                     need_gram):
+    """Phase 0 alone over three 2,048-lane tiles of two 1,024-lane
+    chunks each (d = 4,500, ragged): the flush's lane-wide partial sums,
+    carried across chunks and tiles and summed across lanes at the
+    node's last tile, match the oracle at the fleets' and the trainer's
+    slate widths — padded slots, a degree-0 node, tied rows, per-edge
+    ``prev`` or a matrix ``prev`` read through ``prev_idx``, with and
+    without the Gram."""
+    N, M, d = 6, 40, 4500
+    models = jax.random.normal(jax.random.PRNGKey(K), (M, d)) + 0.1
+    idx, valid = _padded_slate(N, K, M, seed=K)
+    pmat = jax.random.normal(jax.random.PRNGKey(K + 1), (2 * M, d))
+    prev_idx = None
+    if prev_kind == "edge":
+        prev = pmat[idx]
+    else:
+        prev = pmat
+        prev_idx = jnp.asarray(
+            np.random.default_rng(K).integers(0, 2 * M, (N, K)), jnp.int32)
+    got = wfagg_round_indexed_stats(models, idx, prev, valid=valid,
+                                    prev_idx=prev_idx, need_gram=need_gram,
+                                    block_d=2048)
+    ref = robust_stats_indexed_ref(models, idx, valid, prev,
+                                   need_gram=need_gram, prev_idx=prev_idx)
+    for name in got._fields:
+        g, r = getattr(got, name), getattr(ref, name)
+        if g is None:
+            assert r is None, name
+            continue
+        g, r = np.asarray(g), np.asarray(r)
+        assert np.isfinite(g).all(), name
+        np.testing.assert_allclose(g, r, rtol=3e-5, atol=1e-3, err_msg=name)
+    assert np.asarray(got.mednorm2)[2] == 0.0       # degree 0: empty median
+
+
 def test_indexed_median_spans_valid_rows_only():
     """A padded slot with a huge model must not perturb the median."""
     N, K, d = 4, 3, 256

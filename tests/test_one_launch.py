@@ -215,6 +215,12 @@ RETILED = {
     "k13_padded_degree0": (16, 13, 700, 256, 0, "table", "wfagg"),
     "prev_idx_matrix": (9, 6, 900, 256, 0, "prev_idx", "wfagg"),
     "alt_gram": (9, 5, 640, 256, 0, "table", "alt"),
+    # 2,048-lane tiles of two 1,024-lane chunks: the flush's lane-wide
+    # partials carried across chunks and tiles, at the slate widths of
+    # the trainer (4), paper20 (8) and er1024 (30)
+    "k4_chunks_gram": (6, 4, 4500, 2048, 0, "table", "alt"),
+    "k8_chunks_prev_idx_gram": (10, 8, 4500, 2048, 0, "prev_idx", "alt"),
+    "k30_chunks_prev_idx": (32, 30, 2500, 2048, 0, "prev_idx", "wfagg"),
 }
 
 

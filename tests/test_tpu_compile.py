@@ -64,7 +64,12 @@ def _assert_mosaic(fn, *args):
                          ids=["wfagg", "alt_wfagg"])
 def test_round_kernel_compiles(one_chip, cfg):
     """The one-launch round with matrix-form temporal state read through
-    its own table (the chaos transport's shape) and WFAgg-T bands."""
+    its own table (the chaos transport's shape) and WFAgg-T bands, at
+    the tile the rule gives the paper fleet (T = 22,528, the flush's
+    partial sums counted)."""
+    from repro.kernels.robust_stats.kernel import round_tile_width
+
+    assert round_tile_width(K, D, True) == 22_528
     s = lambda shape, dt=jnp.float32: _spec(one_chip, shape, dt)  # noqa: E731
     _assert_mosaic(
         lambda loc, m, i, v, p, tb, pi: wfagg_round_indexed(
@@ -81,7 +86,10 @@ def test_round_kernel_compiles_er1024(one_chip, cfg):
     gathers and the tile rule's T = 4,096 at that K), the transport's
     stacked (4N + 4, d) matrix as both candidates and ``prev``, read
     through ``prev_idx``, and the WFAgg-T bands."""
+    from repro.kernels.robust_stats.kernel import round_tile_width
+
     n, k, m = 1024, 30, 4 * 1024 + 4
+    assert round_tile_width(k, D, True) == 4_096
     s = lambda shape, dt=jnp.float32: _spec(one_chip, shape, dt)  # noqa: E731
     _assert_mosaic(
         lambda loc, mat, i, v, tb, pi: wfagg_round_indexed(
@@ -97,8 +105,9 @@ def test_round_kernel_compiles_trainer(one_chip, temporal):
     """The round as the robust-DP trainer's stacked WFAgg step runs it:
     N = 1, an identity table over K = 4 replica gradients, matrix-form
     ``prev``, alpha = 1 with the uniform-mean fallback, at a P where the
-    tile rule picks the widest tile its budget allows at K = 4
-    (T = 52,224 with ``prev``, 86,016 without)."""
+    tile rule picks the widest tile its budget allows at K = 4, the
+    flush's partial sums counted (T = 52,224 with ``prev``, 86,016
+    without)."""
     from repro.kernels.robust_stats.kernel import round_tile_width
 
     k, p = 4, 51 * 84 * 1024 * 24 - 1000
